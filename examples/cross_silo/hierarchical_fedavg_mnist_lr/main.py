@@ -4,7 +4,10 @@ connection, slave procs train stride-shards of the silo's data over the
 host ProcessGroup plane and join the weighted allreduce.  This main.py is a
 self-contained torchrun stand-in: it spawns the silo's slave processes and
 places each by env (FEDML_PROC_RANK_IN_SILO / MASTER_PORT — the same env
-surface a real torchrun-style launcher would set).
+surface a real torchrun-style launcher would set).  A real launcher gives
+each silo process its own accelerator host; this stand-in runs them all on
+ONE host, so every process — the parent included — is placed on the host
+CPU before it touches a jax backend (a chip belongs to one process).
 
     python main.py --cf fedml_config.yaml --role server --rank 0
     python main.py --cf fedml_config.yaml --role client --rank 1
@@ -17,9 +20,11 @@ import sys
 import yaml
 
 import fedml_tpu
+from fedml_tpu.utils.platform import force_cpu_backend
 
 
 def _silo_proc(argv, proc_rank, n_proc, pg_port):
+    force_cpu_backend()
     sys.argv = list(argv)
     os.environ["FEDML_PROC_RANK_IN_SILO"] = str(proc_rank)
     os.environ["FEDML_N_PROC_IN_SILO"] = str(n_proc)

@@ -52,7 +52,11 @@ def init(args: Arguments | None = None, should_init_logs: bool = True) -> Argume
         )
 
     from .core import mlops as _mlops
+    from .utils.platform import configure_compilation_cache
 
+    # before the first compile of any entry point; logged so a run says
+    # where its compiled programs are kept
+    _logger.info("jax compilation cache: %s", configure_compilation_cache())
     _mlops.pre_setup(args)
     if getattr(args, "using_mlops", False):
         _mlops.init(args)

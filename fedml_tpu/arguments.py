@@ -19,7 +19,7 @@ The canonical YAML shape is unchanged::
     train_args:    { federated_optimizer, client_num_in_total, client_num_per_round,
                      comm_round, epochs, batch_size, client_optimizer, learning_rate, ... }
     validation_args: { frequency_of_the_test }
-    device_args:   { using_gpu, device_type, ... }
+    device_args:   { device_type }   # cpu | gpu | tpu; device.get_device enforces it
     comm_args:     { backend, ... }
     tracking_args: { enable_wandb, log_file_dir, ... }
     fault_args:    { fault_plan, ... }

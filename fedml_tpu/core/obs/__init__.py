@@ -7,7 +7,7 @@ One process-global context (configured by ``core.mlops.init`` when
   ``traceparent`` header turn each federated round into one cross-process
   span tree (``round → select → invite → client.train → upload →
   journal.append → aggregate → broadcast``, with fault/recovery events
-  attached — taxonomy in ``docs/OBSERVABILITY.md``);
+  attached — catalogue in ``docs/OBSERVABILITY.md``);
 * a :class:`~.metrics.MetricsRegistry` every library counter mirrors into
   (``tools/lint_obs.py`` forbids NEW bare counter bags outside this
   package and ``core/mlops``);

@@ -22,17 +22,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.7
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _legacy_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-        )
 
 
 # one canonical definition of the per-shard online-softmax math, shared

@@ -42,19 +42,8 @@ from typing import Any, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.7 (check_vma kwarg)
-except ImportError:  # pragma: no cover - legacy jax uses check_rep instead
-    from functools import partial as _partial
-
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _legacy_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-        )
 
 from ...core import obs
 from ...core.dp.fedml_differential_privacy import FedMLDifferentialPrivacy
@@ -194,6 +183,7 @@ class XLASimulator:
         self.aggregator = create_server_aggregator(model, args)
         self.metrics = MetricsLogger(args)
         self.round_times: List[float] = []
+        self.round_losses: List[float] = []
         self.samples_per_round: List[int] = []
         self.samples_trained = 0
         self._rng = jax.random.PRNGKey(int(getattr(args, "random_seed", 0)) + 11)
@@ -246,10 +236,13 @@ class XLASimulator:
         # (transformer Embed requires integers) and keep their dtype.
         x_np = np.concatenate(xs, 0)
         if np.issubdtype(x_np.dtype, np.floating):
-            self.x_all = jnp.asarray(x_np, dtype=data_storage_dtype(self.args, self.module))
-        else:
-            self.x_all = jnp.asarray(x_np)
-        self.y_all = jnp.asarray(np.concatenate(ys, 0))
+            x_np = x_np.astype(data_storage_dtype(self.args, self.module))
+        # committed ONCE to the mesh-replicated sharding the round takes them
+        # under (in_specs P()): a bare jnp.asarray is an uncommitted array on
+        # device 0 that every round's call would copy to the other devices
+        repl = NamedSharding(self.mesh, P())
+        self.x_all = jax.device_put(x_np, repl)
+        self.y_all = jax.device_put(np.concatenate(ys, 0), repl)
         logger.info(
             "packed %d clients (max_n=%d padded_n=%d) data %s (%s) into HBM",
             self.num_clients, self.max_client_n, self.padded_n, self.x_all.shape,
@@ -1076,6 +1069,7 @@ class XLASimulator:
                     tele_merger.merge(tele_blob)
             obs.maybe_export_metrics()
             self.round_times.append(dt)
+            self.round_losses.append(float(mean_loss))
             if round_idx > 0:  # round 0 is dominated by XLA compile
                 # The round's wall time is set by the heaviest mesh slot.
                 # Packed: record max device STEPS — the while_loop's actual

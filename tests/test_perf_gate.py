@@ -1,10 +1,9 @@
 """tools/perf_gate.py — the perf-regression gate over BENCH trajectories.
 
-The gate exists because BENCH_r03-r05 went dark (probe timeouts, empty
-tails) and shipped unnoticed.  These tests pin the acceptance contract:
-the real r01-r02 records pass, the real r03 artifact FAILS the gate, a
-synthetic regressed record fails the tolerance band, and the schema
-constants in bench.py and perf_gate.py cannot drift apart.
+These tests pin the acceptance contract on synthetic driver records: light
+rounds pass, a dark round (nonzero rc / no metric line) FAILS the gate, a
+regressed record fails the tolerance band, and the schema constants in
+bench.py and perf_gate.py cannot drift apart.
 """
 
 from __future__ import annotations
@@ -38,31 +37,41 @@ def _full(n, value, **extra):
     return rec
 
 
-class TestRealTrajectory:
-    """Against the repo's actual checked-in BENCH artifacts."""
+_LEGACY = {"metric": "m", "unit": "u", "value": 11000.0, "vs_baseline": 5.7}
 
-    def test_r01_r02_pass(self, capsys):
-        rc = perf_gate.main([os.path.join(REPO, "BENCH_r01.json"),
-                             os.path.join(REPO, "BENCH_r02.json")])
-        assert rc == 0
+
+class TestDarkRounds:
+    """A trajectory in the driver's wrapper format: two light legacy
+    (pre-schema) rounds, then rounds that died without a metric line."""
+
+    def _paths(self, tmp_path, n_dark=1):
+        paths = [_round_file(tmp_path, 1, _LEGACY),
+                 _round_file(tmp_path, 2, dict(_LEGACY, value=10900.0))]
+        paths += [_round_file(tmp_path, 3 + i, None, rc=1)
+                  for i in range(n_dark)]
+        return paths + ["--baseline", str(tmp_path / "nope")]
+
+    def test_light_rounds_pass(self, tmp_path, capsys):
+        assert perf_gate.main(self._paths(tmp_path, n_dark=0)) == 0
         assert "OK" in capsys.readouterr().out
 
-    def test_real_r03_dark_round_fails(self, capsys):
-        rc = perf_gate.main([os.path.join(REPO, "BENCH_r01.json"),
-                             os.path.join(REPO, "BENCH_r02.json"),
-                             os.path.join(REPO, "BENCH_r03.json")])
-        assert rc == 1
+    def test_dark_round_fails(self, tmp_path, capsys):
+        assert perf_gate.main(self._paths(tmp_path)) == 1
         assert "DARK ROUND" in capsys.readouterr().out
 
-    def test_known_dark_grandfathers_the_historical_window(self):
-        rc = perf_gate.main(["--known-dark", "3,4,5"])
-        assert rc == 0
+    def test_every_dark_round_is_reported(self, tmp_path, capsys):
+        assert perf_gate.main(self._paths(tmp_path, n_dark=3)) == 1
+        assert capsys.readouterr().out.count("DARK ROUND") == 3
 
-    def test_advisory_reports_but_exits_zero(self, capsys):
-        rc = perf_gate.main(["--advisory"])
-        assert rc == 0
+    def test_advisory_reports_but_exits_zero(self, tmp_path, capsys):
+        assert perf_gate.main(self._paths(tmp_path) + ["--advisory"]) == 0
         out = capsys.readouterr().out
         assert "ADVISORY" in out and "DARK ROUND" in out
+
+    def test_no_trajectory_in_the_tree_exits_2(self, capsys):
+        # the pre-PR-1 BENCH_r0x.json records are gone; nothing to gate
+        assert perf_gate.main([]) == 2
+        assert "no bench files found" in capsys.readouterr().out
 
 
 class TestTolerance:
@@ -81,12 +90,11 @@ class TestTolerance:
         assert perf_gate.main(
             paths + ["--baseline", str(tmp_path / "nope")]) == 0
 
-    def test_new_dark_round_fails_despite_known_dark(self, tmp_path):
+    def test_dark_round_between_light_ones_fails(self, tmp_path):
         paths = [_round_file(tmp_path, 1, _full(1, 10.0)),
-                 _round_file(tmp_path, 2, None, rc=1),   # grandfathered
-                 _round_file(tmp_path, 3, None, rc=1)]   # NEW dark round
-        rc = perf_gate.main(paths + ["--known-dark", "2",
-                                     "--baseline", str(tmp_path / "nope")])
+                 _round_file(tmp_path, 2, None, rc=1),
+                 _round_file(tmp_path, 3, _full(3, 10.0))]
+        rc = perf_gate.main(paths + ["--baseline", str(tmp_path / "nope")])
         assert rc == 1
 
     def test_obs_overhead_cap(self, tmp_path, capsys):
@@ -110,8 +118,15 @@ class TestSchemaValidation:
         path = _round_file(tmp_path, 1, rec)
         return perf_gate.main([path, "--baseline", str(tmp_path / "nope")])
 
-    def test_degraded_without_reason_fails(self, tmp_path):
-        rec = _full(1, 1.0)
+    def test_failed_without_reason_fails(self, tmp_path):
+        rec = _full(1, None)
+        rec["mode"] = "failed"
+        assert self._gate(tmp_path, rec) == 1
+
+    def test_retired_degraded_mode_is_rejected(self, tmp_path):
+        # bench.py has no CPU fallback any more: a record claiming one is
+        # not a valid round
+        rec = _full(1, 1.0, degraded_reason="no accelerator (cpu backend)")
         rec["mode"] = "degraded"
         assert self._gate(tmp_path, rec) == 1
 
@@ -133,7 +148,7 @@ class TestSchemaValidation:
         assert self._gate(tmp_path, rec) == 0
 
     def test_legacy_record_numeric_value_passes(self, tmp_path):
-        # pre-schema records (r01/r02 vintage) stay valid
+        # pre-schema records stay valid
         assert self._gate(
             tmp_path, {"metric": "m", "unit": "u", "value": 3.0}) == 0
 

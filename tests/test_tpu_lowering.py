@@ -1,0 +1,73 @@
+"""Cross-lower every Pallas entry point for the TPU from the CPU sandbox, so
+a BlockSpec the TPU lowering refuses fails CI without a chip (every kernel
+in the tree was refused this way until PR 21, and ``interpret=True`` tests
+could not see it).  Where libtpu can describe a v5e without a chip attached
+the kernels are also compiled, which runs Mosaic itself."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fedml_tpu.ops.flash_attention import flash_attention, flash_shard_update
+
+# (B, L, H, D, dtype): bench transformer attention (ragged L, D=64), a
+# 128-wide head, the TransformerConfig default head dim (256/8 = 32), and
+# model.init's L=8 trace
+SHAPES = [(8, 1023, 16, 64, jnp.bfloat16), (2, 1024, 8, 128, jnp.bfloat16),
+          (2, 256, 8, 32, jnp.float32), (2, 8, 8, 32, jnp.float32)]
+
+
+def _forward(q, k, v):
+    return flash_attention(q, k, v, causal=True)
+
+
+def _grad(q, k, v):
+    return jax.grad(lambda *a: _forward(*a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+def _shard_update(q, k, v, q_pos, k_pos, m, l, o):
+    return flash_shard_update(q, k, v, q_pos, k_pos, m, l, o, causal=True)
+
+
+def _entry_points(B, L, H, D, dtype):
+    qkv = (jax.ShapeDtypeStruct((B, L, H, D), dtype),) * 3
+    pos = jax.ShapeDtypeStruct((L,), jnp.int32)
+    stat = jax.ShapeDtypeStruct((B, H, L), jnp.float32)
+    acc = jax.ShapeDtypeStruct((B, L, H, D), jnp.float32)
+    return [("forward", _forward, qkv), ("grad", _grad, qkv),
+            ("shard_update", _shard_update, qkv + (pos, pos, stat, stat, acc))]
+
+
+@pytest.mark.parametrize("shape", SHAPES, ids=lambda s: "x".join(map(str, s[:4])))
+def test_pallas_entry_points_lower_for_tpu(shape):
+    for name, fn, args in _entry_points(*shape):
+        text = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text, name
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One compile-only v5e device, or skip: needs a libtpu that can
+    describe the topology with no chip attached."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2",
+            chips_per_host_bounds=(2, 2, 1), num_slices=1)
+    except Exception as e:  # no libtpu, or one that wants real hardware
+        pytest.skip(f"no compile-only TPU topology here: {e}")
+    return topo.devices[0]
+
+
+@pytest.mark.parametrize("shape", SHAPES[:1] + SHAPES[2:],
+                         ids=lambda s: "x".join(map(str, s[:4])))
+def test_pallas_entry_points_compile_under_mosaic(v5e, shape):
+    sharding = jax.sharding.SingleDeviceSharding(v5e)
+    for name, fn, args in _entry_points(*shape):
+        args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+                for a in args]
+        assert jax.jit(fn).lower(*args).compile() is not None, name

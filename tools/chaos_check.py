@@ -54,12 +54,10 @@ under this before they land unmarked.
 
 Before the pytest loop it runs the **perf gate** over the checked-in
 bench trajectory, advisory-then-strict: first ``tools/perf_gate.py
---advisory`` on the FULL trajectory (the historical BENCH_r03-r05 dark
-window prints loudly every time, so it can't fade into folklore), then
-strict with ``--known-dark 3,4,5`` grandfathering exactly that window —
-any NEW dark round or regression fails the chaos gate before a single
-pytest process spawns.  ``--skip-perf-gate`` opts out (e.g. a checkout
-without bench artifacts).
+--advisory`` for the full report, then strict — any dark round or
+regression fails the chaos gate before a single pytest process spawns.
+A checkout without ``BENCH_r*.json`` artifacts (this one, today) skips the
+leg; ``--skip-perf-gate`` opts out explicitly.
 
 It also runs the **fedlint leg** (``tools/fedlint.py``) the same way:
 advisory first (the full report prints, including pragma/baseline
@@ -98,14 +96,9 @@ import time
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# the historical dark window (BENCH_r03-r05 probe timeouts) — grandfathered
-# in the strict leg; anything dark beyond these rounds fails the gate
-KNOWN_DARK = "3,4,5"
-
-
 def run_perf_gate(timeout: float) -> int:
-    """Advisory pass over the full trajectory, then strict with the
-    historical dark rounds grandfathered.  Returns the strict leg's rc."""
+    """Advisory pass over the trajectory (full report), then strict.
+    Returns the strict leg's rc."""
     import glob
     if not glob.glob(os.path.join(REPO_ROOT, "BENCH_r*.json")):
         print("chaos_check: perf gate skipped — no BENCH_r*.json "
@@ -116,10 +109,8 @@ def run_perf_gate(timeout: float) -> int:
         print("chaos_check: perf gate (advisory, full trajectory)",
               flush=True)
         subprocess.run(gate + ["--advisory"], cwd=REPO_ROOT, timeout=timeout)
-        print(f"chaos_check: perf gate (strict, --known-dark {KNOWN_DARK})",
-              flush=True)
-        strict = subprocess.run(gate + ["--known-dark", KNOWN_DARK],
-                                cwd=REPO_ROOT, timeout=timeout)
+        print("chaos_check: perf gate (strict)", flush=True)
+        strict = subprocess.run(gate, cwd=REPO_ROOT, timeout=timeout)
     except subprocess.TimeoutExpired:
         print("chaos_check: perf gate TIMED OUT", flush=True)
         return 2

@@ -1,16 +1,13 @@
 #!/usr/bin/env python
-"""Perf-regression gate over the checked-in bench trajectory.
+"""Perf-regression gate over a bench trajectory.
 
-BENCH_r03-r05 went dark (probe timeouts, ``parsed: null``) and nobody
-noticed until a human read the JSON tails — three rounds of perf work
-shipped unmeasured.  This gate turns that prose complaint into a failing
-check.  It parses every ``BENCH_rNN.json`` driver record (``{"n", "cmd",
-"rc", "tail"}`` with the bench's single metric JSON line embedded in
-``tail``) plus ``BASELINE.json`` and fails on:
+A bench round that goes dark (nonzero rc, no metric line) or regresses
+should fail a check, not wait for a human to read a JSON tail.  The gate
+parses ``BENCH_rNN.json`` driver records (``{"n", "cmd", "rc", "tail"}``
+with the bench's single metric JSON line embedded in ``tail``) plus
+``BASELINE.json`` and fails on:
 
-* **dark rounds** — nonzero rc or no parseable metric line.  Historical
-  dark rounds are grandfathered explicitly via ``--known-dark 3,4,5``;
-  a NEW dark round always fails.
+* **dark rounds** — nonzero rc or no parseable metric line.
 * **schema violations** — bench.py stamps ``bench_schema`` / ``mode`` /
   ``degraded_reason`` / ``git_rev`` (schema 2); a schema-stamped record
   missing its required keys fails, as does a legacy record without
@@ -29,15 +26,15 @@ check.  It parses every ``BENCH_rNN.json`` driver record (``{"n", "cmd",
   ``BASELINE.json``'s ``published`` map, when populated, bands the same
   way against the published numbers.
 
-``--advisory`` prints every violation but exits 0 — the chaos gate runs
-advisory over the full trajectory (the known-dark window shows up loudly)
-and then strict with the historical dark rounds grandfathered.
+``--advisory`` prints every violation but exits 0.  The tree holds no bench
+trajectory at present (the pre-PR-1 records were taken through a retired
+set-up and are gone; PERF_LEDGER.jsonl is the driver's record now), so with
+no paths given the gate reports "no bench files found" and exits 2.
 
 Usage::
 
     python tools/perf_gate.py                       # BENCH_r*.json + BASELINE.json
     python tools/perf_gate.py BENCH_r01.json BENCH_r02.json
-    python tools/perf_gate.py --known-dark 3,4,5
     python tools/perf_gate.py --advisory --format json
 """
 
@@ -87,7 +84,7 @@ OVERHEAD_BUDGETS = {"dp_overhead_frac": 25.0, "chunk_overhead_frac": 0.05}
 LATENCY_KEYS = ("resize_downtime_s", "remesh_recompile_s",
                 "secagg_mask_s", "dp_overhead_frac")
 
-_MODES = ("full", "degraded", "failed")
+_MODES = ("full", "failed")
 
 
 def extract_metric_line(tail: str) -> Optional[Dict[str, Any]]:
@@ -146,8 +143,8 @@ def validate_record(entry: Dict[str, Any]) -> List[str]:
     mode = rec.get("mode")
     if mode not in _MODES:
         out.append(f"{where}: mode must be one of {_MODES}, got {mode!r}")
-    if mode in ("degraded", "failed") and not rec.get("degraded_reason"):
-        out.append(f"{where}: {mode} record missing degraded_reason")
+    if mode == "failed" and not rec.get("degraded_reason"):
+        out.append(f"{where}: failed record missing degraded_reason")
     if mode == "full" and rec.get("degraded_reason") not in (None, ""):
         out.append(f"{where}: full record carries degraded_reason "
                    f"{rec.get('degraded_reason')!r}")
@@ -166,18 +163,14 @@ def _median(vals: List[float]) -> float:
 
 def check_trajectory(entries: List[Dict[str, Any]], tolerance: float,
                      obs_overhead_max: float,
-                     known_dark: Optional[set] = None,
                      baseline: Optional[Dict[str, Any]] = None,
                      ) -> List[str]:
     """Every violation in the trajectory (empty = gate passes)."""
-    known_dark = known_dark or set()
     violations: List[str] = []
     light: List[Dict[str, Any]] = []
     for entry in entries:
         dark = entry["rc"] != 0 or entry["parsed"] is None
         if dark:
-            if entry["round"] in known_dark:
-                continue
             why = (f"rc={entry['rc']}" if entry["rc"] != 0
                    else "no parseable metric line in tail")
             violations.append(
@@ -249,13 +242,9 @@ def main(argv=None) -> int:
                     help="baseline metadata file (published reference keys)")
     ap.add_argument("--tolerance", type=float, default=0.5,
                     help="allowed fractional drop of a relative key vs the "
-                         "prior-round median (default 0.5 — CPU-degraded "
-                         "relative measures are noisy)")
+                         "prior-round median (default 0.5)")
     ap.add_argument("--obs-overhead-max", type=float, default=0.25,
                     help="absolute cap on obs_overhead_frac (default 0.25)")
-    ap.add_argument("--known-dark", default="",
-                    help="comma-separated round indices grandfathered as "
-                         "dark (the historical r03-r05 window)")
     ap.add_argument("--advisory", action="store_true",
                     help="report violations but exit 0")
     ap.add_argument("--format", choices=("text", "json"), default="text")
@@ -266,7 +255,6 @@ def main(argv=None) -> int:
     if not paths:
         print("perf_gate: no bench files found", flush=True)
         return 2
-    known_dark = {int(x) for x in args.known_dark.split(",") if x.strip()}
     try:
         entries = [load_round(p, i + 1) for i, p in enumerate(paths)]
     except (OSError, ValueError) as e:
@@ -280,15 +268,13 @@ def main(argv=None) -> int:
         pass  # baseline metadata is optional context, not a gate input
 
     violations = check_trajectory(
-        entries, args.tolerance, args.obs_overhead_max,
-        known_dark=known_dark, baseline=baseline)
+        entries, args.tolerance, args.obs_overhead_max, baseline=baseline)
     failed = bool(violations) and not args.advisory
     if args.format == "json":
         print(json.dumps({
             "ok": not violations,
             "advisory": bool(args.advisory),
             "n_rounds": len(entries),
-            "known_dark": sorted(known_dark),
             "violations": violations,
             "rounds": [{"round": e["round"], "rc": e["rc"],
                         "path": os.path.basename(e["path"]),
