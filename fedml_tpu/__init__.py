@@ -22,6 +22,7 @@ from __future__ import annotations
 import logging
 import os
 import random as _random
+import time
 
 import numpy as _np
 
@@ -38,6 +39,7 @@ _logger = logging.getLogger(__name__)
 def init(args: Arguments | None = None, should_init_logs: bool = True) -> Arguments:
     """Bootstrap (reference ``__init__.py:27-93``): load config, seed RNGs,
     init security/DP singletons, per-platform setup."""
+    t_init = time.perf_counter()
     if args is None:
         args = load_arguments()
     if hasattr(args, "validate"):
@@ -135,6 +137,11 @@ def init(args: Arguments | None = None, should_init_logs: bool = True) -> Argume
         # reference update_client_id_list (:265): synthesize [1..N]
         n = int(getattr(args, "client_num_in_total", 0) or 0)
         args.client_id_list = list(range(1, n + 1))
+    # init runs before obs.configure, so no span can time it: the registry
+    # is up by now and keeps its seconds (set-up's share of this call)
+    from .core import obs as _obs
+
+    _obs.gauge_set("startup.init_seconds", time.perf_counter() - t_init)
     _logger.info("fedml_tpu %s initialized (training_type=%s backend=%s)",
                  __version__, getattr(args, "training_type", None), getattr(args, "backend", None))
     return args

@@ -183,6 +183,15 @@ Observability knobs (``tracking_args`` or ``obs_args``; consumed by
   than ``factor * median(previous rounds)`` gets a ``slow_round`` span
   event (straggler flagging in ``tools/trace_report.py`` uses the same
   factor).
+* ``enable_profiler`` (bool, default False) / ``profiler_dir`` (path,
+  default ``<log_file_dir>/xla_trace``) — the XLA simulator's bounded
+  device trace: ``jax.profiler`` records rounds 1-3 of the run (round 0
+  compiles; the second to fourth rounds after a resume) into
+  ``profiler_dir`` and stops.  With ``obs_trace`` on, the host line of
+  that trace carries the ``sim.train`` / ``round`` / ``round.select`` /
+  ``round.pack`` / ``round.dispatch`` / ``round.wait`` / ``round.close``
+  spans on the device's clock; the ``fed.*`` scopes and the ``flash_*``
+  kernel names are in the programs either way.
 * ``obs_flight_capacity`` (int >= 0, default 2048) — size of the flight
   recorder's in-memory ring of recent telemetry records; 0 disables the
   recorder entirely.

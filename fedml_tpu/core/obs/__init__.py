@@ -69,6 +69,7 @@ __all__ = [
     "maybe_export_metrics", "slow_round_factor",
     "flight_recorder", "flight_dump", "exporter",
     "sample_resource_gauges", "compile_seconds_total",
+    "trace_seconds_total", "compiles_total",
     "ClientTelemetry", "TelemetryMerger", "TOPIC_TELEMETRY",
     "telemetry_enabled", "telemetry_flush_s",
     "make_client_telemetry", "make_telemetry_merger",
@@ -380,24 +381,48 @@ def sample_resource_gauges() -> None:
         pass
 
 
-# XLA compile-time accumulator: jax.monitoring fires
-# /jax/core/compile/backend_compile_duration for EVERY backend compile in
-# the process (round fns, eval fns, the agg plane), so one listener gives
-# the compile side of the compile-vs-execute split without touching any
-# hot path.  Registered once per process; reads the live _ctx per event.
-_compile_state = {"lock": threading.Lock(), "total": 0.0, "registered": False}
+# XLA start-up accounting: jax.monitoring fires one duration event per
+# jaxpr trace, per lowering to MLIR and per backend compile in the process
+# (round fns, eval fns, the agg plane) and one plain event per persistent
+# compilation-cache hit or write, so two listeners give the compile side of
+# the compile-vs-execute split, and what a start pays before its first
+# round, without touching any hot path.  Registered once per process; they
+# read the live _ctx per event.
+_BACKEND_COMPILE = "backend_compile_duration"
+_TRACE_EVENTS = ("jaxpr_trace_duration", "jaxpr_to_mlir_module_duration")
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "xla.cache_hits",
+                 "/jax/compilation_cache/cache_misses": "xla.cache_misses"}
+_compile_state = {"lock": threading.Lock(), "total": 0.0, "trace": 0.0,
+                  "requests": 0, "cache_hits": 0, "registered": False}
 
 
 def _on_jax_event_duration(event: str, duration: float, **kw: Any) -> None:
-    if not _ctx.get("enabled") or not str(event).endswith(
-            "backend_compile_duration"):
+    if not _ctx.get("enabled"):
+        return
+    event = str(event)
+    if event.endswith(_TRACE_EVENTS):
+        with _compile_state["lock"]:
+            _compile_state["trace"] += float(duration)
+        return
+    if not event.endswith(_BACKEND_COMPILE):
         return
     with _compile_state["lock"]:
         _compile_state["total"] += float(duration)
+        _compile_state["requests"] += 1
     try:
         _registry.histogram_observe("xla.compile_seconds", float(duration))
     except Exception:
         pass
+
+
+def _on_jax_event(event: str, **kw: Any) -> None:
+    name = _CACHE_EVENTS.get(str(event))
+    if name is None or not _ctx.get("enabled"):
+        return
+    if name == "xla.cache_hits":
+        with _compile_state["lock"]:
+            _compile_state["cache_hits"] += 1
+    _registry.counter_inc(name)
 
 
 def _register_compile_listener() -> None:
@@ -408,6 +433,7 @@ def _register_compile_listener() -> None:
 
         _monitoring.register_event_duration_secs_listener(
             _on_jax_event_duration)
+        _monitoring.register_event_listener(_on_jax_event)
         _compile_state["registered"] = True
     except Exception:  # jax absent or API moved: attribution degrades
         pass
@@ -416,9 +442,26 @@ def _register_compile_listener() -> None:
 def compile_seconds_total() -> float:
     """Cumulative XLA backend-compile seconds observed so far; snapshot
     before/after a round call and the difference is that round's compile
-    share."""
+    share.  A program loaded from the persistent cache counts its load."""
     with _compile_state["lock"]:
         return float(_compile_state["total"])
+
+
+def trace_seconds_total() -> float:
+    """Cumulative seconds jax spent tracing Python to jaxprs and lowering
+    them to MLIR: what a start pays again for every program even when the
+    persistent cache saves its compile."""
+    with _compile_state["lock"]:
+        return float(_compile_state["trace"])
+
+
+def compiles_total() -> int:
+    """How many programs the XLA backend compiled so far: the compile
+    requests less those the persistent cache served (``xla.cache_hits``).
+    With a warm cache that leaves the programs under jax's cache
+    thresholds, which compile on every start."""
+    with _compile_state["lock"]:
+        return int(_compile_state["requests"] - _compile_state["cache_hits"])
 
 
 # -- span helpers (no-ops until configure) ----------------------------------

@@ -151,12 +151,13 @@ def build_packed_device_fn(
             # to streaming HBM bandwidth), then the loop reads contiguous
             # slices.  HBM cost: S_bucket * B * sample (the simulator trims
             # S to a power-of-two bucket of the round's real step count).
-            bx_stream = jnp.take(x_all, idx.reshape(-1), axis=0).reshape(
-                idx.shape + x_all.shape[1:]
-            )
-            by_stream = jnp.take(y_all, idx.reshape(-1), axis=0).reshape(
-                idx.shape + y_all.shape[1:]
-            )
+            with jax.named_scope("fed.gather"):
+                bx_stream = jnp.take(x_all, idx.reshape(-1), axis=0).reshape(
+                    idx.shape + x_all.shape[1:]
+                )
+                by_stream = jnp.take(y_all, idx.reshape(-1), axis=0).reshape(
+                    idx.shape + y_all.shape[1:]
+                )
         params0 = variables["params"]
         other0 = {k: v for k, v in variables.items() if k != "params"}
         opt0 = tx.init(params0)
@@ -184,14 +185,7 @@ def build_packed_device_fn(
             lambda t: jnp.zeros((slots_per_device,) + t.shape, jnp.float32), out_t
         )
 
-        def body(carry):
-            (step, params, other, opt_state, c_steps, c_loss, c_cnt,
-             acc, wsum, lsum, cnt, ext, outs) = carry
-            if pregather:
-                bx, by = bx_stream[step], by_stream[step]
-            else:
-                bx = jnp.take(x_all, idx[step], axis=0)
-                by = jnp.take(y_all, idx[step], axis=0)
+        def local_step(step, params, other, opt_state, bx, by):
             bmask = mask[step]
             key = jax.random.fold_in(rng, step)
             (lval, updated), grads = jax.value_and_grad(
@@ -222,6 +216,20 @@ def build_packed_device_fn(
                 if updated:
                     other = jax.tree_util.tree_map(
                         lambda n, o: jnp.where(any_valid, n, o), updated, other)
+            return params, other, opt_state, lval, bmask
+
+        def body(carry):
+            (step, params, other, opt_state, c_steps, c_loss, c_cnt,
+             acc, wsum, lsum, cnt, ext, outs) = carry
+            with jax.named_scope("fed.gather"):
+                if pregather:
+                    bx, by = bx_stream[step], by_stream[step]
+                else:
+                    bx = jnp.take(x_all, idx[step], axis=0)
+                    by = jnp.take(y_all, idx[step], axis=0)
+            with jax.named_scope("fed.local_step"):
+                params, other, opt_state, lval, bmask = local_step(
+                    step, params, other, opt_state, bx, by)
             c_steps = c_steps + (jnp.sum(bmask) > 0).astype(jnp.float32)
             c_loss = c_loss + lval * jnp.sum(bmask)
             c_cnt = c_cnt + jnp.sum(bmask)
@@ -272,12 +280,13 @@ def build_packed_device_fn(
             def keep(ops):
                 return ops
 
-            (params, other, opt_state, c_steps, c_loss, c_cnt,
-             acc, wsum, lsum, cnt, ext, outs) = jax.lax.cond(
-                boundary[step] > 0, flush, keep,
+            with jax.named_scope("fed.flush"):
                 (params, other, opt_state, c_steps, c_loss, c_cnt,
-                 acc, wsum, lsum, cnt, ext, outs),
-            )
+                 acc, wsum, lsum, cnt, ext, outs) = jax.lax.cond(
+                    boundary[step] > 0, flush, keep,
+                    (params, other, opt_state, c_steps, c_loss, c_cnt,
+                     acc, wsum, lsum, cnt, ext, outs),
+                )
             return (step + 1, params, other, opt_state, c_steps, c_loss, c_cnt,
                     acc, wsum, lsum, cnt, ext, outs)
 

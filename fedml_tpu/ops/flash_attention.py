@@ -201,6 +201,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret,
         ],
         scratch_shapes=_scratch(block_q, D),
         interpret=interpret,
+        name="flash_fwd",
     )(qb, kb, vb)
     out = _from_bh(out, B, L, H, D)
     return (out, lse) if with_lse else out
@@ -347,6 +348,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct((B * H, Lp, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qb, kb, vb, dob, lse, delta)
     col_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),  # q
@@ -374,6 +376,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qb, kb, vb, dob, lse, delta)
     return (_from_bh(dq, B, L, H, D), _from_bh(dk, B, L, H, D),
             _from_bh(dv, B, L, H, D))
@@ -554,6 +557,7 @@ def _flash_shard_update_impl(q, k, v, q_pos, k_pos, m, l, o, causal,
             jax.ShapeDtypeStruct((B * H, Lqp, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_shard_update",
     )(k_first, q_last, qb, kb, vb, qp, kp, mb, lb, ob)
     m_out = mo[:, 0, :Lq].reshape(B, H, Lq)
     l_out = lo[:, 0, :Lq].reshape(B, H, Lq)

@@ -57,9 +57,52 @@ from .algorithms import create_inmesh_algorithm
 
 logger = logging.getLogger(__name__)
 
+# enable_profiler traces the second to fourth rounds a train() call runs
+# (rounds 1-3 of a fresh run; the first round compiles) and stops
+PROFILED_ROUNDS = (1, 3)
+
+
+class _Phase:
+    """One timed phase: ``with _Phase(record, name, parent) as ph`` opens the
+    obs span ``name`` (``annotate=True``: on the profiler's host line, on the
+    device trace's clock), times the body with ONE pair of
+    ``time.perf_counter`` reads and gives that number both to the span's
+    ``duration_s`` and to ``record[<last part of name>_s]`` — the record is
+    filled whether or not obs is configured.  ``ph.attrs`` collects what
+    the body learns, for the span's end record."""
+
+    __slots__ = ("record", "key", "span", "attrs", "t0")
+
+    def __init__(self, record: Dict[str, Any], name: str, parent=None,
+                 round_idx: int = None, seq: int = 0):
+        self.record, self.key = record, name.rpartition(".")[2] + "_s"
+        self.attrs: Dict[str, Any] = {}
+        self.span = obs.span(name, parent, round_idx=round_idx, seq=seq,
+                             annotate=True)
+
+    @property
+    def ctx(self):
+        return self.span.ctx
+
+    def __enter__(self) -> "_Phase":
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self.t0
+        self.record[self.key] = dt
+        self.span.end(duration_s=dt, **self.attrs)
+
 
 class XLASimulator:
     def __init__(self, args, dataset, model, mesh: Mesh = None):
+        # start-up's seconds by phase (sim.build and its children), kept like
+        # round_log for whoever reads set-up without an obs sink
+        self.startup_log: Dict[str, float] = {}
+        with _Phase(self.startup_log, "sim.build") as build:
+            self._build(args, dataset, model, mesh, build.ctx)
+
+    def _build(self, args, dataset, model, mesh, build_ctx):
         self.args = args
         (
             self.train_num,
@@ -116,9 +159,12 @@ class XLASimulator:
         self._multihot_labels = ds in _TAG_DATASETS
         self.loss_kind = "bce" if self._multihot_labels else loss_kind_for_dataset(ds)
 
-        self._pack_data()
-        sample = jnp.asarray(self.train_global[0][:1])
-        self.variables = init_variables(model, sample, seed=int(getattr(args, "random_seed", 0)))
+        with _Phase(self.startup_log, "sim.pack_data", build_ctx):
+            self._pack_data()
+        with _Phase(self.startup_log, "sim.init_variables", build_ctx):
+            sample = jnp.asarray(self.train_global[0][:1])
+            self.variables = init_variables(
+                model, sample, seed=int(getattr(args, "random_seed", 0)))
         self.algo = create_inmesh_algorithm(args)
         self.server_state = self.algo.init_server_state(self.variables)
         self.client_state = self.algo.init_client_state(self.num_clients, self.variables)
@@ -137,14 +183,15 @@ class XLASimulator:
         # security tail now: each of those programs ends at the psum'd
         # accumulator and the model-sharded GSPMD tail applies the server
         # step — defended + model-sharded rounds run, they don't degrade
-        if self.packed:
-            self._build_packed_round_fn()
-        else:
-            self._build_round_fn()
-        if self.needs_stack:
-            self._build_security_fn()
-        if self.sharded_state:
-            self._build_server_tail()
+        with _Phase(self.startup_log, "sim.build_round_fn", build_ctx):
+            if self.packed:
+                self._build_packed_round_fn()
+            else:
+                self._build_round_fn()
+            if self.needs_stack:
+                self._build_security_fn()
+            if self.sharded_state:
+                self._build_server_tail()
 
         self.runtime_estimator = RuntimeEstimator(self.n_dev, uniform_devices=True)
         self.scheduler = SeqTrainScheduler(self.n_dev, estimator=self.runtime_estimator)
@@ -185,6 +232,10 @@ class XLASimulator:
         self.round_times: List[float] = []
         self.round_losses: List[float] = []
         self.samples_per_round: List[int] = []
+        # one dict a round: the wall time (the round_times entry), the host
+        # phases that split it, and what the round carried — filled with obs
+        # off too, as round_times is
+        self.round_log: List[Dict[str, Any]] = []
         self.samples_trained = 0
         self._rng = jax.random.PRNGKey(int(getattr(args, "random_seed", 0)) + 11)
 
@@ -291,7 +342,8 @@ class XLASimulator:
             grad_hook=algo.grad_hook(), loss=self.loss_kind,
         )
 
-        def per_device(variables, server_state, x_all, y_all, idx_l, counts_l, rngs_l, cex_l):
+        def fedml_round_padded(variables, server_state, x_all, y_all, idx_l,
+                               counts_l, rngs_l, cex_l):
             # idx_l: [C/n_dev, padded_n]; counts_l: [C/n_dev]; rngs_l: [C/n_dev, 2]
             # cex_l: per-client algorithm inputs (leading axis C/n_dev)
             per_dev = idx_l.shape[0]
@@ -301,12 +353,14 @@ class XLASimulator:
             )
 
             def one_client(idx_row, n_i, rng, cex):
-                x = jnp.take(x_all, idx_row, axis=0)
-                y = jnp.take(y_all, idx_row, axis=0)
-                result = local_train(
-                    variables, x, y, n_i, rng,
-                    extra=algo.engine_extra(cex, server_state),
-                )
+                with jax.named_scope("fed.gather"):
+                    x = jnp.take(x_all, idx_row, axis=0)
+                    y = jnp.take(y_all, idx_row, axis=0)
+                with jax.named_scope("fed.local_step"):
+                    result = local_train(
+                        variables, x, y, n_i, rng,
+                        extra=algo.engine_extra(cex, server_state),
+                    )
                 if post_train is not None:
                     # in-mesh local DP: per-client noise before aggregation
                     result = result._replace(variables=post_train(
@@ -314,11 +368,13 @@ class XLASimulator:
                     ))
                 w = n_i.astype(jnp.float32)
                 real = (n_i > 0).astype(jnp.float32)
-                wv = jax.tree_util.tree_map(
-                    lambda p: w * p.astype(jnp.float32), result.variables
-                )
-                contrib = algo.client_contrib(variables, result, w, real, cex, server_state)
-                out = algo.client_out(variables, result, real, cex, server_state)
+                with jax.named_scope("fed.flush"):
+                    wv = jax.tree_util.tree_map(
+                        lambda p: w * p.astype(jnp.float32), result.variables
+                    )
+                    contrib = algo.client_contrib(
+                        variables, result, w, real, cex, server_state)
+                    out = algo.client_out(variables, result, real, cex, server_state)
                 if stacked:
                     # per-client update stack for the security program (the
                     # weights are the host-known sample counts); "tau" = the
@@ -335,8 +391,10 @@ class XLASimulator:
             def train_chunk(carry, inp):
                 acc, wsum, lsum, ext = carry
                 wv, w, wl, contrib, out = vclients(*inp)  # leading axis k
-                acc = jax.tree_util.tree_map(lambda a, p: a + p.sum(0), acc, wv)
-                ext = jax.tree_util.tree_map(lambda e, c: e + c.sum(0), ext, contrib)
+                with jax.named_scope("fed.flush"):
+                    acc = jax.tree_util.tree_map(lambda a, p: a + p.sum(0), acc, wv)
+                    ext = jax.tree_util.tree_map(
+                        lambda e, c: e + c.sum(0), ext, contrib)
                 return (acc, wsum + w.sum(), lsum + wl.sum(), ext), out
 
             chunked = jax.tree_util.tree_map(
@@ -353,25 +411,28 @@ class XLASimulator:
                 lambda o: o.reshape((per_dev,) + o.shape[2:]), outs
             )
             # the "fedml_nccl_reduce": one psum over ICI
-            wsum = jax.lax.psum(wsum, "client")
-            lsum = jax.lax.psum(lsum, "client")
-            ext = jax.lax.psum(ext, "client")
+            with jax.named_scope("fed.exchange"):
+                wsum = jax.lax.psum(wsum, "client")
+                lsum = jax.lax.psum(lsum, "client")
+                ext = jax.lax.psum(ext, "client")
             mean_loss = lsum / jnp.maximum(wsum, 1e-9)
             if stacked:
                 # aggregation + server step move to the security program,
                 # which consumes the sharded update stack (XLA drops the
                 # unused acc accumulator — no wasted model-size psum)
                 return mean_loss, outs, ext
-            acc = jax.lax.psum(acc, "client")
+            with jax.named_scope("fed.exchange"):
+                acc = jax.lax.psum(acc, "client")
             if sharded:
                 # server_state=sharded: the algorithm's server step moves to
                 # the separate model-sharded GSPMD tail program — this
                 # program ends at the reduced accumulator
                 return acc, wsum, ext, mean_loss, outs
             # algorithm server step, replicated — still inside the XLA program
-            new_global, new_state = algo.server_update(
-                acc, wsum, ext, variables, server_state
-            )
+            with jax.named_scope("fed.server_step"):
+                new_global, new_state = algo.server_update(
+                    acc, wsum, ext, variables, server_state
+                )
             return new_global, new_state, mean_loss, outs
 
         if stacked:
@@ -382,7 +443,7 @@ class XLASimulator:
             out_specs = (P(), P(), P(), P("client"))
         self._round_fn = jax.jit(
             shard_map(
-                per_device,
+                fedml_round_padded,
                 mesh=mesh,
                 in_specs=(P(), P(), P(), P(), P("client"), P("client"), P("client"), P("client")),
                 out_specs=out_specs,
@@ -437,7 +498,8 @@ class XLASimulator:
         algo = self.algo
 
         def tail(variables, server_state, acc, wsum, ext):
-            return algo.server_update(acc, wsum, ext, variables, server_state)
+            with jax.named_scope("fed.server_step"):
+                return algo.server_update(acc, wsum, ext, variables, server_state)
 
         self._server_tail = jax.jit(
             tail, donate_argnums=(0, 1, 2),
@@ -469,28 +531,31 @@ class XLASimulator:
             capture_updates=stacked,
         )
 
-        def per_device(variables, server_state, x_all, y_all, idx, mask, boundary,
-                       weight, slot, n_steps, rngs, cex):
+        def fedml_round_packed(variables, server_state, x_all, y_all, idx, mask,
+                               boundary, weight, slot, n_steps, rngs, cex):
             # arrays with a [n_dev, ...] leading axis arrive as [1, ...]
             acc, wsum, lsum, cnt, ext, outs = device_fn(
                 variables, server_state, x_all, y_all, idx[0], mask[0],
                 boundary[0], weight[0], slot[0], n_steps[0], rngs[0], cex,
             )
-            lsum = jax.lax.psum(lsum, "client")
-            cnt = jax.lax.psum(cnt, "client")
-            ext = jax.lax.psum(ext, "client")
+            with jax.named_scope("fed.exchange"):
+                lsum = jax.lax.psum(lsum, "client")
+                cnt = jax.lax.psum(cnt, "client")
+                ext = jax.lax.psum(ext, "client")
             mean_loss = lsum / jnp.maximum(cnt, 1.0)
             if stacked:
                 return mean_loss, outs, ext
-            acc = jax.lax.psum(acc, "client")
-            wsum = jax.lax.psum(wsum, "client")
+            with jax.named_scope("fed.exchange"):
+                acc = jax.lax.psum(acc, "client")
+                wsum = jax.lax.psum(wsum, "client")
             if sharded:
                 # program ends at the reduced accumulator; the model-sharded
                 # tail applies the server step (same split as _build_round_fn)
                 return acc, wsum, ext, mean_loss, outs
-            new_global, new_state = algo.server_update(
-                acc, wsum, ext, variables, server_state
-            )
+            with jax.named_scope("fed.server_step"):
+                new_global, new_state = algo.server_update(
+                    acc, wsum, ext, variables, server_state
+                )
             return new_global, new_state, mean_loss, outs
 
         if stacked:
@@ -501,7 +566,7 @@ class XLASimulator:
             out_specs = (P(), P(), P(), P("client"))
         self._round_fn = jax.jit(
             shard_map(
-                per_device,
+                fedml_round_packed,
                 mesh=mesh,
                 in_specs=(P(), P(), P(), P(), P("client"), P("client"), P("client"),
                           P("client"), P("client"), P("client"), P("client"), P("client")),
@@ -666,11 +731,13 @@ class XLASimulator:
         self._bucket_compiling = s_bucket not in seen
         seen.add(s_bucket)
         self._s_bucket = s_bucket
+        self._steps_max = s_used
         sched = sched._replace(
             idx=sched.idx[:, :s_bucket], mask=sched.mask[:, :s_bucket],
             boundary=sched.boundary[:, :s_bucket], weight=sched.weight[:, :s_bucket],
             slot=sched.slot[:, :s_bucket],
         )
+        self._h2d_bytes = sum(int(a.nbytes) for a in sched)
         return tuple(jnp.asarray(a) for a in sched)
 
     def _client_steps(self, n: int) -> int:
@@ -785,11 +852,16 @@ class XLASimulator:
                     c, self._async_t + float(self._async_durations[c]))
 
     def train(self) -> Dict[str, Any]:
-        from ...core.checkpoint import checkpoint_frequency, maybe_checkpointer
+        # train()'s own preamble and tail on the same line as the rounds: the
+        # benchmark pays them once a round (a unit is one run())
+        with obs.span("sim.train", None, seq=len(self.round_times),
+                      annotate=True):
+            return self._train()
+
+    def _train(self) -> Dict[str, Any]:
+        from ...core.checkpoint import maybe_checkpointer
 
         comm_round = int(self.args.comm_round)
-        freq = int(getattr(self.args, "frequency_of_the_test", 10))
-        eval_enabled = freq > 0  # freq <= 0 disables eval (throughput benches)
         last: Dict[str, Any] = {}
         ckpt = maybe_checkpointer(self.args)
         start_round = 0
@@ -819,33 +891,64 @@ class XLASimulator:
                 self._defense_n = int(state.get("defense_n", -1))
             start_round = step + 1
             logger.info("resumed from checkpoint round %d", step)
-        profiling = bool(getattr(self.args, "enable_profiler", False))
-        if profiling:
-            # whole-run XLA trace (TensorBoard-viewable; the reference's
-            # profiler posts wall-clock events — on TPU the on-device
-            # timeline is the thing worth capturing)
+        # enable_profiler: a bounded device trace (TensorBoard/XProf-viewable)
+        # of PROFILED_ROUNDS, with the round.* phase spans on its host line
+        prof_dir = None
+        if bool(getattr(self.args, "enable_profiler", False)):
             prof_dir = str(getattr(self.args, "profiler_dir", "")
                            or os.path.join(
                                str(getattr(self.args, "log_file_dir", ".") or "."),
                                "xla_trace"))
-            jax.profiler.start_trace(prof_dir)
-            logger.info("jax profiler trace -> %s", prof_dir)
+        prof_first, prof_last = (start_round + r for r in PROFILED_ROUNDS)
+        profiling = False
         # in-process loopback telemetry (cohort-level: the in-mesh round has
         # no per-client wall times, so the remote "client.train" leg covers
         # the whole cohort's execute time) — keeps the trace_report shape
         # identical between simulation and distributed runs
         tele_cap = obs.make_client_telemetry(0)
         tele_merger = obs.make_telemetry_merger()
-        for round_idx in range(start_round, comm_round):
-            t0 = time.time()
-            compile_s0 = obs.compile_seconds_total()
-            # the whole round is one (or two) compiled XLA programs, so the
-            # round root is the only meaningful span here; annotate=True nests
-            # it inside the device trace when enable_profiler is on
-            rsp = obs.round_span(
-                round_idx, annotate=True,
-                mode="simulation_xla_async" if self.async_mode
-                else "simulation_xla")
+        try:
+            for round_idx in range(start_round, comm_round):
+                if prof_dir is not None and round_idx == prof_first:
+                    jax.profiler.start_trace(prof_dir)
+                    profiling = True
+                    logger.info("jax profiler trace of rounds %d-%d -> %s",
+                                prof_first, prof_last, prof_dir)
+                evaluated = self._run_round(round_idx, comm_round, ckpt,
+                                            tele_cap, tele_merger)
+                if evaluated is not None:
+                    last = evaluated
+                if profiling and round_idx == prof_last:
+                    jax.profiler.stop_trace()
+                    profiling = False
+        finally:
+            if profiling:  # the run ended, or raised, inside the traced rounds
+                jax.profiler.stop_trace()
+        if prof_dir is not None and comm_round <= prof_first:
+            logger.warning(
+                "enable_profiler traces rounds %d-%d of a run and this one ended "
+                "at round %d: nothing was traced", prof_first, prof_last,
+                comm_round - 1)
+        return last
+
+    def _run_round(self, round_idx: int, comm_round: int, ckpt, tele_cap,
+                   tele_merger):
+        """One round, split into the host phases ``round.select`` / ``pack`` /
+        ``dispatch`` / ``wait`` / ``close`` (children of the ``round`` root,
+        each timed once by :class:`_Phase` into the span and into
+        ``round_log``).  Returns the eval record where the round evaluated."""
+        from ...core import mlops
+        from ...core.checkpoint import checkpoint_frequency
+
+        freq = int(getattr(self.args, "frequency_of_the_test", 10))
+        epochs = int(getattr(self.args, "epochs", 1))
+        rec: Dict[str, Any] = {"round": round_idx}
+        t0 = time.perf_counter()
+        compile_s0 = obs.compile_seconds_total()
+        rsp = obs.round_span(
+            round_idx, annotate=True,
+            mode="simulation_xla_async" if self.async_mode else "simulation_xla")
+        with _Phase(rec, "round.select", rsp.ctx, round_idx) as ph:
             if self.async_mode:
                 sampled, stal_map = self._async_next_flush()
                 self.algo.set_staleness(stal_map)
@@ -856,7 +959,6 @@ class XLASimulator:
             # participation mask as the compiled round sees it: a sampled
             # client with zero local samples contributes nothing in-mesh
             participated = (counts > 0).astype(np.float32)
-            self._rng, sub = jax.random.split(self._rng)
             cex = self.algo.gather_client_extras(
                 self.client_state, ids, participated, round_idx
             )
@@ -867,6 +969,9 @@ class XLASimulator:
                 # path, where add_noise spends before producing the noised
                 # update): budget exhaustion must abort the round, not trail it
                 dp.spend_budget(int(participated.sum()))
+            ph.attrs["n_sampled"] = len(sampled)
+        with _Phase(rec, "round.pack", rsp.ctx, round_idx) as ph:
+            self._rng, sub = jax.random.split(self._rng)
             if self.packed:
                 packed = self._packed_inputs(np.asarray(ids), counts, round_idx)
                 dev_rngs = jax.random.split(
@@ -874,11 +979,24 @@ class XLASimulator:
                 )
                 round_inputs = (self.variables, self.server_state, self.x_all,
                                 self.y_all, *packed, dev_rngs, cex)
+                ph.attrs.update(s_bucket=self._s_bucket, steps_max=self._steps_max,
+                                h2d_bytes=self._h2d_bytes)
+                if self._bucket_compiling:
+                    obs.span_event("bucket_compile", ph.ctx, round_idx=round_idx,
+                                   s_bucket=self._s_bucket)
             else:
                 rngs = jax.random.split(jax.random.fold_in(sub, round_idx), len(ids))
-                idx_rows = self.client_idx[jnp.asarray(ids)]
+                ids_up, counts_up = jnp.asarray(ids), jnp.asarray(counts)
+                idx_rows = self.client_idx[ids_up]
                 round_inputs = (self.variables, self.server_state, self.x_all,
-                                self.y_all, idx_rows, jnp.asarray(counts), rngs, cex)
+                                self.y_all, idx_rows, counts_up, rngs, cex)
+                # shape-static: every client pays padded_n, on every device
+                ph.attrs.update(
+                    s_bucket=0, h2d_bytes=int(ids_up.nbytes + counts_up.nbytes),
+                    steps_max=(len(ids) // self.n_dev)
+                    * (self.padded_n // self.batch_size) * epochs)
+            rec.update(ph.attrs)
+        with _Phase(rec, "round.dispatch", rsp.ctx, round_idx) as ph:
             if self.needs_stack:
                 # security path: the round returns the sharded per-client
                 # update stack; the second jitted program runs stacked model
@@ -916,7 +1034,7 @@ class XLASimulator:
                         skey,
                         dstate,
                     )
-                    with obs.span("aggregate.reduce", rsp.ctx,
+                    with obs.span("aggregate.reduce", ph.ctx,
                                   round_idx=round_idx,
                                   n_clients=int(real_sel.size),
                                   mode="inmesh"):
@@ -929,7 +1047,7 @@ class XLASimulator:
                             acc_d, wsum_d, ext_d, self._defense_state = (
                                 self._security_fn(*sec_inputs))
                             var_sh, state_sh, repl = self._tail_shardings
-                            t_tail = time.time()
+                            t_tail = time.perf_counter()
                             with warnings.catch_warnings():
                                 warnings.filterwarnings(
                                     "ignore",
@@ -943,7 +1061,7 @@ class XLASimulator:
                                 )
                             jax.block_until_ready(self.variables)
                             obs.histogram_observe(
-                                "server_opt.step_seconds", time.time() - t_tail,
+                                "server_opt.step_seconds", time.perf_counter() - t_tail,
                                 labels={"policy": type(self.algo).__name__,
                                         "mode": "inmesh"})
                             if self._tail_subset:
@@ -981,8 +1099,8 @@ class XLASimulator:
                 # the algorithm's server step on donated resident buffers
                 acc, wsum, ext, mean_loss, outs = self._round_fn(*round_inputs)
                 var_sh, state_sh, repl = self._tail_shardings
-                t_tail = time.time()
-                with obs.span("round.server_update", rsp.ctx,
+                t_tail = time.perf_counter()
+                with obs.span("round.server_update", ph.ctx,
                               round_idx=round_idx,
                               n_clients=int(participated.sum()),
                               mode="inmesh", policy=type(self.algo).__name__):
@@ -1000,7 +1118,7 @@ class XLASimulator:
                         )
                     jax.block_until_ready(self.variables)
                 obs.histogram_observe(
-                    "server_opt.step_seconds", time.time() - t_tail,
+                    "server_opt.step_seconds", time.perf_counter() - t_tail,
                     labels={"policy": type(self.algo).__name__,
                             "mode": "inmesh"})
                 if self._tail_subset:
@@ -1011,6 +1129,7 @@ class XLASimulator:
                 self.variables, self.server_state, mean_loss, outs = self._round_fn(
                     *round_inputs
                 )
+        with _Phase(rec, "round.wait", rsp.ctx, round_idx) as ph:
             self.client_state = self.algo.apply_client_outs(self.client_state, ids, outs)
             self.algo.host_round_end(ids, participated, round_idx)
             if self.async_mode:
@@ -1018,7 +1137,7 @@ class XLASimulator:
                 # the compiled round): staleness distribution + buffer shape
                 # for trace_report's async columns
                 svals = list(stal_map.values()) or [0]
-                with obs.span("buffer.flush", rsp.ctx, round_idx=round_idx,
+                with obs.span("buffer.flush", ph.ctx, round_idx=round_idx,
                               n_deltas=len(sampled), reason="full",
                               capacity=self._async_cap,
                               staleness_min=int(min(svals)),
@@ -1032,7 +1151,10 @@ class XLASimulator:
             if dp.is_global_dp_enabled():
                 self.variables = dp.add_global_noise(self.variables)
             jax.block_until_ready(self.variables)
-            dt = time.time() - t0
+        # the round's wall time: its start to the new global model being ready
+        dt = time.perf_counter() - t0
+        evaluated = None
+        with _Phase(rec, "round.close", rsp.ctx, round_idx) as ph:
             if obs.enabled() and len(self.round_times) >= 3:
                 med = float(np.median(self.round_times))
                 if dt > obs.slow_round_factor() * med:
@@ -1044,14 +1166,10 @@ class XLASimulator:
                             labels={"path": "inmesh"})
             # compile-vs-execute attribution: the jax.monitoring listener
             # accumulated every backend compile this round triggered (round
-            # fn, security fn, eval fn); the rest of the wall time is
-            # execute + host orchestration
+            # fn, security fn); the rest of the wall time is execute + host
+            # orchestration
             compile_s = max(0.0, obs.compile_seconds_total() - compile_s0)
-            if compile_s > 0.0:
-                obs.histogram_observe("round.compile_seconds", compile_s)
-            rsp.end(reason="closed", loss=float(mean_loss),
-                    compile_s=round(compile_s, 6),
-                    execute_s=round(max(0.0, dt - compile_s), 6))
+            loss = float(mean_loss)
             if tele_cap is not None and tele_merger is not None:
                 tctx = tele_cap.record_span(
                     "client.train", max(0.0, dt - compile_s), parent=rsp.ctx,
@@ -1060,16 +1178,13 @@ class XLASimulator:
                     tele_cap.record_span(
                         "client.train.compile", compile_s, parent=tctx,
                         round_idx=round_idx)
-                tele_cap.record_span(
-                    "client.train.step", max(0.0, dt - compile_s),
-                    parent=tctx, round_idx=round_idx)
                 tele_cap.sample_resources()
                 tele_blob = tele_cap.drain()
                 if tele_blob:
                     tele_merger.merge(tele_blob)
             obs.maybe_export_metrics()
             self.round_times.append(dt)
-            self.round_losses.append(float(mean_loss))
+            self.round_losses.append(loss)
             if round_idx > 0:  # round 0 is dominated by XLA compile
                 # The round's wall time is set by the heaviest mesh slot.
                 # Packed: record max device STEPS — the while_loop's actual
@@ -1079,26 +1194,23 @@ class XLASimulator:
                 # round is shape-static (every client pays padded_n), so the
                 # model degenerates to count-balancing there by design.
                 if self.packed:
-                    if getattr(self, "_bucket_compiling", False):
+                    if self._bucket_compiling:
                         pass  # compile-dominated round: would poison the fit
                     else:
-                        epochs_ = int(getattr(self.args, "epochs", 1))
                         steps2d = -(-counts.reshape(self.n_dev, -1)
-                                    // self.batch_size) * epochs_
+                                    // self.batch_size) * epochs
                         self.runtime_estimator.record(
                             0, int(steps2d.sum(axis=1).max()), dt
                         )
                 else:
                     dev_loads = counts.reshape(self.n_dev, -1).sum(axis=1)
                     self.runtime_estimator.record(0, int(dev_loads.max()), dt)
-            epochs = int(getattr(self.args, "epochs", 1))
-            self.samples_per_round.append(int(counts.sum()) * epochs)
-            self.samples_trained += int(counts.sum()) * epochs
+            samples = int(counts.sum()) * epochs
+            self.samples_per_round.append(samples)
+            self.samples_trained += samples
             self.metrics.log(
-                {"round": round_idx, "round_time_s": round(dt, 4), "train_loss": float(mean_loss)}
+                {"round": round_idx, "round_time_s": round(dt, 4), "train_loss": loss}
             )
-            from ...core import mlops
-
             mlops.log_round_info(comm_round, round_idx)
             # population accounting for the synchronous round: everyone
             # sampled was invited and reported; emits cohort_stats
@@ -1108,24 +1220,31 @@ class XLASimulator:
             ):
                 from flax import serialization
 
-                state = {"variables": self.variables, "rng": self._rng,
-                         "server_state": serialization.to_state_dict(self.server_state)}
-                if self.client_state is not None:
-                    state["client_state"] = serialization.to_state_dict(self.client_state)
-                host = self.algo.host_state()
-                if host:
-                    state["algo_host_state"] = host
-                if self.defended and self._defense_state:
-                    state["defense_state"] = {
-                        k: np.asarray(v) for k, v in self._defense_state.items()
-                    }
-                    state["defense_n"] = self._defense_n
-                ckpt.save(round_idx, state)
-            if eval_enabled and (round_idx % freq == 0 or round_idx == comm_round - 1):
-                last = self._test_global(round_idx)
-        if profiling:
-            jax.profiler.stop_trace()
-        return last
+                with obs.span("round.checkpoint", ph.ctx, round_idx=round_idx):
+                    state = {"variables": self.variables, "rng": self._rng,
+                             "server_state": serialization.to_state_dict(self.server_state)}
+                    if self.client_state is not None:
+                        state["client_state"] = serialization.to_state_dict(self.client_state)
+                    host = self.algo.host_state()
+                    if host:
+                        state["algo_host_state"] = host
+                    if self.defended and self._defense_state:
+                        state["defense_state"] = {
+                            k: np.asarray(v) for k, v in self._defense_state.items()
+                        }
+                        state["defense_n"] = self._defense_n
+                    ckpt.save(round_idx, state)
+            if freq > 0 and (round_idx % freq == 0 or round_idx == comm_round - 1):
+                # freq <= 0 disables eval (throughput benches)
+                with obs.span("round.eval", ph.ctx, round_idx=round_idx):
+                    evaluated = self._test_global(round_idx)
+        rec.update(wall_s=dt, compile_s=compile_s, samples=samples, loss=loss)
+        self.round_log.append(rec)
+        # the root ends after round.close, so the tree nests; compile_s and
+        # execute_s keep their meaning (start of the round to the new model)
+        rsp.end(reason="closed", loss=loss, compile_s=round(compile_s, 6),
+                execute_s=round(max(0.0, dt - compile_s), 6))
+        return evaluated
 
     def _test_global(self, round_idx: int) -> Dict[str, Any]:
         self.aggregator.set_model_params(self.variables)

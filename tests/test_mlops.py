@@ -125,11 +125,16 @@ class TestXLAProfilerCapture:
     def test_enable_profiler_writes_trace(self, tmp_path):
         """args.enable_profiler captures a TensorBoard-viewable XLA trace of
         the compiled round (the TPU-first half of the reference's profiler
-        event reporting)."""
+        event reporting): bounded to rounds 1-3 of the run, with the round's
+        host phase spans on the profiler's host line."""
+        import glob
         import os
+
+        import jax
 
         import fedml_tpu
         from fedml_tpu.arguments import Arguments
+        from fedml_tpu.core import mlops
         from fedml_tpu.simulation.xla.fed_sim import XLASimulator
 
         args = Arguments.from_dict({
@@ -140,18 +145,32 @@ class TestXLAProfilerCapture:
             "model_args": {"model": "lr"},
             "train_args": {"federated_optimizer": "FedAvg",
                            "client_num_in_total": 4, "client_num_per_round": 4,
-                           "comm_round": 1, "epochs": 1, "batch_size": 16,
+                           "comm_round": 6, "epochs": 1, "batch_size": 16,
                            "client_optimizer": "sgd", "learning_rate": 0.1},
             "validation_args": {"frequency_of_the_test": 0},
             "comm_args": {"backend": "XLA"},
+            # the phase spans are obs spans: they reach the trace with obs on
+            "tracking_args": {"using_mlops": True, "obs_trace": True},
         }).validate()
         args.enable_profiler = True
         args.profiler_dir = str(tmp_path / "trace")
-        args = fedml_tpu.init(args, should_init_logs=False)
-        dataset, out_dim = fedml_tpu.data.load(args)
-        model = fedml_tpu.models.create(args, out_dim)
-        XLASimulator(args, dataset, model).train()
+        try:
+            args = fedml_tpu.init(args, should_init_logs=False)
+            dataset, out_dim = fedml_tpu.data.load(args)
+            model = fedml_tpu.models.create(args, out_dim)
+            XLASimulator(args, dataset, model).train()
+        finally:
+            mlops.finish()
         dumped = []
         for root, _, files in os.walk(args.profiler_dir):
             dumped += [f for f in files if f.endswith((".pb", ".json.gz", ".xplane.pb"))]
         assert dumped, "no trace files captured"
+        # one trace, stopped after round 3: the rounds' spans on the host line
+        (xplane,) = glob.glob(os.path.join(args.profiler_dir, "**", "*.xplane.pb"),
+                              recursive=True)
+        names = [ev.name for plane in jax.profiler.ProfileData.from_file(xplane).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events]
+        for phase in ("select", "pack", "dispatch", "wait", "close"):
+            assert names.count("round." + phase) == 3, (phase, names.count("round." + phase))
+        assert names.count("round") == 3
