@@ -71,8 +71,10 @@ TRANSFORMER_BATCH, TRANSFORMER_STEPS = 8, 3
 # truth, not a second approximation.
 TOLERANCE = 2e-2
 # (B, L, H, D, dtype): the bench transformer's attention shapes (ragged L,
-# D=64) and the TransformerConfig default head dim (D=32)
-KERNEL_SHAPES = [(8, 1023, 16, 64, "bfloat16"), (2, 256, 8, 32, "float32")]
+# D=64), the TransformerConfig default head dim (D=32), and the benchmark
+# cells' own shape (dsllm7b-sim: 32 heads of 128, L 2,048, batch 2)
+KERNEL_SHAPES = [(8, 1023, 16, 64, "bfloat16"), (2, 256, 8, 32, "float32"),
+                 (2, 2048, 32, 128, "bfloat16")]
 # one- vs four-device runs of the same seed train the same clients on the
 # same batches; they differ in summation order under bf16 compute (measured
 # 7.2e-5 over these four rounds on the v5e, this PR)

@@ -17,6 +17,28 @@ backward re-materializes P blockwise from (q, k, lse) in two passes (a dQ
 pass with k innermost, a dK/dV pass with q innermost), so backward memory
 is O(block²) per core like the forward, never the O(L²) probs matrix.
 
+How blocks are chosen.  A grid step costs about 0.35 µs on a v5e whatever it
+computes, and a 128 x 128 tile's matmuls a tenth of that, so the tiling, not
+the MXU, sets the kernels' time.  ``block_q`` / ``block_k`` left ``None``
+are chosen per kernel by :func:`_choose_blocks`, a pure function of L, D and
+the input dtype: the largest multiples of 128 that divide the lane-rounded
+length, up to the pair the on-chip sweep read best for that kernel
+(``_BLOCK_TARGET``) and inside a VMEM budget written beside it
+(``_VMEM_BUDGET``, ``_vmem_bytes``).  Explicit blocks are taken as given
+(interpret-mode tests pass small ones); there is no option, environment
+variable or run-time autotune.  What was chosen is left as the gauges
+``flash.block_q``, ``flash.block_k`` and ``flash.live_step_share`` per kernel
+name (docs/OBSERVABILITY.md).
+
+Dead tiles cost a step and no copy.  Under the causal mask a tile whose keys
+all lie after its queries (and any tile of padding) is dead: its step still
+runs — the grid is rectangular and no scalar-prefetched schedule is added —
+but computes nothing, and its BlockSpec index is clamped to the last live
+tile of the row (the first of the column in the keys-major pass), which is
+the block already in VMEM, so the pipeline issues no copy for it.  A live
+tile that the diagonal and the padded tail do not cross is all live and takes
+an unmasked path (no iotas, compare or select); only the others build a mask.
+
 Layouts are the ones Mosaic accepts: per-row softmax statistics are
 ``[block_q, 1]`` columns inside a kernel (they broadcast along lanes against
 the ``[block_q, block_k]`` scores) and lane-dense ``[B*H, 1, L]`` rows in HBM,
@@ -26,12 +48,14 @@ block_q]``) so every matmul is a plain or rhs-transposed one.
 ``interpret=True`` runs the same kernels on CPU (how tests exercise them);
 ``tests/test_tpu_lowering.py`` cross-lowers them for the TPU without a chip.
 :func:`attention` picks the kernel when the default backend is ``tpu`` and
-the fused-XLA reference elsewhere; ragged lengths pad to block multiples.
+the fused-XLA reference elsewhere; ragged lengths pad to their lane rounding
+(to a common multiple of explicit blocks where those are given).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -67,17 +91,19 @@ def _col_to_row(x):
 
 
 def _fold_block(s, v, m_ref, l_ref, acc_ref):
-    """Fold one masked score block ``s`` [bq, bk] (dead entries ``-inf``)
-    and its values ``v`` [bk, D] into the running online-softmax state held
-    in VMEM scratch: ``m``/``l`` [bq, 1] f32 columns, ``acc`` [bq, D] f32
+    """Fold one score block ``s`` [bq, bk] (dead entries ``-inf``) and its
+    values ``v`` [bk, D] into the running online-softmax state held in VMEM
+    scratch: ``m``/``l`` [bq, 1] f32 columns, ``acc`` [bq, D] f32
     (unnormalized).  Shared by the forward and the ring shard-update kernel
     so the two cannot drift."""
     m = m_ref[...]
     new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-    # rows with every position masked keep a -inf max; shift by a finite one
+    # rows with every position masked keep a -inf max; shift by a finite one,
+    # so that a dead entry (and a first block's -inf history) is exp(-inf) = 0
+    # with no select over the block
     safe_m = jnp.where(new_m > -jnp.inf, new_m, 0.0)
-    p = jnp.where(s > -jnp.inf, jnp.exp(s - safe_m), 0.0)
-    corr = jnp.where(m > -jnp.inf, jnp.exp(m - safe_m), 0.0)
+    p = jnp.exp(s - safe_m)
+    corr = jnp.exp(m - safe_m)
     m_ref[...] = new_m
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
     # matmuls stay in the input dtype (bf16 rides the MXU at full rate)
@@ -86,70 +112,180 @@ def _fold_block(s, v, m_ref, l_ref, acc_ref):
         p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-                  block_q, block_k, n_kb, causal, scale, valid_len):
-    """Grid cell (bh, qi, kj): fold K/V block kj into q block qi's online
-    softmax state (scratch persists across the sequential kj dimension)."""
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+# -- tiling ------------------------------------------------------------------
+# Every kernel walks the (q block, k block) tiles of one head.  Three
+# predicates of the tile's indices, written once for the kernels, their
+# BlockSpec index maps and the trace-time gauges (program ids, or numpy
+# index grids): whether a tile holds any live pair, whether all of it is
+# live, and the mask of one that is neither.
 
-    @pl.when(kj == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # a causal block whose keys all lie after this q block's last row (or an
-    # entirely-padded key block) contributes nothing — skip its FLOPs
-    block_live = kj * block_k < valid_len
+def _tile_live(qi, kj, *, block_q, block_k, causal, valid_len):
+    """Tile (qi, kj) holds at least one live (query, key) pair: neither block
+    is all padding and, under the causal mask, the tile's first key is not
+    after its last query.  A dead tile's step runs no FLOPs."""
+    live = (kj * block_k < valid_len) & (qi * block_q < valid_len)
     if causal:
-        block_live = jnp.logical_and(block_live, kj * block_k <= (qi + 1) * block_q - 1)
+        live = live & (kj * block_k <= (qi + 1) * block_q - 1)
+    return live
 
-    @pl.when(block_live)
-    def _attend():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], _NT, preferred_element_type=jnp.float32
-        ) * scale  # [bq, bk]
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        live = k_pos < valid_len  # padded tail keys never contribute
-        if causal:
-            live = live & (q_pos >= k_pos)
-        _fold_block(jnp.where(live, s, -jnp.inf), v_ref[0], m_ref, l_ref, acc_ref)
 
-    @pl.when(kj == n_kb - 1)
-    def _finish():
-        l = l_ref[...]
-        m = m_ref[...]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
-        # per-row log-sum-exp of the SCALED scores — the softmax statistic
-        # the backward kernels re-materialize P from (-inf for dead rows)
-        lse = jnp.where(
-            l > 0, jnp.where(m > -jnp.inf, m, 0.0) + jnp.log(jnp.maximum(l, 1e-38)),
-            -jnp.inf,
-        )
-        lse_ref[0] = _col_to_row(lse)
+def _tile_interior(qi, kj, *, block_q, block_k, causal, valid_len):
+    """Every pair of tile (qi, kj) is live: its keys end inside ``valid_len``
+    and, under the causal mask, its last key is not after its first query.
+    Such a tile needs no mask.  (Padded QUERY rows need none either: their
+    outputs are cut off and their dO is zero.)"""
+    inside = (kj + 1) * block_k <= valid_len
+    if causal:
+        inside = inside & ((kj + 1) * block_k - 1 <= qi * block_q)
+    return inside
+
+
+def _tile_mask(qi, kj, shape, q_dim, *, block_q, block_k, causal, valid_len):
+    """Live entries of a tile the diagonal or the padded tail crosses;
+    ``shape`` has queries along ``q_dim`` and keys along the other."""
+    k_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+    live = k_idx < valid_len - kj * block_k  # padded tail keys never contribute
+    if causal:
+        q_idx = jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+        live = live & (q_idx - k_idx >= kj * block_k - qi * block_q)
+    return live
+
+
+def _live_k_block(i, j, *, block_q, block_k, causal, valid_len):
+    """Index map of a K/V block in a queries-major grid: ``j`` clamped to the
+    last live tile of row ``i``, so a dead step names the block already
+    resident and the pipeline copies nothing."""
+    last = (valid_len - 1) // block_k
+    if causal:
+        last = jnp.minimum(last, jax.lax.div((i + 1) * block_q - 1, block_k))
+    return jnp.minimum(j, last)
+
+
+def _live_q_block(i, j, *, block_q, block_k, causal, valid_len):
+    """Index map of a q-side block (q, dO, lse, delta) in the keys-major
+    grid: ``i`` clamped into the live tiles of column ``j``, which under the
+    causal mask start at the diagonal — the dead steps before it prefetch it."""
+    last = (valid_len - 1) // block_q
+    first = jnp.minimum(jax.lax.div(j * block_k, block_q), last) if causal else 0
+    return jnp.clip(i, first, last)
+
+
+# VMEM a step may plan for: the 16 MiB that Mosaic gives a kernel by default on
+# a v5e (its scoped limit; no call raises it, so the same rule serves chips
+# with less VMEM behind that default).
+_VMEM_BUDGET = 16 * 2**20
+# What a step of each kernel holds: q-sized and k-sized blocks the pipeline
+# double-buffers in the input dtype, and q-sized and k-sized f32 accumulators.
+_FOOTPRINT = {
+    "flash_fwd": (2, 2, 1, 0),      # q, out | k, v | acc
+    "flash_bwd_dq": (3, 2, 1, 0),   # q, dO, dq | k, v | acc
+    "flash_bwd_dkv": (2, 4, 0, 2),  # q, dO | k, v, dk, dv | dk, dv
+}
+# f32 [block_q, block_k] intermediates a step is reckoned to hold at once
+# (scores -> probs in place, one more, a bf16 copy for the MXU).  Calibrated
+# against Mosaic at 64 x 2,048 x 128 bf16: with it every pair that compiled is
+# inside the budget (1,024 x 1,024 and 2,048 x 512 in all three kernels, 512 x
+# 2,048 forward and dQ) and the one that ran out of VMEM (dK/dV at 512 x 2,048)
+# is not.
+_SCORE_TILES = 2.5
+# The largest (block_q, block_k) each kernel is given: where the sweep on the
+# v5e at the cells' shape (64 x 2,048 x 128 bf16; PERF.md section 6, PR 26)
+# read its best time.  The forward and dQ (queries-major: their per-row
+# softmax columns cost a step the same whatever its keys) want the widest key
+# block that still skips half the causal square; dK/dV is flat from 512 up.
+_BLOCK_TARGET = {
+    "flash_fwd": (1024, 1024),
+    "flash_bwd_dq": (1024, 1024),
+    "flash_bwd_dkv": (512, 512),
+}
+
+
+def _lane_round(n):
+    return -(-n // _LANES) * _LANES
+
+
+def _vmem_bytes(kernel, block_q, block_k, D, itemsize):
+    """Upper estimate of the VMEM one grid step of ``kernel`` holds."""
+    q_pipe, k_pipe, q_acc, k_acc = _FOOTPRINT[kernel]
+    d = _lane_round(D)  # the last dim pads to whole lanes
+    stats = 2 * 4 * _LANES * block_q  # m, l columns (lane-padded); lse, delta rows
+    return int(2 * itemsize * d * (q_pipe * block_q + k_pipe * block_k)
+               + 4 * d * (q_acc * block_q + k_acc * block_k)
+               + 4 * _SCORE_TILES * block_q * block_k + stats)
+
+
+def _choose_blocks(kernel, L, D, dtype):
+    """(block_q, block_k) of ``kernel`` for a sequence of ``L``, from what the
+    call can see.  Each block is the largest multiple of 128 that divides the
+    lane-rounded length and is at most the kernel's ``_BLOCK_TARGET``, so no
+    length pads beyond its lane rounding (1,023 -> 1,024, 8 -> 128) and every
+    kernel of one call shares one padded length; a length whose lane count
+    is prime (1,664 = 13 x 128) falls to 128 and pays the grid-step floor
+    rather than padding.  The larger block then steps down to the next divisor
+    until the step's ``_vmem_bytes`` is inside ``_VMEM_BUDGET`` (wide heads,
+    f32 inputs)."""
+    lanes = -(-L // _LANES)
+    fits = [n * _LANES for n in range(1, lanes + 1) if lanes % n == 0]
+    itemsize = jnp.dtype(dtype).itemsize
+    block_q, block_k = (max(b for b in fits if b <= target)
+                        for target in _BLOCK_TARGET[kernel])
+    while (_vmem_bytes(kernel, block_q, block_k, D, itemsize) > _VMEM_BUDGET
+           and max(block_q, block_k) > _LANES):
+        if block_q >= block_k:
+            block_q = max(b for b in fits if b < block_q)
+        else:
+            block_k = max(b for b in fits if b < block_k)
+    return block_q, block_k
 
 
 def _fit_block(block, L):
     """Clamp a block to the lane-rounded length: a sequence shorter than the
     block (``model.init`` traces L=8) pads up to one full-width block, not
     down to a sub-tile shape Mosaic would have to relayout."""
-    return min(block, -(-L // _LANES) * _LANES)
+    return min(block, _lane_round(L))
 
 
-def _pad_geometry(q, block_q, block_k):
-    import math
+def _geometry(L, D, dtype, block_q, block_k):
+    """``{kernel: (block_q, block_k)}`` and the padded length all three
+    kernels of a call share.  An explicit block is every kernel's; one left
+    ``None`` is each kernel's own choice (:func:`_choose_blocks`).  The
+    length pads to a common multiple of ALL blocks: the grids are
+    (Lp//block_q, Lp//block_k), so a padded length one block does not divide
+    would silently truncate that axis (keys never folded in / rows never
+    written).  Chosen blocks all divide the lane-rounded length."""
+    blocks = {}
+    for kernel in _BLOCK_TARGET:
+        own_q, own_k = _choose_blocks(kernel, L, D, dtype)
+        blocks[kernel] = (_fit_block(block_q, L) if block_q else own_q,
+                          _fit_block(block_k, L) if block_k else own_k)
+    m = math.lcm(*(b for pair in blocks.values() for b in pair))
+    return blocks, -(-L // m) * m
 
-    B, L, H, D = q.shape
-    block_q = _fit_block(block_q, L)
-    block_k = _fit_block(block_k, L)
-    # pad to a common multiple of BOTH blocks: the grid is (Lp//block_q,
-    # Lp//block_k), so a padded length only one block divides would silently
-    # truncate the other axis (keys never folded in / rows never written)
-    m = math.lcm(block_q, block_k)
-    Lp = -(-L // m) * m
-    return B, L, H, D, block_q, block_k, Lp
+
+def _tiling(kernel, Lp, blocks, causal, valid_len):
+    """One call's tile parameters (the keywords of the ``_tile_*`` predicates)
+    and its grid extents (q blocks, k blocks).  Leaves what was chosen as
+    gauges per kernel name (trace time: Python, from shapes): the blocks, and
+    live grid steps over grid steps."""
+    import numpy as np
+
+    from ..core import obs
+
+    block_q, block_k = blocks
+    tile = dict(block_q=block_q, block_k=block_k, causal=causal, valid_len=valid_len)
+    n_qb, n_kb = Lp // block_q, Lp // block_k
+    live = _tile_live(np.arange(n_qb)[:, None], np.arange(n_kb)[None, :], **tile)
+    labels = {"kernel": kernel}
+    obs.gauge_set("flash.block_q", block_q, labels)
+    obs.gauge_set("flash.block_k", block_k, labels)
+    obs.gauge_set("flash.live_step_share", float(np.mean(live)), labels)
+    return tile, n_qb, n_kb
+
+
+# batch·heads and the outer block axis are independent; the inner one carries
+# the accumulators (free on a v5e's single core, required to split a megacore)
+_GRID_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _to_bh(x, B, L, H, D, Lp):  # [B, L, H, D] -> [B*H, Lp, D]
@@ -171,81 +307,125 @@ def _scratch(block_q, D):
     ]
 
 
-def _flash_forward(q, k, v, causal, block_q, block_k, interpret,
-                   with_lse: bool = False):
-    B, L, H, D, block_q, block_k, Lp = _pad_geometry(q, block_q, block_k)
-    qb = _to_bh(q, B, L, H, D, Lp)
-    kb = _to_bh(k, B, L, H, D, Lp)
-    vb = _to_bh(v, B, L, H, D, Lp)
-    scale = float(1.0 / (D**0.5))  # python float: traced scalars can't be closed over
-    n_kb = Lp // block_k
-    kernel = functools.partial(
-        _flash_kernel, block_q=block_q, block_k=block_k, n_kb=n_kb,
-        causal=causal, scale=scale, valid_len=L,
-    )
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B * H, Lp // block_q, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-        ],
+def _when_live(qi, kj, tile, step):
+    """Run ``step(masked)`` for a live tile: unmasked where the whole tile is
+    live, with the mask only where the diagonal or the padded tail crosses."""
+    live = _tile_live(qi, kj, **tile)
+    interior = _tile_interior(qi, kj, **tile)
+    pl.when(live & interior)(functools.partial(step, False))
+    pl.when(live & jnp.logical_not(interior))(functools.partial(step, True))
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
+                  n_kb, scale, tile):
+    """Grid cell (bh, qi, kj): fold K/V block kj into q block qi's online
+    softmax state (scratch persists across the sequential kj dimension)."""
+    qi = pl.program_id(1)
+    kj = pl.program_id(2)
+
+    @pl.when(kj == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def _attend(masked):
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], _NT, preferred_element_type=jnp.float32
+        ) * scale  # [bq, bk]
+        if masked:
+            s = jnp.where(_tile_mask(qi, kj, s.shape, 0, **tile), s, -jnp.inf)
+        _fold_block(s, v_ref[0], m_ref, l_ref, acc_ref)
+
+    _when_live(qi, kj, tile, _attend)
+
+    @pl.when(kj == n_kb - 1)
+    def _finish():
+        l = l_ref[...]
+        m = m_ref[...]
+        o_ref[0] = (acc_ref[...] * (1.0 / jnp.maximum(l, 1e-20))).astype(o_ref.dtype)
+        # per-row log-sum-exp of the SCALED scores — the softmax statistic
+        # the backward kernels re-materialize P from (-inf for dead rows)
+        lse = jnp.where(
+            l > 0, jnp.where(m > -jnp.inf, m, 0.0) + jnp.log(jnp.maximum(l, 1e-38)),
+            -jnp.inf,
+        )
+        lse_ref[0] = _col_to_row(lse)
+
+
+def _fwd_call(qb, kb, vb, blocks, causal, valid_len, interpret):
+    """``flash_fwd`` over ``[B*H, Lp, D]`` operands: (out, lse [B*H, 1, Lp])."""
+    BH, Lp, D = qb.shape
+    block_q, block_k = blocks
+    tile, n_qb, n_kb = _tiling("flash_fwd", Lp, blocks, causal, valid_len)
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+    k_spec = pl.BlockSpec(
+        (1, block_k, D), lambda b, i, j: (b, _live_k_block(i, j, **tile), 0))
+    return pl.pallas_call(
+        functools.partial(_flash_kernel, n_kb=n_kb,
+                          scale=float(1.0 / (D**0.5)), tile=tile),
+        grid=(BH, n_qb, n_kb),
+        in_specs=[q_spec, k_spec, k_spec],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            q_spec,
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Lp, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, 1, Lp), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype),
+            jax.ShapeDtypeStruct((BH, 1, Lp), jnp.float32),
         ],
         scratch_shapes=_scratch(block_q, D),
+        compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
         name="flash_fwd",
     )(qb, kb, vb)
+
+
+def _flash_forward(q, k, v, causal, block_q, block_k, interpret,
+                   with_lse: bool = False):
+    B, L, H, D = q.shape
+    blocks, Lp = _geometry(L, D, q.dtype, block_q, block_k)
+    qb, kb, vb = (_to_bh(x, B, L, H, D, Lp) for x in (q, k, v))
+    out, lse = _fwd_call(qb, kb, vb, blocks["flash_fwd"], causal, L, interpret)
     out = _from_bh(out, B, L, H, D)
     return (out, lse) if with_lse else out
 
 
-def _block_grads(q, k, v, do, lse, delta, qi, kj, *, block_q, block_k, causal,
-                 scale, valid_len, keys_major):
-    """Shared backward block math: re-materialize this (qi, kj) block's probs
-    P from (q, k, lse) and form dS — used identically by the dQ and dK/dV
-    kernels so the two gradients cannot desynchronize.
+def _block_grads(q, k, v, do, lse, delta, mask, *, scale, keys_major):
+    """Shared backward block math: re-materialize this tile's probs P from
+    (q, k, lse) and form dS WITHOUT its factor ``scale`` (each kernel applies
+    it once, to the accumulated gradient) — used identically by the dQ and
+    dK/dV kernels so the two gradients cannot desynchronize.
 
     ``keys_major=False`` (dQ pass) works on ``[bq, bk]`` blocks with
     ``lse``/``delta`` as ``[bq, 1]`` columns; ``keys_major=True`` (dK/dV
     pass) on the transposed ``[bk, bq]`` blocks with ``[1, bq]`` rows, so
     that pass's accumulating matmuls need no transposed left operand.
+    ``mask`` is the tile's live entries, or None for a tile that is all live.
 
     ``lse`` is finite for any q row that attends >=1 live key — which
     includes padded q-tail rows (the live mask constrains keys, not
     queries).  Padded-tail GRADIENT correctness therefore rests on dO (and
     hence delta) being zero-padded by _to_bh, not on lse masking; the
     finiteness guard only covers rows with no live keys at all (e.g. the
-    first rows of a fully-masked causal block)."""
+    first rows of a fully-masked causal block), which no all-live tile has."""
     if keys_major:
-        shape, q_dim, k_dim = (block_k, block_q), 1, 0
         s = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
     else:
-        shape, q_dim, k_dim = (block_q, block_k), 0, 1
         s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, k_dim)
-    row_live = lse > -jnp.inf
-    live = (k_pos < valid_len) & row_live
-    if causal:
-        live = live & (q_pos >= k_pos)
-    p = jnp.where(live, jnp.exp(s * scale - jnp.where(row_live, lse, 0.0)), 0.0)
-    ds = p * (dp - delta) * scale
-    return p, ds
+    if mask is None:
+        p = jnp.exp(s * scale - lse)
+    else:
+        row_live = lse > -jnp.inf
+        p = jnp.where(mask & row_live,
+                      jnp.exp(s * scale - jnp.where(row_live, lse, 0.0)), 0.0)
+    return p, p * (dp - delta)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, acc_ref, *, block_q, block_k, n_kb, causal,
-                         scale, valid_len):
+                         dq_ref, acc_ref, *, n_kb, scale, tile):
     """Grid cell (bh, qi, kj): accumulate q block qi's gradient over k blocks
     (sequential innermost kj; acc persists in VMEM scratch)."""
     qi = pl.program_id(1)
@@ -255,32 +435,30 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    block_live = kj * block_k < valid_len
-    if causal:
-        block_live = jnp.logical_and(block_live, kj * block_k <= (qi + 1) * block_q - 1)
-
-    @pl.when(block_live)
-    def _accum():
+    def _accum(masked):
         k = k_ref[0]
+        mask = (_tile_mask(qi, kj, (tile["block_q"], tile["block_k"]), 0, **tile)
+                if masked else None)
         _, ds = _block_grads(
             q_ref[0], k, v_ref[0], do_ref[0],
-            lse_ref[0, 0][:, None], delta_ref[0, 0][:, None], qi, kj,
-            block_q=block_q, block_k=block_k, causal=causal, scale=scale,
-            valid_len=valid_len, keys_major=False,
+            lse_ref[0, 0][:, None], delta_ref[0, 0][:, None], mask,
+            scale=scale, keys_major=False,
         )
         acc_ref[...] += jax.lax.dot_general(
             ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
+    _when_live(qi, kj, tile, _accum)
+
     @pl.when(kj == n_kb - 1)
     def _finish():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
-                          n_qb, causal, scale, valid_len):
+                          dk_ref, dv_ref, dk_acc, dv_acc, *, n_qb, scale, tile):
     """Grid cell (bh, kj, qi): accumulate k/v block kj's gradients over q
-    blocks (sequential innermost qi)."""
+    blocks (sequential innermost qi).  p is zero wherever q_pos < k_pos, so
+    the q blocks entirely above kj are dead tiles."""
     kj = pl.program_id(1)
     qi = pl.program_id(2)
 
@@ -289,95 +467,95 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    block_live = qi * block_q < valid_len
-    if causal:
-        # p is zero wherever q_pos < k_pos: skip q blocks entirely above kj
-        block_live = jnp.logical_and(block_live, (qi + 1) * block_q - 1 >= kj * block_k)
-
-    @pl.when(block_live)
-    def _accum():
+    def _accum(masked):
         q = q_ref[0]
         do = do_ref[0]
+        mask = (_tile_mask(qi, kj, (tile["block_k"], tile["block_q"]), 1, **tile)
+                if masked else None)
         p_t, ds_t = _block_grads(
-            q, k_ref[0], v_ref[0], do, lse_ref[0], delta_ref[0], qi, kj,
-            block_q=block_q, block_k=block_k, causal=causal, scale=scale,
-            valid_len=valid_len, keys_major=True,
+            q, k_ref[0], v_ref[0], do, lse_ref[0], delta_ref[0], mask,
+            scale=scale, keys_major=True,
         )  # both [bk, bq]
         dv_acc[...] += jax.lax.dot_general(
             p_t.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
         dk_acc[...] += jax.lax.dot_general(
             ds_t.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32)
 
+    _when_live(qi, kj, tile, _accum)
+
     @pl.when(qi == n_qb - 1)
     def _finish():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _dq_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret):
+    """``flash_bwd_dq`` over ``[B*H, Lp, D]`` operands and ``[B*H, 1, Lp]``
+    row statistics: dQ, queries-major like the forward."""
+    BH, Lp, D = qb.shape
+    block_q, block_k = blocks
+    tile, n_qb, n_kb = _tiling("flash_bwd_dq", Lp, blocks, causal, valid_len)
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+    k_spec = pl.BlockSpec(
+        (1, block_k, D), lambda b, i, j: (b, _live_k_block(i, j, **tile), 0))
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_dq_kernel, n_kb=n_kb,
+                          scale=float(1.0 / (D**0.5)), tile=tile),
+        grid=(BH, n_qb, n_kb),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        compiler_params=_GRID_SEMANTICS,
+        interpret=interpret,
+        name="flash_bwd_dq",
+    )(qb, kb, vb, dob, lse, delta)
+
+
+def _dkv_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret):
+    """``flash_bwd_dkv`` over the same operands: (dK, dV), keys-major — the
+    grid is (bh, kj, qi) and the q-side blocks follow the inner axis."""
+    BH, Lp, D = qb.shape
+    block_q, block_k = blocks
+    tile, n_qb, n_kb = _tiling("flash_bwd_dkv", Lp, blocks, causal, valid_len)
+    q_spec = pl.BlockSpec(
+        (1, block_q, D), lambda b, j, i: (b, _live_q_block(i, j, **tile), 0))
+    k_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
+    row_spec = pl.BlockSpec(
+        (1, 1, block_q), lambda b, j, i: (b, 0, _live_q_block(i, j, **tile)))
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_dkv_kernel, n_qb=n_qb,
+                          scale=float(1.0 / (D**0.5)), tile=tile),
+        grid=(BH, n_kb, n_qb),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[k_spec, k_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype),
+            jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                        pltpu.VMEM((block_k, D), jnp.float32)],
+        compiler_params=_GRID_SEMANTICS,
+        interpret=interpret,
+        name="flash_bwd_dkv",
+    )(qb, kb, vb, dob, lse, delta)
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
     """Pallas flash backward: same blockwise structure as the forward — P is
     re-materialized per block from (q, k, lse), so backward memory is
     O(block² ) per core instead of the O(L²) probs matrix."""
-    B, L, H, D, block_q, block_k, Lp = _pad_geometry(q, block_q, block_k)
-    qb = _to_bh(q, B, L, H, D, Lp)
-    kb = _to_bh(k, B, L, H, D, Lp)
-    vb = _to_bh(v, B, L, H, D, Lp)
-    dob = _to_bh(g.astype(q.dtype), B, L, H, D, Lp)
-    ob = _to_bh(out, B, L, H, D, Lp)
+    B, L, H, D = q.shape
+    blocks, Lp = _geometry(L, D, q.dtype, block_q, block_k)
+    qb, kb, vb, dob, ob = (_to_bh(x, B, L, H, D, Lp)
+                           for x in (q, k, v, g.astype(q.dtype), out))
     # delta_i = rowsum(dO * O): tiny elementwise pass, fused by XLA
     delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
                     axis=-1)[:, None, :]  # [B*H, 1, Lp], like lse
-    scale = float(1.0 / (D**0.5))
-    n_qb, n_kb = Lp // block_q, Lp // block_k
-    row_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),  # q
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),  # k
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),  # v
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),  # dO
-        pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),  # lse
-        pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),  # delta
-    ]
-    dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k, n_kb=n_kb,
-            causal=causal, scale=scale, valid_len=L,
-        ),
-        grid=(B * H, n_qb, n_kb),
-        in_specs=row_specs,
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Lp, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(qb, kb, vb, dob, lse, delta)
-    col_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),  # q
-        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),  # k
-        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),  # v
-        pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),  # dO
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),  # lse
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),  # delta
-    ]
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k, n_qb=n_qb,
-            causal=causal, scale=scale, valid_len=L,
-        ),
-        grid=(B * H, n_kb, n_qb),
-        in_specs=col_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Lp, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Lp, D), q.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dkv",
-    )(qb, kb, vb, dob, lse, delta)
+    operands = (qb, kb, vb, dob, lse, delta)
+    dq = _dq_call(*operands, blocks["flash_bwd_dq"], causal, L, interpret)
+    dk, dv = _dkv_call(*operands, blocks["flash_bwd_dkv"], causal, L, interpret)
     return (_from_bh(dq, B, L, H, D), _from_bh(dk, B, L, H, D),
             _from_bh(dv, B, L, H, D))
 
@@ -388,12 +566,14 @@ def flash_attention(
     k: jnp.ndarray,
     v: jnp.ndarray,
     causal: bool = True,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Pallas blockwise attention. q/k/v: [B, L, H, D] -> [B, L, H, D].
-    Ragged L is padded to a block multiple internally."""
+    ``block_q`` / ``block_k`` left ``None`` are chosen per kernel from the
+    shape (:func:`_choose_blocks`); ragged L is padded internally, to its
+    lane rounding then and to a common multiple of explicit blocks else."""
     return _flash_forward(q, k, v, causal, block_q, block_k, interpret)
 
 

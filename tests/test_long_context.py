@@ -165,6 +165,47 @@ class TestFlashAttentionKernel:
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                        atol=2e-5, err_msg=f"bq={bq} bk={bk}")
 
+    @pytest.mark.parametrize("L", [88, 96])
+    @pytest.mark.parametrize("blocks", [(32, 16), (16, 32), (64, 16), (16, 64)],
+                             ids=lambda b: f"{b[0]}x{b[1]}")
+    def test_unequal_blocks_forward_and_grad(self, blocks, L):
+        """block_q != block_k both ways, a ragged and an even length: every
+        grid holds dead tiles (above the diagonal; wholly padded at 64x16,
+        L 88), interior tiles (the unmasked path), diagonal tiles and — at L
+        88 — a tile the padded tail crosses, and the K/V (dQ pass: K/V; dK/dV
+        pass: q, dO, lse, delta) index maps are clamped on the dead steps."""
+        bq, bk = blocks
+        q, k, v = _qkv(B=1, L=L, H=2, D=8, seed=37 + L)
+        w = jax.random.normal(jax.random.PRNGKey(L), q.shape, jnp.float32)
+
+        def loss(attn):
+            return lambda q, k, v: jnp.sum(attn(q, k, v) * w)
+
+        flash = lambda q, k, v: flash_attention(q, k, v, True, bq, bk, True)
+        ref = lambda q, k, v: reference_attention(q, k, v, causal=True)
+        np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                   np.asarray(ref(q, k, v)), atol=2e-5)
+        gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+        for name, a, b in zip("qkv", gf, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                       err_msg=f"d{name} at {bq}x{bk}, L={L}")
+
+    def test_default_blocks_match_reference(self):
+        """No explicit block: each kernel picks its own pair from the shape
+        (one 128-row tile here, the tail crossing it), and records it."""
+        from fedml_tpu.core import obs
+
+        q, k, v = _qkv(B=1, L=40, H=2, D=8, seed=41)
+        ref = reference_attention(q, k, v, causal=True)
+        out = flash_attention(q, k, v, causal=True, interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+        gauges = {(r["metric"], r["labels"].get("kernel")): r["value"]
+                  for r in obs.registry().export() if r["metric"].startswith("flash.")}
+        assert gauges[("flash.block_q", "flash_fwd")] == 128
+        assert gauges[("flash.block_k", "flash_fwd")] == 128
+        assert gauges[("flash.live_step_share", "flash_fwd")] == 1.0
+
     def test_transformer_with_flash_attention(self):
         """The kernel slots in as the transformer's attention_fn."""
         from functools import partial
@@ -250,3 +291,75 @@ class TestRingPlusPallas:
         g_full = jax.grad(loss_full)(q)
         np.testing.assert_allclose(np.asarray(g_ring), np.asarray(g_full),
                                    atol=5e-4)
+
+
+@pytest.fixture(scope="module")
+def fa():
+    """The kernel's module (the package re-exports the name as the function)."""
+    import importlib
+
+    return importlib.import_module("fedml_tpu.ops.flash_attention")
+
+
+class TestFlashBlockRule:
+    """The pure function that tiles the three kernels from the shape."""
+
+    @pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"])
+    @pytest.mark.parametrize("L", [8, 1023, 1152, 1664, 2048, 32768])
+    @pytest.mark.parametrize("head", [(128, jnp.bfloat16), (64, jnp.bfloat16),
+                                      (32, jnp.float32), (256, jnp.float32)],
+                             ids=lambda h: f"D{h[0]}-{jnp.dtype(h[1]).name}")
+    def test_blocks_divide_the_lane_rounded_length_inside_the_budget(
+            self, fa, kernel, L, head):
+        D, dtype = head
+        rounded = -(-L // 128) * 128
+        bq, bk = fa._choose_blocks(kernel, L, D, dtype)
+        for b in (bq, bk):
+            assert b % 128 == 0 and rounded % b == 0, (bq, bk)
+        assert fa._vmem_bytes(kernel, bq, bk, D, jnp.dtype(dtype).itemsize) \
+            <= fa._VMEM_BUDGET
+        target = fa._BLOCK_TARGET[kernel]
+        assert bq <= target[0] and bk <= target[1]
+        # nothing pads beyond its lane rounding: L 1,023 -> 1,024, L 8 -> 128
+        blocks, padded = fa._geometry(L, D, dtype, None, None)
+        assert padded == rounded and blocks[kernel] == (bq, bk)
+
+    def test_rule_at_the_cells_shape_leaves_the_step_floor(self, fa):
+        """At L 2,048, D 128, bf16 no kernel is left at 128 x 128 (16,384
+        grid steps a call): at most 1,024 steps of 64 head-batches."""
+        for kernel in fa._BLOCK_TARGET:
+            bq, bk = fa._choose_blocks(kernel, 2048, 128, jnp.bfloat16)
+            assert (2048 // bq) * (2048 // bk) <= 16, (kernel, bq, bk)
+
+    def test_explicit_blocks_override_and_share_one_padded_length(self, fa):
+        blocks, padded = fa._geometry(32, 8, jnp.float32, 32, 24)
+        assert set(blocks.values()) == {(32, 24)} and padded == 96
+        # one explicit block: the other is each kernel's own, one length for all
+        blocks, padded = fa._geometry(1023, 64, jnp.bfloat16, 256, None)
+        assert all(b[0] == 256 for b in blocks.values())
+        assert all(padded % b == 0 for pair in blocks.values() for b in pair)
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("blocks", [(32, 16), (16, 32), (64, 16)],
+                             ids=lambda b: f"{b[0]}x{b[1]}")
+    def test_clamped_index_maps_stay_on_live_tiles(self, fa, blocks, causal):
+        """On every step of either grid the clamped index names a live tile of
+        its row (column), and on a live step it is the step's own tile."""
+        bq, bk = blocks
+        L, padded = 88, 128
+        tile = dict(block_q=bq, block_k=bk, causal=causal, valid_len=L)
+        for i in range(padded // bq):
+            for j in range(padded // bk):
+                live = bool(fa._tile_live(i, j, **tile))
+                kj = int(fa._live_k_block(i, j, **tile))
+                qi = int(fa._live_q_block(i, j, **tile))
+                if live:
+                    assert (kj, qi) == (j, i)
+                if i * bq < L:  # a row with live tiles names one of them
+                    assert fa._tile_live(i, kj, **tile)
+                if j * bk < L:
+                    assert fa._tile_live(qi, j, **tile)
+                # an interior tile is live and its mask is all true
+                if fa._tile_interior(i, j, **tile) and i * bq < L:
+                    assert live
+                    assert bool(jnp.all(fa._tile_mask(i, j, (bq, bk), 0, **tile)))

@@ -12,10 +12,11 @@ import pytest
 from fedml_tpu.ops.flash_attention import flash_attention, flash_shard_update
 
 # (B, L, H, D, dtype): bench transformer attention (ragged L, D=64), a
-# 128-wide head, the TransformerConfig default head dim (256/8 = 32), and
-# model.init's L=8 trace
+# 128-wide head, the TransformerConfig default head dim (256/8 = 32),
+# model.init's L=8 trace, and the benchmark cells' own shape (dsllm7b-sim)
 SHAPES = [(8, 1023, 16, 64, jnp.bfloat16), (2, 1024, 8, 128, jnp.bfloat16),
-          (2, 256, 8, 32, jnp.float32), (2, 8, 8, 32, jnp.float32)]
+          (2, 256, 8, 32, jnp.float32), (2, 8, 8, 32, jnp.float32),
+          (2, 2048, 32, 128, jnp.bfloat16)]
 
 
 def _forward(q, k, v):
