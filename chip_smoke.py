@@ -21,7 +21,10 @@ before any data is generated.
 * leg B — the Pallas kernels, compiled (never interpreted): ``flash_attention``
   forward and ``jax.grad`` against ``reference_attention``, and
   ``flash_shard_update`` against ``shard_update_reference``, within
-  TOLERANCE; then TransformerLM training steps at the bench
+  TOLERANCE; at the ``kimi-linear-48b-a3b-sim`` cell's shapes flash at q/k
+  192 != v 128, KDA chunkwise forward and ``jax.grad`` against the per-token
+  recurrence and the expert layer's grouped products against a dense masked
+  loop (``kimi_linear_ops``); then TransformerLM training steps at the bench
   transformer shapes through its DEFAULT attention.
 * leg C — only when the host has >= 4 devices: leg A again on the
   four-device ``client`` mesh (per-round loss must agree with leg A within
@@ -75,6 +78,13 @@ TOLERANCE = 2e-2
 # cells' own shape (dsllm7b-sim: 32 heads of 128, L 2,048, batch 2)
 KERNEL_SHAPES = [(8, 1023, 16, 64, "bfloat16"), (2, 256, 8, 32, "float32"),
                  (2, 2048, 32, 128, "bfloat16")]
+# the kimi-linear-48b-a3b-sim cell's shapes: one sequence of 8,192 tokens, 32
+# heads, q/k 192 and v 128 (MLA), 128 / 128 (KDA), hidden 2,304, 8 held of 256
+# experts of 1,024 at top 8.  The references are computed ORACLE_HEADS heads at
+# a time (a head's attention and a head's recurrence know no other head), so
+# that the float32 scores and the per-token scan's saved states fit the chip.
+KIMI = dict(L=8192, H=32, qk=192, v=128, kda=128, d=2304, f=1024, held=8, routed=256, top=8)
+ORACLE_HEADS = 4
 # one- vs four-device runs of the same seed train the same clients on the
 # same batches; they differ in summation order under bf16 compute (measured
 # 7.2e-5 over these four rounds on the v5e, this PR)
@@ -144,6 +154,106 @@ def _within_tolerance(errors, what):
     errors = {k: float(f"{e:.3e}") for k, e in errors.items()}
     bad = {k: e for k, e in errors.items() if not e <= TOLERANCE}
     _check(not bad, f"{what} vs reference beyond {TOLERANCE}: {bad} (all: {errors})")
+    return errors
+
+
+def kimi_linear_ops():
+    """{name: error} of the ops the ``kimi_linear`` decoder adds, compiled at the
+    cell's shapes, each against its reference at "highest" precision."""
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.models import kimi_linear
+    from fedml_tpu.ops import kda
+    from fedml_tpu.ops.flash_attention import flash_attention, reference_attention
+
+    L, H, errors = KIMI["L"], KIMI["H"], {}
+    heads = [slice(h, h + ORACLE_HEADS) for h in range(0, H, ORACLE_HEADS)]
+
+    def worst(name, value):
+        errors[name] = max(errors.get(name, 0.0), value)
+
+    # flash at q/k 192 != v 128, every head in one call; the oracle by groups of heads
+    keys = jax.random.split(jax.random.PRNGKey(192), 4)
+    q, k = (jax.random.normal(key, (1, L, H, KIMI["qk"]), jnp.bfloat16) for key in keys[:2])
+    v = jax.random.normal(keys[2], (1, L, H, KIMI["v"]), jnp.bfloat16)
+    w = jax.random.normal(keys[3], (1, L, H, KIMI["v"]), jnp.float32)
+    flash = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
+    out = flash(q, k, v)
+    got = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash(q, k, v).astype(jnp.float32) * w),
+                           argnums=(0, 1, 2)))(q, k, v)
+    for hs in heads:
+        part = [x[:, :, hs] for x in (q, k, v)]
+        with jax.default_matmul_precision("highest"):
+            ref = lambda q, k, v: reference_attention(q, k, v, causal=True)
+            worst("fwd_mla_flash", _rel_err(out[:, :, hs], jax.jit(ref)(*part)))
+            exp = jax.jit(jax.grad(lambda *a: jnp.sum(ref(*a).astype(jnp.float32) * w[:, :, hs]),
+                                   argnums=(0, 1, 2)))(*part)
+        for name, g, e in zip("qkv", got, exp):
+            worst(f"d{name}_mla_flash", _rel_err(g[:, :, hs], e))
+
+    # KDA chunkwise, every head in one call; the per-token oracle by groups of heads
+    keys = jax.random.split(jax.random.PRNGKey(64), 6)
+    shape = (1, L, H, KIMI["kda"])
+    q, k = (jax.random.normal(key, shape, jnp.float32) for key in keys[:2])
+    q, k = (x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)) for x in (q, k))
+    v, w = (jax.random.normal(key, shape, jnp.float32) for key in keys[2:4])
+    g = -0.1 * jax.nn.softplus(jax.random.normal(keys[4], shape, jnp.float32))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], shape[:3], jnp.float32))
+    args = (q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), g, beta)
+
+    def value_and_grads(fn, args, w):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2, 3, 4)))(*args)
+
+    _, got = value_and_grads(kda.kda_chunked, args, w)
+    out = jax.jit(kda.kda_chunked)(*args)
+    for hs in heads:
+        part = [x[:, :, hs] for x in args]
+        with jax.default_matmul_precision("highest"):
+            _, exp = value_and_grads(kda.kda_recurrent, part, w[:, :, hs])
+            worst("fwd_kda", _rel_err(out[:, :, hs], jax.jit(kda.kda_recurrent)(*part)))
+        for name, g_, e in zip(("q", "k", "v", "g", "beta"), got, exp):
+            worst(f"d{name}_kda", _rel_err(g_[:, :, hs], e))
+
+    # the expert layer's grouped products against a dense loop over the held experts
+    keys = jax.random.split(jax.random.PRNGKey(256), 6)
+    d, f, held = KIMI["d"], KIMI["f"], KIMI["held"]
+    h = jax.random.normal(keys[0], (L, d), jnp.bfloat16)
+    w_gate, w_up = (jax.random.normal(key, (held, d, f), jnp.bfloat16) * d ** -0.5
+                    for key in keys[1:3])
+    w_down = jax.random.normal(keys[3], (held, f, d), jnp.bfloat16) * f ** -0.5
+    scores = jax.nn.sigmoid(jax.random.normal(keys[4], (L, KIMI["routed"]), jnp.float32))
+    chosen, weights = kimi_linear.route(scores, jnp.zeros(KIMI["routed"]), KIMI["top"], 2.446, True)
+    cot = jax.random.normal(keys[5], (L, d), jnp.float32)
+
+    def grouped(h, w_gate, w_up, w_down):
+        return kimi_linear.grouped_experts(h, chosen, weights, (0, held), w_gate, w_up, w_down)[0]
+
+    def dense(h, w_gate, w_up, w_down):
+        h, w_gate, w_up, w_down = (x.astype(jnp.float32) for x in (h, w_gate, w_up, w_down))
+        out = jnp.zeros_like(h)
+        for e in range(held):
+            weight = jnp.sum(jnp.where(chosen == e, weights, 0.0), -1)
+            out = out + weight[:, None] * kimi_linear.swiglu(h, w_gate[e], w_up[e], w_down[e])
+        return out
+
+    def value_and_grads(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * cot), argnums=(0, 1, 2, 3)))(
+                h, w_gate, w_up, w_down)
+
+    # the grouped products are traced outside the oracle's precision: it is read at
+    # trace time and would change the kernels under test
+    _, got = value_and_grads(grouped)
+    out = jax.jit(grouped)(h, w_gate, w_up, w_down)
+    with jax.default_matmul_precision("highest"):
+        _, exp = value_and_grads(dense)
+        errors["fwd_grouped_experts"] = _rel_err(out, jax.jit(dense)(h, w_gate, w_up, w_down))
+    for name, g_, e in zip(("h", "w_gate", "w_up", "w_down"), got, exp):
+        errors[f"d{name}_grouped_experts"] = _rel_err(g_, e)
+    counters = kimi_linear.grouped_experts(h, chosen, weights, (0, held), w_gate, w_up, w_down)[1]
+    _check(float(counters["moe.assignments_dropped"]) == 0.0, f"assignments dropped: {counters}")
     return errors
 
 
@@ -266,6 +376,7 @@ def kernel_leg():
                 state_ref = upd_ref(q, k_shard, v, q_pos, k_pos, *state_ref)
         for name, g, e in zip("mlo", state, state_ref):
             errors[f"shard_update_{name}_{tag}"] = _rel_err(g, e)
+    errors.update(kimi_linear_ops())
     errors = _within_tolerance(errors, "kernel")
 
     # TransformerLM through its default attention (the flash kernel on tpu)

@@ -469,6 +469,17 @@ class Arguments:
                 from .constants import FEDPROX_DEFAULT_MU
 
                 self.proximal_mu = FEDPROX_DEFAULT_MU
+        # a model built from a published config.json (models/hub.py): a dict of
+        # its keys, or the path of a JSON file that holds one
+        mc = getattr(self, "model_config", None)
+        if mc is not None:
+            if isinstance(mc, (str, os.PathLike)):
+                if not os.path.isfile(mc):
+                    raise ValueError(f"model_config names no file: {mc!r}")
+            elif not isinstance(mc, dict):
+                raise ValueError(
+                    "model_config must be a dict of the published config.json's keys "
+                    f"or the path of a JSON file (got {type(mc).__name__})")
         # population / pacing knobs fail at config time, not as a traceback
         # mid-run when the first round opens (core/population semantics)
         oc = getattr(self, "pacing_overcommit", None)

@@ -126,8 +126,11 @@ def build_packed_device_fn(
     """The per-device round body (composed under shard_map by the simulator).
 
     Returns ``fn(variables, server_state, x_all, y_all, idx, mask, boundary,
-    weight, slot, n_steps, rng, cex) -> (acc, wsum, lsum, cnt, ext, outs)``
-    where cex has leading axis slots_per_device and outs matches it.
+    weight, slot, n_steps, rng, cex) -> (acc, wsum, lsum, cnt, ext, outs,
+    counters)`` where cex has leading axis slots_per_device and outs matches
+    it.  ``counters``: the stream's sums of what the module sows a step
+    (``module.round_counters``, e.g. an expert layer's loads), ``{}`` for a
+    module that names none.
 
     ``capture_updates``: also record each slot's final (post-``post_train``)
     variables into the per-slot output buffer — ``outs`` becomes
@@ -137,7 +140,8 @@ def build_packed_device_fn(
     """
     tx = make_optimizer(args)
     grad_hook = resolve_grad_hook(args, algo.grad_hook())
-    loss_and_updated = build_loss_fn(module, has_dropout, loss)
+    counter_names = tuple(getattr(module, "round_counters", ()))
+    loss_and_updated = build_loss_fn(module, has_dropout, loss, counter_names)
 
     from ...simulation.xla.algorithms import InMeshAlgorithm
 
@@ -191,6 +195,9 @@ def build_packed_device_fn(
             (lval, updated), grads = jax.value_and_grad(
                 loss_and_updated, has_aux=True
             )(params, other, bx, by, bmask, key)
+            counts = {}
+            if counter_names:
+                updated, counts = updated
             if grad_hook is not None:
                 s = slot[step]  # device-local schedule slot
                 extra = None
@@ -216,11 +223,11 @@ def build_packed_device_fn(
                 if updated:
                     other = jax.tree_util.tree_map(
                         lambda n, o: jnp.where(any_valid, n, o), updated, other)
-            return params, other, opt_state, lval, bmask
+            return params, other, opt_state, lval, bmask, counts
 
         def body(carry):
             (step, params, other, opt_state, c_steps, c_loss, c_cnt,
-             acc, wsum, lsum, cnt, ext, outs) = carry
+             acc, wsum, lsum, cnt, ext, outs, ctr) = carry
             with jax.named_scope("fed.gather"):
                 if pregather:
                     bx, by = bx_stream[step], by_stream[step]
@@ -228,9 +235,11 @@ def build_packed_device_fn(
                     bx = jnp.take(x_all, idx[step], axis=0)
                     by = jnp.take(y_all, idx[step], axis=0)
             with jax.named_scope("fed.local_step"):
-                params, other, opt_state, lval, bmask = local_step(
+                params, other, opt_state, lval, bmask, counts = local_step(
                     step, params, other, opt_state, bx, by)
-            c_steps = c_steps + (jnp.sum(bmask) > 0).astype(jnp.float32)
+            valid = (jnp.sum(bmask) > 0).astype(jnp.float32)
+            ctr = {n: ctr[n] + valid * counts[n] for n in ctr}
+            c_steps = c_steps + valid
             c_loss = c_loss + lval * jnp.sum(bmask)
             c_cnt = c_cnt + jnp.sum(bmask)
 
@@ -288,10 +297,11 @@ def build_packed_device_fn(
                      acc, wsum, lsum, cnt, ext, outs),
                 )
             return (step + 1, params, other, opt_state, c_steps, c_loss, c_cnt,
-                    acc, wsum, lsum, cnt, ext, outs)
+                    acc, wsum, lsum, cnt, ext, outs, ctr)
 
         init = (jnp.int32(0), params0, other0, opt0, 0.0, 0.0, 0.0,
-                zeros_vars, 0.0, 0.0, 0.0, ext0, outs0)
+                zeros_vars, 0.0, 0.0, 0.0, ext0, outs0,
+                {n: jnp.zeros((), jnp.float32) for n in counter_names})
         if scanning:
             # static-length scan over the bucketed stream: XLA can pipeline
             # iterations (no traced trip count); tail steps beyond n_steps
@@ -302,10 +312,10 @@ def build_packed_device_fn(
             final, _ = jax.lax.scan(
                 scan_body, init[1:], jnp.arange(idx.shape[0], dtype=jnp.int32)
             )
-            (_, _, _, _, _, _, acc, wsum, lsum, cnt, ext, outs) = final
+            (_, _, _, _, _, _, acc, wsum, lsum, cnt, ext, outs, ctr) = final
         else:
             final = jax.lax.while_loop(lambda c: c[0] < n_steps, body, init)
-            (_, _, _, _, _, _, _, acc, wsum, lsum, cnt, ext, outs) = final
-        return acc, wsum, lsum, cnt, ext, outs
+            (_, _, _, _, _, _, _, acc, wsum, lsum, cnt, ext, outs, ctr) = final
+        return acc, wsum, lsum, cnt, ext, outs, ctr
 
     return device_fn

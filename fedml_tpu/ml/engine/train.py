@@ -176,15 +176,22 @@ def resolve_grad_hook(args, grad_hook: Optional[Callable]) -> Optional[Callable]
     return grad_hook
 
 
-def build_loss_fn(module, has_dropout: bool = True, loss: str = "ce") -> Callable:
+def build_loss_fn(module, has_dropout: bool = True, loss: str = "ce",
+                  counters: tuple = ()) -> Callable:
     """Shared masked-loss closure for both engines: applies the module with
     any mutable (non-param) collections threaded through, returns
-    ``(loss_val, updated_collections)``."""
+    ``(loss_val, updated_collections)``.
+
+    ``counters``: names the module sows into its ``counters`` collection in a
+    training step (``module.round_counters``).  Given any, the closure returns
+    ``(loss_val, (updated_collections, {name: sum over the layers that sowed
+    it}))``: numbers that come out of the compiled step beside the loss and
+    are no part of the model's state."""
     loss_kind = LOSS_FNS[loss]
 
     def loss_fn(params, other_vars, bx, by, bmask, rng):
         variables = dict(other_vars, params=params)
-        mutable = [k for k in other_vars.keys()]
+        mutable = [k for k in other_vars.keys()] + (["counters"] if counters else [])
         rngs = {"dropout": rng} if has_dropout else None
         if mutable:
             logits, updated = module.apply(
@@ -194,7 +201,13 @@ def build_loss_fn(module, has_dropout: bool = True, loss: str = "ce") -> Callabl
             logits = module.apply(variables, bx, train=True, rngs=rngs)
             updated = {}
         loss_val, _ = loss_kind(logits, by, bmask)
-        return loss_val, updated
+        if not counters:
+            return loss_val, updated
+        updated = dict(updated)
+        sown = jax.tree_util.tree_flatten_with_path(updated.pop("counters", {}))[0]
+        sums = {name: sum((v for path, v in sown if path[-1].key == name),
+                          jnp.zeros((), jnp.float32)) for name in counters}
+        return loss_val, (updated, sums)
 
     return loss_fn
 

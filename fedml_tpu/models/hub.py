@@ -17,6 +17,9 @@ logger = logging.getLogger(__name__)
 
 
 _BF16_MODELS = {"resnet20", "resnet56", "resnet18", "resnet18_gn"}
+# built from ``args.model_config`` (a published config.json's keys), which
+# states its own compute dtype
+_CONFIG_MODELS = {"kimi_linear"}
 
 
 def create(args: Any, output_dim: int) -> nn.Module:
@@ -25,6 +28,14 @@ def create(args: Any, output_dim: int) -> nn.Module:
 
     import jax.numpy as jnp
 
+    if name in _CONFIG_MODELS:
+        from .kimi_linear import KimiLinearConfig, KimiLinearLM, load_config
+
+        config = getattr(args, "model_config", None)
+        if config is None:
+            raise ValueError(f"model {name!r} is built from model_config (a dict or a JSON "
+                             "file with the published config.json's keys); none was given")
+        return KimiLinearLM(KimiLinearConfig.from_dict(load_config(config)))
     if _dtype(args) is not jnp.float32 and name not in _BF16_MODELS:
         logger.warning(
             "compute_dtype=%s is only plumbed into %s; model %r runs fp32",

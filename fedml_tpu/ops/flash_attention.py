@@ -71,7 +71,7 @@ _NN = (((1,), (0,)), ((), ()))  # a @ b
 def reference_attention(q, k, v, causal: bool = True):
     """Fused-XLA attention, [B, L, H, D] layout (fallback, test oracle, and
     the single fused-attention definition — models/transformer.py delegates
-    here)."""
+    here).  v may have another width than q and k."""
     d = q.shape[-1]
     scores = jnp.einsum("blhd,bmhd->bhlm", q, k).astype(jnp.float32) / jnp.sqrt(
         jnp.float32(d)
@@ -176,10 +176,12 @@ def _live_q_block(i, j, *, block_q, block_k, causal, valid_len):
 _VMEM_BUDGET = 16 * 2**20
 # What a step of each kernel holds: q-sized and k-sized blocks the pipeline
 # double-buffers in the input dtype, and q-sized and k-sized f32 accumulators.
+# Each entry counts (blocks of the q/k width, blocks of the v width): the two
+# widths differ under latent attention (q, k of 192, v of 128).
 _FOOTPRINT = {
-    "flash_fwd": (2, 2, 1, 0),      # q, out | k, v | acc
-    "flash_bwd_dq": (3, 2, 1, 0),   # q, dO, dq | k, v | acc
-    "flash_bwd_dkv": (2, 4, 0, 2),  # q, dO | k, v, dk, dv | dk, dv
+    "flash_fwd": ((1, 1), (1, 1), (0, 1), (0, 0)),      # q, out | k, v | acc
+    "flash_bwd_dq": ((2, 1), (1, 1), (1, 0), (0, 0)),   # q, dq, dO | k, v | acc
+    "flash_bwd_dkv": ((1, 1), (2, 2), (0, 0), (1, 1)),  # q, dO | k, dk, v, dv | dk, dv
 }
 # f32 [block_q, block_k] intermediates a step is reckoned to hold at once
 # (scores -> probs in place, one more, a bf16 copy for the MXU).  Calibrated
@@ -204,17 +206,20 @@ def _lane_round(n):
     return -(-n // _LANES) * _LANES
 
 
-def _vmem_bytes(kernel, block_q, block_k, D, itemsize):
-    """Upper estimate of the VMEM one grid step of ``kernel`` holds."""
-    q_pipe, k_pipe, q_acc, k_acc = _FOOTPRINT[kernel]
-    d = _lane_round(D)  # the last dim pads to whole lanes
+def _vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv=None):
+    """Upper estimate of the VMEM one grid step of ``kernel`` holds; ``D`` is
+    the width of q and k, ``Dv`` that of v and the output (default: ``D``)."""
+    # the last dim pads to whole lanes
+    widths = (_lane_round(D), _lane_round(D if Dv is None else Dv))
+    q_pipe, k_pipe, q_acc, k_acc = (
+        sum(n * w for n, w in zip(counts, widths)) for counts in _FOOTPRINT[kernel])
     stats = 2 * 4 * _LANES * block_q  # m, l columns (lane-padded); lse, delta rows
-    return int(2 * itemsize * d * (q_pipe * block_q + k_pipe * block_k)
-               + 4 * d * (q_acc * block_q + k_acc * block_k)
+    return int(2 * itemsize * (q_pipe * block_q + k_pipe * block_k)
+               + 4 * (q_acc * block_q + k_acc * block_k)
                + 4 * _SCORE_TILES * block_q * block_k + stats)
 
 
-def _choose_blocks(kernel, L, D, dtype):
+def _choose_blocks(kernel, L, D, dtype, Dv=None):
     """(block_q, block_k) of ``kernel`` for a sequence of ``L``, from what the
     call can see.  Each block is the largest multiple of 128 that divides the
     lane-rounded length and is at most the kernel's ``_BLOCK_TARGET``, so no
@@ -229,7 +234,7 @@ def _choose_blocks(kernel, L, D, dtype):
     itemsize = jnp.dtype(dtype).itemsize
     block_q, block_k = (max(b for b in fits if b <= target)
                         for target in _BLOCK_TARGET[kernel])
-    while (_vmem_bytes(kernel, block_q, block_k, D, itemsize) > _VMEM_BUDGET
+    while (_vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv) > _VMEM_BUDGET
            and max(block_q, block_k) > _LANES):
         if block_q >= block_k:
             block_q = max(b for b in fits if b < block_q)
@@ -245,7 +250,7 @@ def _fit_block(block, L):
     return min(block, _lane_round(L))
 
 
-def _geometry(L, D, dtype, block_q, block_k):
+def _geometry(L, D, dtype, block_q, block_k, Dv=None):
     """``{kernel: (block_q, block_k)}`` and the padded length all three
     kernels of a call share.  An explicit block is every kernel's; one left
     ``None`` is each kernel's own choice (:func:`_choose_blocks`).  The
@@ -255,7 +260,7 @@ def _geometry(L, D, dtype, block_q, block_k):
     written).  Chosen blocks all divide the lane-rounded length."""
     blocks = {}
     for kernel in _BLOCK_TARGET:
-        own_q, own_k = _choose_blocks(kernel, L, D, dtype)
+        own_q, own_k = _choose_blocks(kernel, L, D, dtype, Dv)
         blocks[kernel] = (_fit_block(block_q, L) if block_q else own_q,
                           _fit_block(block_k, L) if block_k else own_k)
     m = math.lcm(*(b for pair in blocks.values() for b in pair))
@@ -288,15 +293,25 @@ _GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _to_bh(x, B, L, H, D, Lp):  # [B, L, H, D] -> [B*H, Lp, D]
+def _to_bh(x, B, L, H, D, Lp, Dp=None):  # [B, L, H, D] -> [B*H, Lp, Dp or D]
     x = x.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    if Lp != L:
-        x = jnp.pad(x, ((0, 0), (0, Lp - L), (0, 0)))
+    Dp = D if Dp is None else Dp
+    if Lp != L or Dp != D:
+        x = jnp.pad(x, ((0, 0), (0, Lp - L), (0, Dp - D)))
     return x
 
 
-def _from_bh(x, B, L, H, D):  # [B*H, Lp, D] -> [B, L, H, D]
-    return x[:, :L].reshape(B, H, L, D).transpose(0, 2, 1, 3)
+def _from_bh(x, B, L, H, D):  # [B*H, Lp, >=D] -> [B, L, H, D]
+    return x[:, :L, :D].reshape(B, H, L, D).transpose(0, 2, 1, 3)
+
+
+def _head_widths(q, v):
+    """(D, Dv, Dp): the width of q and k, that of v and the output, and the
+    width q and k are zero-padded to where theirs is no lane multiple and
+    differs from v's (latent attention: 192 -> 256; zero columns leave q.k
+    as it is).  Equal widths are left alone, as they always were."""
+    D, Dv = q.shape[-1], v.shape[-1]
+    return D, Dv, (_lane_round(D) if D != Dv else D)
 
 
 def _scratch(block_q, D):
@@ -353,28 +368,43 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         lse_ref[0] = _col_to_row(lse)
 
 
-def _fwd_call(qb, kb, vb, blocks, causal, valid_len, interpret):
-    """``flash_fwd`` over ``[B*H, Lp, D]`` operands: (out, lse [B*H, 1, Lp])."""
+def _specs(D, Dv, block_q, block_k, q_index, k_index):
+    """BlockSpecs of a q-sized and a k-sized block at the q/k width ``D`` and
+    at the v width ``Dv`` (one object each where the widths are equal)."""
+    def spec(block, width, index):
+        return pl.BlockSpec((1, block, width), lambda *ids: (*index(*ids), 0))
+
+    q_spec, k_spec = spec(block_q, D, q_index), spec(block_k, D, k_index)
+    if Dv == D:
+        return q_spec, k_spec, q_spec, k_spec
+    return q_spec, k_spec, spec(block_q, Dv, q_index), spec(block_k, Dv, k_index)
+
+
+def _fwd_call(qb, kb, vb, blocks, causal, valid_len, interpret, scale=None):
+    """``flash_fwd`` over ``[B*H, Lp, D]`` q and k and ``[B*H, Lp, Dv]`` v:
+    (out [B*H, Lp, Dv], lse [B*H, 1, Lp]).  ``scale`` defaults to that of the
+    operands' own width (a caller that zero-padded q and k gives the real one)."""
     BH, Lp, D = qb.shape
+    Dv = vb.shape[-1]
     block_q, block_k = blocks
     tile, n_qb, n_kb = _tiling("flash_fwd", Lp, blocks, causal, valid_len)
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec(
-        (1, block_k, D), lambda b, i, j: (b, _live_k_block(i, j, **tile), 0))
+    q_spec, k_spec, o_spec, v_spec = _specs(
+        D, Dv, block_q, block_k, lambda b, i, j: (b, i),
+        lambda b, i, j: (b, _live_k_block(i, j, **tile)))
     return pl.pallas_call(
         functools.partial(_flash_kernel, n_kb=n_kb,
-                          scale=float(1.0 / (D**0.5)), tile=tile),
+                          scale=float(scale or 1.0 / (D**0.5)), tile=tile),
         grid=(BH, n_qb, n_kb),
-        in_specs=[q_spec, k_spec, k_spec],
+        in_specs=[q_spec, k_spec, v_spec],
         out_specs=[
-            q_spec,
+            o_spec,
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype),
+            jax.ShapeDtypeStruct((BH, Lp, Dv), qb.dtype),
             jax.ShapeDtypeStruct((BH, 1, Lp), jnp.float32),
         ],
-        scratch_shapes=_scratch(block_q, D),
+        scratch_shapes=_scratch(block_q, Dv),
         compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
         name="flash_fwd",
@@ -383,11 +413,14 @@ def _fwd_call(qb, kb, vb, blocks, causal, valid_len, interpret):
 
 def _flash_forward(q, k, v, causal, block_q, block_k, interpret,
                    with_lse: bool = False):
-    B, L, H, D = q.shape
-    blocks, Lp = _geometry(L, D, q.dtype, block_q, block_k)
-    qb, kb, vb = (_to_bh(x, B, L, H, D, Lp) for x in (q, k, v))
-    out, lse = _fwd_call(qb, kb, vb, blocks["flash_fwd"], causal, L, interpret)
-    out = _from_bh(out, B, L, H, D)
+    B, L, H, _ = q.shape
+    D, Dv, Dp = _head_widths(q, v)
+    blocks, Lp = _geometry(L, Dp, q.dtype, block_q, block_k, Dv)
+    qb, kb = (_to_bh(x, B, L, H, D, Lp, Dp) for x in (q, k))
+    vb = _to_bh(v, B, L, H, Dv, Lp)
+    out, lse = _fwd_call(qb, kb, vb, blocks["flash_fwd"], causal, L, interpret,
+                         scale=1.0 / (D**0.5))
+    out = _from_bh(out, B, L, H, Dv)
     return (out, lse) if with_lse else out
 
 
@@ -489,21 +522,23 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _dq_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret):
-    """``flash_bwd_dq`` over ``[B*H, Lp, D]`` operands and ``[B*H, 1, Lp]``
-    row statistics: dQ, queries-major like the forward."""
+def _dq_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret,
+             scale=None):
+    """``flash_bwd_dq`` over ``[B*H, Lp, D]`` q and k, ``[B*H, Lp, Dv]`` v and
+    dO and ``[B*H, 1, Lp]`` row statistics: dQ, queries-major like the forward."""
     BH, Lp, D = qb.shape
+    Dv = vb.shape[-1]
     block_q, block_k = blocks
     tile, n_qb, n_kb = _tiling("flash_bwd_dq", Lp, blocks, causal, valid_len)
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec(
-        (1, block_k, D), lambda b, i, j: (b, _live_k_block(i, j, **tile), 0))
+    q_spec, k_spec, do_spec, v_spec = _specs(
+        D, Dv, block_q, block_k, lambda b, i, j: (b, i),
+        lambda b, i, j: (b, _live_k_block(i, j, **tile)))
     row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
     return pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, n_kb=n_kb,
-                          scale=float(1.0 / (D**0.5)), tile=tile),
+                          scale=float(scale or 1.0 / (D**0.5)), tile=tile),
         grid=(BH, n_qb, n_kb),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
@@ -513,29 +548,31 @@ def _dq_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret):
     )(qb, kb, vb, dob, lse, delta)
 
 
-def _dkv_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret):
+def _dkv_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret,
+              scale=None):
     """``flash_bwd_dkv`` over the same operands: (dK, dV), keys-major — the
     grid is (bh, kj, qi) and the q-side blocks follow the inner axis."""
     BH, Lp, D = qb.shape
+    Dv = vb.shape[-1]
     block_q, block_k = blocks
     tile, n_qb, n_kb = _tiling("flash_bwd_dkv", Lp, blocks, causal, valid_len)
-    q_spec = pl.BlockSpec(
-        (1, block_q, D), lambda b, j, i: (b, _live_q_block(i, j, **tile), 0))
-    k_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
+    q_spec, k_spec, do_spec, v_spec = _specs(
+        D, Dv, block_q, block_k, lambda b, j, i: (b, _live_q_block(i, j, **tile)),
+        lambda b, j, i: (b, j))
     row_spec = pl.BlockSpec(
         (1, 1, block_q), lambda b, j, i: (b, 0, _live_q_block(i, j, **tile)))
     return pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, n_qb=n_qb,
-                          scale=float(1.0 / (D**0.5)), tile=tile),
+                          scale=float(scale or 1.0 / (D**0.5)), tile=tile),
         grid=(BH, n_kb, n_qb),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-        out_specs=[k_spec, k_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype),
-            jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype),
+            jax.ShapeDtypeStruct((BH, Lp, Dv), qb.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
         compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
         name="flash_bwd_dkv",
@@ -546,18 +583,20 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
     """Pallas flash backward: same blockwise structure as the forward — P is
     re-materialized per block from (q, k, lse), so backward memory is
     O(block² ) per core instead of the O(L²) probs matrix."""
-    B, L, H, D = q.shape
-    blocks, Lp = _geometry(L, D, q.dtype, block_q, block_k)
-    qb, kb, vb, dob, ob = (_to_bh(x, B, L, H, D, Lp)
-                           for x in (q, k, v, g.astype(q.dtype), out))
+    B, L, H, _ = q.shape
+    D, Dv, Dp = _head_widths(q, v)
+    blocks, Lp = _geometry(L, Dp, q.dtype, block_q, block_k, Dv)
+    qb, kb = (_to_bh(x, B, L, H, D, Lp, Dp) for x in (q, k))
+    vb, dob, ob = (_to_bh(x, B, L, H, Dv, Lp) for x in (v, g.astype(q.dtype), out))
     # delta_i = rowsum(dO * O): tiny elementwise pass, fused by XLA
     delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
                     axis=-1)[:, None, :]  # [B*H, 1, Lp], like lse
     operands = (qb, kb, vb, dob, lse, delta)
-    dq = _dq_call(*operands, blocks["flash_bwd_dq"], causal, L, interpret)
-    dk, dv = _dkv_call(*operands, blocks["flash_bwd_dkv"], causal, L, interpret)
+    scale = 1.0 / (D**0.5)
+    dq = _dq_call(*operands, blocks["flash_bwd_dq"], causal, L, interpret, scale)
+    dk, dv = _dkv_call(*operands, blocks["flash_bwd_dkv"], causal, L, interpret, scale)
     return (_from_bh(dq, B, L, H, D), _from_bh(dk, B, L, H, D),
-            _from_bh(dv, B, L, H, D))
+            _from_bh(dv, B, L, H, Dv))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -570,7 +609,8 @@ def flash_attention(
     block_k: int | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Pallas blockwise attention. q/k/v: [B, L, H, D] -> [B, L, H, D].
+    """Pallas blockwise attention. q/k: [B, L, H, D], v: [B, L, H, Dv] ->
+    [B, L, H, Dv] (``Dv`` may differ from ``D``: latent attention's 192 / 128).
     ``block_q`` / ``block_k`` left ``None`` are chosen per kernel from the
     shape (:func:`_choose_blocks`); ragged L is padded internally, to its
     lane rounding then and to a common multiple of explicit blocks else."""

@@ -534,7 +534,7 @@ class XLASimulator:
         def fedml_round_packed(variables, server_state, x_all, y_all, idx, mask,
                                boundary, weight, slot, n_steps, rngs, cex):
             # arrays with a [n_dev, ...] leading axis arrive as [1, ...]
-            acc, wsum, lsum, cnt, ext, outs = device_fn(
+            acc, wsum, lsum, cnt, ext, outs, counters = device_fn(
                 variables, server_state, x_all, y_all, idx[0], mask[0],
                 boundary[0], weight[0], slot[0], n_steps[0], rngs[0], cex,
             )
@@ -542,21 +542,25 @@ class XLASimulator:
                 lsum = jax.lax.psum(lsum, "client")
                 cnt = jax.lax.psum(cnt, "client")
                 ext = jax.lax.psum(ext, "client")
+                # the round's sums of what the module counts a step: a module
+                # that names any (``round_counters``) gets them back last,
+                # beside the loss; for every other the results are as they were
+                counted = (jax.lax.psum(counters, "client"),) if counters else ()
             mean_loss = lsum / jnp.maximum(cnt, 1.0)
             if stacked:
-                return mean_loss, outs, ext
+                return (mean_loss, outs, ext) + counted
             with jax.named_scope("fed.exchange"):
                 acc = jax.lax.psum(acc, "client")
                 wsum = jax.lax.psum(wsum, "client")
             if sharded:
                 # program ends at the reduced accumulator; the model-sharded
                 # tail applies the server step (same split as _build_round_fn)
-                return acc, wsum, ext, mean_loss, outs
+                return (acc, wsum, ext, mean_loss, outs) + counted
             with jax.named_scope("fed.server_step"):
                 new_global, new_state = algo.server_update(
                     acc, wsum, ext, variables, server_state
                 )
-            return new_global, new_state, mean_loss, outs
+            return (new_global, new_state, mean_loss, outs) + counted
 
         if stacked:
             out_specs = (P(), P("client"), P())
@@ -564,6 +568,8 @@ class XLASimulator:
             out_specs = (P(), P(), P(), P(), P("client"))
         else:
             out_specs = (P(), P(), P(), P("client"))
+        if getattr(self.module, "round_counters", ()):
+            out_specs += (P(),)
         self._round_fn = jax.jit(
             shard_map(
                 fedml_round_packed,
@@ -1001,7 +1007,7 @@ class XLASimulator:
                 # security path: the round returns the sharded per-client
                 # update stack; the second jitted program runs stacked model
                 # attacks + robust aggregation + the server step on device
-                mean_loss, outs, ext = self._round_fn(*round_inputs)
+                mean_loss, outs, ext, *counters = self._round_fn(*round_inputs)
                 stack = outs["update"]
                 taus = outs["tau"]
                 outs = outs["algo"]
@@ -1097,7 +1103,7 @@ class XLASimulator:
                 # two programs: the client-axis training round ends at the
                 # psum'd accumulator; the model-sharded GSPMD tail applies
                 # the algorithm's server step on donated resident buffers
-                acc, wsum, ext, mean_loss, outs = self._round_fn(*round_inputs)
+                acc, wsum, ext, mean_loss, outs, *counters = self._round_fn(*round_inputs)
                 var_sh, state_sh, repl = self._tail_shardings
                 t_tail = time.perf_counter()
                 with obs.span("round.server_update", ph.ctx,
@@ -1126,9 +1132,8 @@ class XLASimulator:
                     self.variables = jax.device_put(self.variables, full)
                     self.server_state = jax.device_put(self.server_state, full)
             else:
-                self.variables, self.server_state, mean_loss, outs = self._round_fn(
-                    *round_inputs
-                )
+                (self.variables, self.server_state, mean_loss, outs,
+                 *counters) = self._round_fn(*round_inputs)
         with _Phase(rec, "round.wait", rsp.ctx, round_idx) as ph:
             self.client_state = self.algo.apply_client_outs(self.client_state, ids, outs)
             self.algo.host_round_end(ids, participated, round_idx)
@@ -1170,6 +1175,12 @@ class XLASimulator:
             # orchestration
             compile_s = max(0.0, obs.compile_seconds_total() - compile_s0)
             loss = float(mean_loss)
+            # what the module counted in the compiled round (the packed round
+            # returns it last; the padded round has none): into round_log and
+            # the registry under the module's own names
+            for name, value in (counters[0] if counters else {}).items():
+                rec[name] = float(value)
+                obs.counter_inc(name, rec[name])
             if tele_cap is not None and tele_merger is not None:
                 tctx = tele_cap.record_span(
                     "client.train", max(0.0, dt - compile_s), parent=rsp.ctx,

@@ -72,3 +72,60 @@ def test_pallas_entry_points_compile_under_mosaic(v5e, shape):
         args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
                 for a in args]
         assert jax.jit(fn).lower(*args).compile() is not None, name
+
+
+# latent attention: q and k of 192 (128 + 64, no lane multiple), v of 128; the
+# second is the benchmark's own shape (kimi-linear-48b-a3b-sim, one sequence)
+MLA_SHAPES = [(2, 1000, 4, 192, 128, jnp.bfloat16), (1, 8192, 32, 192, 128, jnp.bfloat16)]
+
+
+def _mla_args(B, L, H, D, Dv, dtype, sharding=None):
+    kw = {} if sharding is None else {"sharding": sharding}
+    qk = jax.ShapeDtypeStruct((B, L, H, D), dtype, **kw)
+    return (qk, qk, jax.ShapeDtypeStruct((B, L, H, Dv), dtype, **kw))
+
+
+@pytest.mark.parametrize("shape", MLA_SHAPES, ids=lambda s: "x".join(map(str, s[:5])))
+def test_unequal_head_widths_lower_for_tpu(shape):
+    for name, fn in (("forward", _forward), ("grad", _grad)):
+        text = jax.jit(fn).trace(*_mla_args(*shape)).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text, name
+
+
+@pytest.mark.parametrize("shape", MLA_SHAPES, ids=lambda s: "x".join(map(str, s[:5])))
+def test_unequal_head_widths_compile_under_mosaic(v5e, shape):
+    args = _mla_args(*shape, sharding=jax.sharding.SingleDeviceSharding(v5e))
+    for name, fn in (("forward", _forward), ("grad", _grad)):
+        assert jax.jit(fn).lower(*args).compile() is not None, name
+
+
+def test_kimi_linear_ops_compile_for_v5e(v5e):
+    """The XLA-op paths the ``kimi_linear`` decoder adds, forward and backward
+    at the benchmark cell's shapes: KDA chunkwise (scans, the triangular
+    solves) and the expert layer's grouped products (``ragged_dot``, which the
+    TPU compiler turns into its own Mosaic kernels)."""
+    from fedml_tpu.models import kimi_linear
+    from fedml_tpu.ops import kda
+
+    sharding = jax.sharding.SingleDeviceSharding(v5e)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    qkv = s((1, 8192, 32, 128), jnp.bfloat16)
+    kda_args = (qkv, qkv, qkv, s((1, 8192, 32, 128), jnp.float32), s((1, 8192, 32), jnp.float32))
+    kda_grad = jax.grad(lambda *a: kda.kda_chunked(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2, 3, 4))
+    assert jax.jit(kda_grad).lower(*kda_args).compile() is not None
+
+    def experts(h, chosen, weights, w_gate, w_up, w_down):
+        out, counters = kimi_linear.grouped_experts(h, chosen, weights, (0, 8),
+                                                    w_gate, w_up, w_down)
+        return out.astype(jnp.float32).sum() + counters["moe.assignments_dropped"]
+
+    moe_args = (s((8192, 2304), jnp.bfloat16), s((8192, 8), jnp.int32),
+                s((8192, 8), jnp.float32), s((8, 2304, 1024), jnp.bfloat16),
+                s((8, 2304, 1024), jnp.bfloat16), s((8, 1024, 2304), jnp.bfloat16))
+    compiled = jax.jit(jax.grad(experts, argnums=(0, 3, 4, 5))).lower(*moe_args).compile()
+    assert "ragged" in compiled.as_text()
