@@ -22,9 +22,11 @@ before any data is generated.
   forward and ``jax.grad`` against ``reference_attention``, and
   ``flash_shard_update`` against ``shard_update_reference``, within
   TOLERANCE; at the ``kimi-linear-48b-a3b-sim`` cell's shapes flash at q/k
-  192 != v 128, KDA chunkwise forward and ``jax.grad`` against the per-token
-  recurrence and the expert layer's grouped products against a dense masked
-  loop (``kimi_linear_ops``); then TransformerLM training steps at the bench
+  192 != v 128, KDA through the entry the model calls (the kernels ``kda_fwd``
+  / ``kda_bwd``, or the leg fails) forward and ``jax.grad`` against the
+  per-token recurrence (``kda_errors``) and the expert layer's grouped products
+  against a dense masked loop (``kimi_linear_ops``); then TransformerLM
+  training steps at the bench
   transformer shapes through its DEFAULT attention.
 * leg C — only when the host has >= 4 devices: leg A again on the
   four-device ``client`` mesh (per-round loss must agree with leg A within
@@ -157,6 +159,51 @@ def _within_tolerance(errors, what):
     return errors
 
 
+def kda_errors(L, H, D):
+    """{name: error} of KDA through the entry the model calls
+    (``ops/kda.kda``), forward and all five gradients, every head in one call
+    against the per-token recurrence by groups of heads; plus ``kda_worst``.  On
+    the chip the entry has to have lowered to the kernels ``kda_fwd`` /
+    ``kda_bwd``: it is they that are held to TOLERANCE here."""
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.ops import kda
+
+    keys = jax.random.split(jax.random.PRNGKey(64), 6)
+    shape = (1, L, H, D)
+    q, k = (jax.random.normal(key, shape, jnp.float32) for key in keys[:2])
+    q, k = (x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)) for x in (q, k))
+    v, w = (jax.random.normal(key, shape, jnp.float32) for key in keys[2:4])
+    g = -0.1 * jax.nn.softplus(jax.random.normal(keys[4], shape, jnp.float32))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], shape[:3], jnp.float32))
+    args = (q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), g, beta)
+
+    def value_and_grads(fn, w):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2, 3, 4)))
+
+    lowered = value_and_grads(kda.kda, w).lower(*args)
+    text = lowered.as_text()
+    _check("kda_fwd" in text and "kda_bwd" in text,
+           "ops/kda.kda did not dispatch to the pallas kernels kda_fwd / kda_bwd")
+    _, got = lowered.compile()(*args)
+    out = jax.jit(kda.kda)(*args)
+    errors = {}
+    for h in range(0, H, ORACLE_HEADS):
+        hs = slice(h, h + ORACLE_HEADS)
+        part = [x[:, :, hs] for x in args]
+        with jax.default_matmul_precision("highest"):
+            _, exp = value_and_grads(kda.kda_recurrent, w[:, :, hs])(*part)
+            pairs = [("fwd_kda", out, jax.jit(kda.kda_recurrent)(*part))]
+        pairs += [(f"d{name}_kda", g_, e) for name, g_, e in zip(("q", "k", "v", "g", "beta"),
+                                                               got, exp)]
+        for name, a, e in pairs:
+            errors[name] = max(errors.get(name, 0.0), _rel_err(a[:, :, hs], e))
+    errors["kda_worst"] = max(errors.values())
+    return errors
+
+
 def kimi_linear_ops():
     """{name: error} of the ops the ``kimi_linear`` decoder adds, compiled at the
     cell's shapes, each against its reference at "highest" precision."""
@@ -164,7 +211,6 @@ def kimi_linear_ops():
     import jax.numpy as jnp
 
     from fedml_tpu.models import kimi_linear
-    from fedml_tpu.ops import kda
     from fedml_tpu.ops.flash_attention import flash_attention, reference_attention
 
     L, H, errors = KIMI["L"], KIMI["H"], {}
@@ -192,29 +238,7 @@ def kimi_linear_ops():
         for name, g, e in zip("qkv", got, exp):
             worst(f"d{name}_mla_flash", _rel_err(g[:, :, hs], e))
 
-    # KDA chunkwise, every head in one call; the per-token oracle by groups of heads
-    keys = jax.random.split(jax.random.PRNGKey(64), 6)
-    shape = (1, L, H, KIMI["kda"])
-    q, k = (jax.random.normal(key, shape, jnp.float32) for key in keys[:2])
-    q, k = (x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)) for x in (q, k))
-    v, w = (jax.random.normal(key, shape, jnp.float32) for key in keys[2:4])
-    g = -0.1 * jax.nn.softplus(jax.random.normal(keys[4], shape, jnp.float32))
-    beta = jax.nn.sigmoid(jax.random.normal(keys[5], shape[:3], jnp.float32))
-    args = (q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), g, beta)
-
-    def value_and_grads(fn, args, w):
-        return jax.jit(jax.value_and_grad(
-            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2, 3, 4)))(*args)
-
-    _, got = value_and_grads(kda.kda_chunked, args, w)
-    out = jax.jit(kda.kda_chunked)(*args)
-    for hs in heads:
-        part = [x[:, :, hs] for x in args]
-        with jax.default_matmul_precision("highest"):
-            _, exp = value_and_grads(kda.kda_recurrent, part, w[:, :, hs])
-            worst("fwd_kda", _rel_err(out[:, :, hs], jax.jit(kda.kda_recurrent)(*part)))
-        for name, g_, e in zip(("q", "k", "v", "g", "beta"), got, exp):
-            worst(f"d{name}_kda", _rel_err(g_[:, :, hs], e))
+    errors.update(kda_errors(L, H, KIMI["kda"]))
 
     # the expert layer's grouped products against a dense loop over the held experts
     keys = jax.random.split(jax.random.PRNGKey(256), 6)

@@ -189,7 +189,7 @@ class KDAMixer(nn.Module):
             (H, D))
         g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(f.astype(jnp.float32) + dt_bias)
         beta = jax.nn.sigmoid(proj("w_beta", (d, H), d, h, "bld,dh->blh").astype(jnp.float32))
-        o = kda_ops.kda_chunked(l2(q).astype(dt), l2(k).astype(dt), v, g, beta)
+        o = kda_ops.kda(l2(q).astype(dt), l2(k).astype(dt), v, g, beta)
         gate = proj("g_up", (r, H, D), r, proj("g_down", (d, r), d, h, "bld,dr->blr"),
                     "blr,rhk->blhk")
         o_norm = self.param("o_norm", nn.initializers.ones, (D,), jnp.float32)

@@ -77,3 +77,14 @@ def test_a_leg_that_raises_fails_the_run(monkeypatch, capsys):
     last = json.loads(capsys.readouterr().out.splitlines()[-1])
     assert last["ok"] is True and set(last) == {"ok", "device"}
     assert set(last["device"]) == {"platform", "kind", "count"}
+
+
+def test_leg_b_holds_the_kda_kernels_and_not_the_xla_path():
+    """``kda_errors`` goes through the entry the model calls; where that did not
+    lower to ``kda_fwd`` / ``kda_bwd`` (here: no TPU) the leg fails before it
+    compares anything, so a pass on the chip is the kernels' pass."""
+    sys.path.insert(0, ROOT)
+    import chip_smoke
+
+    with pytest.raises(AssertionError, match="kda_fwd / kda_bwd"):
+        chip_smoke.kda_errors(128, 2, 128)

@@ -67,6 +67,64 @@ def test_kda_chunkwise_is_the_per_token_recurrence(L, gate, chunks):
     assert all(bool(jnp.all(jnp.isfinite(g))) for g in got[1])
 
 
+# the kernels in interpret mode at d 128, over the cases above that the XLA path
+# answers for: L not a multiple of the chunk (and a block of three heads), L under
+# one chunk, strong gates, and three runs of 8 chunks, so that the carried state
+# and the reverse dS cross a run's boundary (L 1,100: the last run ends in padding)
+@pytest.mark.parametrize("L,gate,B,H,run", [(100, 1.0, 1, 3, 2), (40, 1.0, 2, 1, 1),
+                                            (130, 8.0, 1, 2, 3), (1100, 1.0, 1, 1, 8)])
+def test_kda_kernels_are_the_per_token_recurrence(L, gate, B, H, run):
+    from fedml_tpu.core import obs
+
+    args = _kda_inputs(L, B=B, H=H, D=128, gate=gate)
+    kernels = lambda *a: kda.kda_pallas(*a, interpret=True)
+    want = _value_and_grads(kda.kda_recurrent, args)
+    got = _value_and_grads(kernels, args)
+    np.testing.assert_allclose(kernels(*args), kda.kda_recurrent(*args), atol=5e-6)
+    _assert_close(want, got, 5e-5)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in got[1])
+    gauges = {r["metric"]: r["value"] for r in obs.registry().export() if r["kind"] == "gauge"}
+    assert gauges["kda.kernel"] == 1 and gauges["kda.chunk"] == 64
+    assert gauges["kda.chunks_per_step"] == run  # the shape's own: 18 chunks pad least by 8
+
+
+def test_kda_kernels_take_bfloat16():
+    """q, k, v as the model hands them over; o and their gradients leave in
+    bfloat16, so they are held to its rounding (2^-8 of the largest), the
+    float32 gradients of g and beta to what that rounding of o moves them."""
+    q, k, v, g, beta = _kda_inputs(100, B=1, H=2, D=128)
+    args = (q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), g, beta)
+    loss = lambda fn: lambda *a: fn(*a).astype(jnp.float32)
+    want = _value_and_grads(loss(kda.kda_recurrent), args)
+    got = _value_and_grads(loss(lambda *a: kda.kda_pallas(*a, interpret=True)), args)
+    assert [x.dtype for x in got[1]] == [x.dtype for x in args]
+    as_float = lambda t: jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), t)
+    _assert_close(as_float(want), as_float(got), 2.0 ** -7)
+    _assert_close(want[1][3:], got[1][3:], 1e-3)
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in as_float(got[1]))
+
+
+def test_kda_entry_dispatches_on_backend_and_shape():
+    """Off the TPU every shape is the XLA path's; on it the kernels take heads
+    whose widths are lane multiples (the tiny presets' d = 8 are not)."""
+    from fedml_tpu.core import obs
+
+    tiny, wide = _kda_inputs(70, B=1, H=1), jax.eval_shape(lambda: _kda_inputs(70, D=128))
+    obs.gauge_set("kda.kernel", 1)
+    assert jax.jit(kda.kda)(*tiny).shape == tiny[2].shape
+    gauges = {r["metric"]: r["value"] for r in obs.registry().export() if r["kind"] == "gauge"}
+    assert jax.default_backend() != "tpu" and gauges["kda.kernel"] == 0
+    assert kda._kernels_take(wide[0], wide[2]) and not kda._kernels_take(tiny[0], tiny[2])
+    # a step's heads and chunks, from the shape: the cell's, a float32 twin, a prime
+    # count of chunks (8 pads it least), one run, heads that 4 does not divide
+    assert kda._choose_step(128, 32, 128, 128, jnp.bfloat16) == (4, 16)
+    assert kda._choose_step(128, 32, 128, 128, jnp.float32) == (4, 8)
+    assert kda._choose_step(17, 32, 128, 128, jnp.bfloat16) == (4, 8)
+    assert kda._choose_step(5, 6, 128, 128, jnp.bfloat16) == (3, 5)
+    for heads, run in ((4, 16), (4, 8), (3, 5)):
+        assert kda._vmem_bytes(heads, run, 128, 128, 2) <= kda._VMEM_BUDGET
+
+
 @pytest.mark.parametrize("L", [75, 32])
 def test_reference_kda_by_chunks_is_its_per_token_form(L):
     args = _kda_inputs(L, gate=2.0, seed=1)
@@ -230,7 +288,7 @@ def test_packed_round_through_the_runner_is_the_references_round(model):
     assert record["moe.expert_load_max"] >= record["moe.expert_load_mean"] > 0
     gauges = {r["metric"]: r["value"] for r in obs.registry().export() if r["kind"] == "gauge"}
     assert gauges["moe.experts_held"] == 4 and gauges["moe.experts_total"] == 16
-    assert gauges["kda.chunk"] == 64
+    assert gauges["kda.chunk"] == 64 and gauges["kda.kernel"] == 0  # d 8, and no TPU
     program = driver.program
     driver.release()
     correct, table = compare.judge(compare.numbers(program, driver.reference_readings()),

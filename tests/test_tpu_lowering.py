@@ -100,6 +100,38 @@ def test_unequal_head_widths_compile_under_mosaic(v5e, shape):
         assert jax.jit(fn).lower(*args).compile() is not None, name
 
 
+# the KDA kernels at the benchmark cell's shape (kimi-linear-48b-a3b-sim, one
+# sequence) and at a ragged length under float32 inputs
+KDA_SHAPES = [(1, 8192, 32, 128, jnp.bfloat16), (2, 1000, 3, 128, jnp.float32)]
+
+
+def _kda_entry_points(B, L, H, D, dtype, sharding=None):
+    from fedml_tpu.ops import kda
+
+    kw = {} if sharding is None else {"sharding": sharding}
+    qkv = jax.ShapeDtypeStruct((B, L, H, D), dtype, **kw)
+    args = (qkv, qkv, qkv, jax.ShapeDtypeStruct((B, L, H, D), jnp.float32, **kw),
+            jax.ShapeDtypeStruct((B, L, H), jnp.float32, **kw))
+    grad = jax.grad(lambda *a: kda.kda_pallas(*a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2, 3, 4))
+    return [("forward", lambda *a: kda.kda_pallas(*a), args), ("grad", grad, args)]
+
+
+@pytest.mark.parametrize("shape", KDA_SHAPES, ids=lambda s: "x".join(map(str, s[:4])))
+def test_kda_kernels_lower_for_tpu(shape):
+    for name, fn, args in _kda_entry_points(*shape):
+        text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text and "kda_fwd" in text, name
+        assert ("kda_bwd" in text) == (name == "grad"), name
+
+
+@pytest.mark.parametrize("shape", KDA_SHAPES, ids=lambda s: "x".join(map(str, s[:4])))
+def test_kda_kernels_compile_under_mosaic(v5e, shape):
+    """The off-chip guard of the kernels' VMEM and layouts."""
+    for name, fn, args in _kda_entry_points(*shape, jax.sharding.SingleDeviceSharding(v5e)):
+        assert "kda_" in jax.jit(fn).lower(*args).compile().as_text(), name
+
+
 def test_kimi_linear_ops_compile_for_v5e(v5e):
     """The XLA-op paths the ``kimi_linear`` decoder adds, forward and backward
     at the benchmark cell's shapes: KDA chunkwise (scans, the triangular
