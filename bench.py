@@ -132,14 +132,11 @@ def _bench_args(n_chips: int, compute_dtype: str = "bf16"):
             },
             "model_args": {"model": "resnet56", "compute_dtype": compute_dtype},
             "train_args": {
-                # packed ragged-client round + 32 clients/round: measured on
-                # the v5e chip, packed-32 = 24.1k sps/chip vs padded-8 =
-                # 10.8k (padding waste eliminated + fixed per-round dispatch
-                # cost amortized over 4x the round compute)
+                # 32 clients/round: the fixed per-round dispatch cost
+                # amortized over 4x the round compute of 8
                 "federated_optimizer": "FedAvg",
                 "client_num_in_total": 100,
                 "client_num_per_round": min(100, max(32, n_chips * 8)),
-                "xla_pack": True,
                 "comm_round": 6,  # round 0 compiles, round 1 uploads data; 2-5 are steady state
                 "epochs": 1,
                 "batch_size": 64,
@@ -195,64 +192,6 @@ def _measure_eager_baseline(args, dataset, n_batches: int = 24) -> float:
     jax.block_until_ready(variables)
     dt = time.time() - t0
     return n_batches * b / max(dt, 1e-9)
-
-
-# the packed round's execution-strategy levers, shared with
-# tools/perf_sweep.py so the two grids cannot drift
-AUTOTUNE_VARIANTS = (
-    {},
-    {"xla_pregather": True},
-    {"xla_stream": "scan"},
-    {"xla_pregather": True, "xla_stream": "scan"},
-)
-
-
-def _autotune(args, dataset, model):
-    """Pick the fastest round execution strategy ON THIS CHIP before the
-    real measurement: AUTOTUNE_VARIANTS at the bench config, 5 rounds each
-    (round 0 compiles; throughput() medians rounds 1-4, riding out the
-    round-1 dataset upload).  The levers are equivalence-tested
-    (tests/test_packed_round.py) but their win is hardware-dependent.
-    Disable with BENCH_AUTOTUNE=0.  Returns ``(winning override dict,
-    winning simulator or None, failed variants)``.  Only ONE candidate
-    simulator is ever alive (peak HBM stays one simulator, exactly as
-    without autotune), so the compiled winner can only be handed back when
-    it is the LAST variant trained — which the grid orders it to be in the
-    expected case (both levers on); otherwise the caller rebuilds it (one
-    compile).  A variant that raises does not stop the others, but it is
-    returned by name so the record carries it and the run exits nonzero;
-    the winner is ``None`` if every variant failed."""
-    import copy
-    import traceback
-
-    from fedml_tpu.simulation.xla.fed_sim import XLASimulator
-
-    best = (0.0, None)
-    sim = None
-    last_overrides = None
-    failed = []
-    for overrides in AUTOTUNE_VARIANTS:
-        a = copy.deepcopy(args)
-        a.comm_round = 5
-        for k, v in overrides.items():
-            setattr(a, k, v)
-        try:
-            sim = None  # free the previous candidate BEFORE building the next
-            sim = XLASimulator(a, dataset, model)
-            sim.train()
-            sps = sim.throughput()["samples_per_sec"]
-            last_overrides = overrides
-            print(f"autotune {overrides}: {sps:.1f} samples/s", file=sys.stderr)
-        except Exception as e:
-            traceback.print_exc()
-            failed.append(f"autotune {overrides}: {type(e).__name__}: {e}")
-            sim = None  # never hand a failed variant's sim to the caller
-            continue
-        if best[1] is None or sps > best[0]:
-            best = (sps, overrides)
-    if best[1] is None:
-        return None, None, failed
-    return best[1], (sim if last_overrides == best[1] else None), failed
 
 
 def main() -> int | None:
@@ -320,23 +259,8 @@ def _main() -> int | None:
     eager_sps = _measure_eager_baseline(base_args, dataset)
 
     model = fedml_tpu.models.create(args, out_dim)
-    autotune_on = os.environ.get("BENCH_AUTOTUNE", "1") != "0"
-    tuned, sim, failed = (_autotune(args, dataset, model) if autotune_on
-                          else (None, None, []))
-    for k, v in (tuned or {}).items():
-        setattr(args, k, v)
-    if sim is not None:
-        # keep training the autotune winner: its round fn is already
-        # compiled, so the extra rounds below are pure steady-state
-        # measurement (one big XLA compile saved — matters when the chip
-        # window is short).  train() re-runs rounds 0..comm_round-1 and
-        # APPENDS to round_times; throughput() medians over all recorded
-        # post-warmup rounds.
-        sim.args.comm_round = int(args.comm_round)
-        sim.train()
-    else:
-        sim = XLASimulator(args, dataset, model)
-        sim.train()
+    sim = XLASimulator(args, dataset, model)
+    sim.train()
 
     # median per-round throughput over post-compile rounds: the steady-state
     # rate (compile + one-time dataset upload amortized out; see
@@ -359,10 +283,6 @@ def _main() -> int | None:
         "mfu": round(achieved_tflops / peaks["bf16_tflops"], 5),
         "compute_dtype": "bf16",
     }
-    if autotune_on:
-        # {} = baseline won; {...} = winning flags; null = every variant
-        # failed (distinct from BENCH_AUTOTUNE=0, where the key is absent)
-        out["autotuned"] = tuned
     phases = [
         ("obs_overhead", lambda: _measure_obs_overhead(sim)),
         ("telemetry_overhead", _measure_telemetry_overhead),
@@ -381,7 +301,7 @@ def _main() -> int | None:
     if os.environ.get("BENCH_SP"):
         phases.append(("sp", lambda: {
             "sp_samples_per_sec": round(_measure_sp(args, dataset), 2)}))
-    failed += _run_phases(out, phases)
+    failed = _run_phases(out, phases)
     if failed:
         out["failed_phases"] = failed
     _emit(out, "full")
@@ -1249,10 +1169,7 @@ def _measure_obs_overhead(sim) -> dict:
     from fedml_tpu.core import obs
     from fedml_tpu.core.mlops.sinks import InMemorySink
 
-    # post-compile tracing-off rounds (round 0 of the final train() run
-    # is steady-state too when the autotune winner was reused, but the
-    # conservative slice — drop the first recorded round — covers both
-    # construction paths)
+    # post-compile tracing-off rounds: drop the first recorded round
     mark = len(sim.round_times)
     off = [t for t in sim.round_times[1:mark]]
     export_dir = tempfile.mkdtemp(prefix="bench_export_")

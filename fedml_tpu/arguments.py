@@ -441,6 +441,15 @@ class Arguments:
         "comm_round",
     ]
 
+    # the in-mesh round's retired levers: (key, whether a value still means
+    # the packed while-loop stream), each value read as its lever read it
+    RETIRED_ROUND_KEYS = (
+        ("xla_pack", bool),
+        ("xla_stream", lambda v: str(v) == "while"),
+        ("xla_pregather", lambda v: not bool(v)),
+        ("xla_client_chunk", lambda v: int(v or 0) <= 1),
+    )
+
     def validate(self, for_training: bool = True) -> "Arguments":
         if for_training:
             missing = [k for k in self.REQUIRED_FOR_TRAINING if not hasattr(self, k)]
@@ -480,6 +489,14 @@ class Arguments:
                 raise ValueError(
                     "model_config must be a dict of the published config.json's keys "
                     f"or the path of a JSON file (got {type(mc).__name__})")
+        # a config from outside must not silently train another round than
+        # the one it names
+        for key, means_packed in self.RETIRED_ROUND_KEYS:
+            v = getattr(self, key, None)
+            if v is not None and not means_packed(v):
+                raise ValueError(
+                    f"{key}={v!r} asks for an in-mesh round that no longer exists: "
+                    "the packed stream is the only round (drop the key)")
         # population / pacing knobs fail at config time, not as a traceback
         # mid-run when the first round opens (core/population semantics)
         oc = getattr(self, "pacing_overcommit", None)

@@ -1,10 +1,9 @@
-"""Packed ragged-client round: eliminate per-client padding waste.
+"""The in-mesh round's data layout and walk: one packed stream of batches per
+device.  How a round's client data is laid out and walked is decided here
+and nowhere else (``XLASimulator`` composes ``device_fn`` under shard_map).
 
-The default in-mesh round pads EVERY client to the global max client size
-(fed_sim._pack_data), so with Dirichlet-skewed clients ~half the compute is
-padding (measured ~49% on the bench partition).  Per-step cost on TPU is
-essentially independent of which client a batch belongs to, so this module
-re-lays the round as ONE stream of batches per device:
+Per-step cost on TPU is essentially independent of which client a batch
+belongs to, so no client is padded to another's size:
 
 * each client contributes ceil(n_i/B) batches per epoch (its own padding is
   at most B-1 samples), clients back-to-back;
@@ -17,8 +16,8 @@ re-lays the round as ONE stream of batches per device:
   no recompile when the sampled client sizes change, and devices stop after
   their own last real step.
 
-Shuffling is host-side (numpy, seeded per (round, client, epoch)) since the
-batch order IS the data layout here; the device no longer permutes.
+Shuffling is host-side (numpy, seeded per (seed, round, client, epoch)) since
+the batch order IS the data layout here; the device does not permute.
 """
 
 from __future__ import annotations
@@ -110,16 +109,24 @@ def s_max_for(max_client_n: int, slots: int, batch_size: int, epochs: int) -> in
     return slots * (-(-max_client_n // batch_size)) * epochs
 
 
+def trim_to_bucket(sched: PackedSchedule, s_max: int) -> PackedSchedule:
+    """Cut the stream buffers to a quantized bucket of the round's longest
+    stream: the upload scales with the bucket, not the global worst case.
+    Quantum = s_max/8 -> at most 8 distinct shapes per run (each compiles
+    once, then caches) and <= one quantum of overshoot."""
+    s_used = max(int(sched.n_steps.max()), 1)
+    quantum = max(1, -(-s_max // 8))
+    s_bucket = min(-(-s_used // quantum) * quantum, s_max)
+    return PackedSchedule(*(a[:, :s_bucket] for a in sched[:5]), sched.n_steps)
+
+
 def build_packed_device_fn(
     module,
     args,
     algo,
     batch_size: int,
     slots_per_device: int,
-    has_dropout: bool = True,
     loss: str = "ce",
-    pregather: bool = False,
-    stream: str = "while",
     post_train=None,
     capture_updates: bool = False,
 ):
@@ -141,7 +148,7 @@ def build_packed_device_fn(
     tx = make_optimizer(args)
     grad_hook = resolve_grad_hook(args, algo.grad_hook())
     counter_names = tuple(getattr(module, "round_counters", ()))
-    loss_and_updated = build_loss_fn(module, has_dropout, loss, counter_names)
+    loss_and_updated = build_loss_fn(module, True, loss, counter_names)
 
     from ...simulation.xla.algorithms import InMeshAlgorithm
 
@@ -149,31 +156,9 @@ def build_packed_device_fn(
 
     def device_fn(variables, server_state, x_all, y_all, idx, mask, boundary,
                   weight, slot, n_steps, rng, cex):
-        if pregather:
-            # ONE vectorized gather for the whole round's stream (TPU row
-            # gathers are slow per-step; a single [S*B]-row gather amortizes
-            # to streaming HBM bandwidth), then the loop reads contiguous
-            # slices.  HBM cost: S_bucket * B * sample (the simulator trims
-            # S to a power-of-two bucket of the round's real step count).
-            with jax.named_scope("fed.gather"):
-                bx_stream = jnp.take(x_all, idx.reshape(-1), axis=0).reshape(
-                    idx.shape + x_all.shape[1:]
-                )
-                by_stream = jnp.take(y_all, idx.reshape(-1), axis=0).reshape(
-                    idx.shape + y_all.shape[1:]
-                )
         params0 = variables["params"]
         other0 = {k: v for k, v in variables.items() if k != "params"}
         opt0 = tx.init(params0)
-        # where-masking of all-padding steps is only needed when state would
-        # drift without it (stateful optimizer / mutable collections); plain
-        # SGD takes zero-grad no-op steps for free.  The scan stream runs the
-        # bucketed tail (step >= n_steps) as real iterations, and a grad hook
-        # (FedProx pull, SCAFFOLD correction) is nonzero even on zero grads —
-        # so scan always takes the masked path.
-        scanning = stream == "scan"
-        stateless = (not jax.tree_util.tree_leaves(opt0) and not other0
-                     and not (scanning and grad_hook is not None))
 
         zeros_vars = jax.tree_util.tree_map(
             lambda v: jnp.zeros_like(v, jnp.float32), variables
@@ -209,31 +194,18 @@ def build_packed_device_fn(
                     extra = algo.engine_extra(cex_i, server_state)
                 grads = grad_hook(grads, params, params0, extra)
             updates, new_opt = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            if stateless:
-                params, opt_state = new_params, new_opt
-                if updated:
-                    other = updated
-            else:
-                any_valid = jnp.sum(bmask) > 0
-                params = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(any_valid, n, o), new_params, params)
-                opt_state = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(any_valid, n, o), new_opt, opt_state)
-                if updated:
-                    other = jax.tree_util.tree_map(
-                        lambda n, o: jnp.where(any_valid, n, o), updated, other)
-            return params, other, opt_state, lval, bmask, counts
+            # every step the loop runs holds a real row (pack_round gives an
+            # epoch ceil(n_i/B) steps and the loop stops at n_steps), so
+            # optimizer state and mutable collections advance unconditionally
+            return (optax.apply_updates(params, updates), updated or other,
+                    new_opt, lval, bmask, counts)
 
         def body(carry):
             (step, params, other, opt_state, c_steps, c_loss, c_cnt,
              acc, wsum, lsum, cnt, ext, outs, ctr) = carry
             with jax.named_scope("fed.gather"):
-                if pregather:
-                    bx, by = bx_stream[step], by_stream[step]
-                else:
-                    bx = jnp.take(x_all, idx[step], axis=0)
-                    by = jnp.take(y_all, idx[step], axis=0)
+                bx = jnp.take(x_all, idx[step], axis=0)
+                by = jnp.take(y_all, idx[step], axis=0)
             with jax.named_scope("fed.local_step"):
                 params, other, opt_state, lval, bmask, counts = local_step(
                     step, params, other, opt_state, bx, by)
@@ -302,20 +274,8 @@ def build_packed_device_fn(
         init = (jnp.int32(0), params0, other0, opt0, 0.0, 0.0, 0.0,
                 zeros_vars, 0.0, 0.0, 0.0, ext0, outs0,
                 {n: jnp.zeros((), jnp.float32) for n in counter_names})
-        if scanning:
-            # static-length scan over the bucketed stream: XLA can pipeline
-            # iterations (no traced trip count); tail steps beyond n_steps
-            # carry all-zero masks so they are exact no-ops
-            def scan_body(carry, step):
-                return body((step,) + carry)[1:], None
-
-            final, _ = jax.lax.scan(
-                scan_body, init[1:], jnp.arange(idx.shape[0], dtype=jnp.int32)
-            )
-            (_, _, _, _, _, _, acc, wsum, lsum, cnt, ext, outs, ctr) = final
-        else:
-            final = jax.lax.while_loop(lambda c: c[0] < n_steps, body, init)
-            (_, _, _, _, _, _, _, acc, wsum, lsum, cnt, ext, outs, ctr) = final
+        final = jax.lax.while_loop(lambda c: c[0] < n_steps, body, init)
+        (_, _, _, _, _, _, _, acc, wsum, lsum, cnt, ext, outs, ctr) = final
         return acc, wsum, lsum, cnt, ext, outs, ctr
 
     return device_fn

@@ -8,19 +8,24 @@ into the server (``common.py:196-210``).  Here the whole round collapses into
 ONE compiled XLA program over a ``Mesh``:
 
 * broadcast  -> implicit replication of the global variables;
-* per-GPU LocalAggregator loop -> per-device ``lax.scan`` over the clients
-  assigned to that mesh slot (client axis sharded with shard_map);
-* local SGD epochs -> nested compiled scan (ml/engine/train.build_local_train);
+* per-GPU LocalAggregator loop -> one packed stream of batches per device
+  (client axis sharded with shard_map), walked by a ``lax.while_loop`` whose
+  trip count is the device's own step count (ml/engine/packed.py, which
+  alone knows how a round's client data is laid out and walked);
+* local SGD epochs -> steps of that stream; at a client's last step the carry
+  flushes into the weighted sum and resets to the round-start state;
 * ``fedml_nccl_reduce`` -> weighted on-device accumulation + ``lax.psum``
   over the 'client' axis riding ICI;
 * the Server/LocalAggregator role split disappears: no host round-trips
   inside a round, weights never leave HBM.
 
-Client heterogeneity under static shapes: all clients pad to one bucket
-(max client size rounded up); padded samples are masked from loss/updates;
-rounds whose sampled-client count doesn't fill devices evenly pad with
-weight-0 dummy clients.  Static greedy balancing of clients->devices by
-sample count (core/schedule) minimizes the padding waste.
+Client heterogeneity under static shapes: a client contributes
+ceil(n_i/B) batches an epoch, so its own padding is at most B-1 samples,
+masked from loss and updates; the stream buffers are sized for the worst
+case and trimmed to a bucket of the round's real step count; rounds whose
+sampled-client count doesn't fill devices evenly pad with weight-0 dummy
+slots that add no step.  Static greedy balancing of clients->devices by
+step count (core/schedule) evens the devices' trip counts.
 
 The algorithm zoo rides this same compiled round via in-mesh strategies
 (algorithms.py): FedAvg/FedProx/FedSGD/FedOpt/FedNova/SCAFFOLD/FedDyn/
@@ -36,8 +41,7 @@ import logging
 import os
 import time
 import warnings
-from functools import partial
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +54,8 @@ from ...core.dp.fedml_differential_privacy import FedMLDifferentialPrivacy
 from ...core.schedule import RuntimeEstimator, SeqTrainScheduler
 from ...core.security.fedml_attacker import FedMLAttacker
 from ...core.security.fedml_defender import FedMLDefender
-from ...ml.engine.train import build_local_train, init_variables
+from ...ml.engine.packed import build_packed_device_fn, pack_round, s_max_for, trim_to_bucket
+from ...ml.engine.train import init_variables
 from ...parallel.mesh import create_fl_mesh, create_round_mesh
 from ...utils.metrics import MetricsLogger
 from .algorithms import create_inmesh_algorithm
@@ -94,6 +99,26 @@ class _Phase:
         self.span.end(duration_s=dt, **self.attrs)
 
 
+class _Run(NamedTuple):
+    """What one ``train()`` call holds for all of its rounds."""
+
+    comm_round: int
+    ckpt: Any         # the checkpointer, or None
+    tele_cap: Any     # loop-back client telemetry and its merger, or None
+    tele_merger: Any
+
+
+class _Cohort(NamedTuple):
+    """What ``round.select`` decides and the later phases read."""
+
+    sampled: np.ndarray       # the cohort as drawn
+    staleness: Dict[int, int]  # fl_mode=async: staleness by client id
+    ids: np.ndarray           # [n_dev * slots] scheduled ids, dummy slots too
+    counts: np.ndarray        # their sample counts, 0 on a dummy slot
+    participated: np.ndarray  # f32: 1 where the compiled round trains the slot
+    cex: Any                  # the algorithm's per-slot inputs
+
+
 class XLASimulator:
     def __init__(self, args, dataset, model, mesh: Mesh = None):
         # start-up's seconds by phase (sim.build and its children), kept like
@@ -122,7 +147,7 @@ class XLASimulator:
         self.clients_per_round = int(args.client_num_per_round)
         self.batch_size = int(getattr(args, "batch_size", 32))
 
-        # Security layer: both rounds can return the per-client update stack
+        # Security layer: the round can return the per-client update stack
         # (sharded over the client axis); a second jitted program then runs
         # stacked model attacks + robust aggregation + the algorithm's server
         # step on it (core/security/stacked.py) — updates never touch the
@@ -131,7 +156,6 @@ class XLASimulator:
         # attacks stamp at pack time, where each client's shard is assembled.
         attacker = FedMLAttacker.get_instance()
         defender = FedMLDefender.get_instance()
-        dp = FedMLDifferentialPrivacy.get_instance()
         self.defended = defender.is_defense_enabled()
         self.model_attacked = attacker.is_model_attack()
         # analysis-primitive attacks (dlg / invert_gradient / revealing
@@ -178,21 +202,35 @@ class XLASimulator:
         self._model_bytes = int(sum(
             l.size * l.dtype.itemsize
             for l in jax.tree_util.tree_leaves(self.variables)))
-        self.packed = bool(getattr(args, "xla_pack", False))
-        # sharded_state composes with BOTH the packed streamer and the
-        # security tail now: each of those programs ends at the psum'd
-        # accumulator and the model-sharded GSPMD tail applies the server
-        # step — defended + model-sharded rounds run, they don't degrade
+        # sharded_state composes with the security tail: the round and the
+        # security program each end at the psum'd accumulator and the
+        # model-sharded GSPMD tail applies the server step
         with _Phase(self.startup_log, "sim.build_round_fn", build_ctx):
-            if self.packed:
-                self._build_packed_round_fn()
-            else:
-                self._build_round_fn()
+            self._build_packed_round_fn()
             if self.needs_stack:
                 self._build_security_fn()
             if self.sharded_state:
                 self._build_server_tail()
 
+        self._build_population()
+        from ...ml.aggregator.aggregator_creator import create_server_aggregator
+
+        self.aggregator = create_server_aggregator(model, args)
+        self.metrics = MetricsLogger(args)
+        self.round_times: List[float] = []
+        self.round_losses: List[float] = []
+        self.samples_per_round: List[int] = []
+        # one dict a round: the wall time (the round_times entry), the host
+        # phases that split it, and what the round carried — filled with obs
+        # off too, as round_times is
+        self.round_log: List[Dict[str, Any]] = []
+        self.samples_trained = 0
+        self._rng = jax.random.PRNGKey(int(getattr(args, "random_seed", 0)) + 11)
+
+    def _build_population(self):
+        """Who trains when: the scheduler and its runtime model, the
+        population's selection policy, and the async arrival queue."""
+        args = self.args
         self.runtime_estimator = RuntimeEstimator(self.n_dev, uniform_devices=True)
         self.scheduler = SeqTrainScheduler(self.n_dev, estimator=self.runtime_estimator)
         # population subsystem: fleet registry + selection policy; the
@@ -224,34 +262,22 @@ class XLASimulator:
         self.async_mode = str(
             getattr(args, "fl_mode", "sync") or "sync").lower() == "async"
         if self.async_mode:
-            self._async_init()
-        from ...ml.aggregator.aggregator_creator import create_server_aggregator
+            from .async_arrivals import VirtualArrivals
 
-        self.aggregator = create_server_aggregator(model, args)
-        self.metrics = MetricsLogger(args)
-        self.round_times: List[float] = []
-        self.round_losses: List[float] = []
-        self.samples_per_round: List[int] = []
-        # one dict a round: the wall time (the round_times entry), the host
-        # phases that split it, and what the round carried — filled with obs
-        # off too, as round_times is
-        self.round_log: List[Dict[str, Any]] = []
-        self.samples_trained = 0
-        self._rng = jax.random.PRNGKey(int(getattr(args, "random_seed", 0)) + 11)
+            self._arrivals = VirtualArrivals(
+                args, self.num_clients, self.clients_per_round, self._client_sampling(0))
 
     # ------------------------------------------------------------------
     # data packing: one global HBM-resident array + per-client index table
     # ------------------------------------------------------------------
     def _pack_data(self):
         """Concatenate client shards into one HBM-resident array pair and
-        record each client's contiguous row range in an index table — so a
-        round's client data is a pure on-device gather (no host transfers)."""
-        b = self.batch_size
+        record each client's contiguous row range in a host index table — a
+        round uploads row indices only and gathers its batches on device."""
         counts = np.array([self.local_num_dict[i] for i in range(self.num_clients)], np.int32)
         self.max_client_n = int(counts.max())
-        self.padded_n = max(b, -(-self.max_client_n // b) * b)
         xs, ys = [], []
-        idx = np.zeros((self.num_clients, self.padded_n), np.int32)
+        idx = np.zeros((self.num_clients, max(1, self.max_client_n)), np.int32)
         cursor = 0
         attacker = FedMLAttacker.get_instance()
         poisoning = attacker.is_data_poisoning_attack()
@@ -271,12 +297,9 @@ class XLASimulator:
             n = len(yi)
             xs.append(np.asarray(xi))
             ys.append(np.asarray(yi))
-            if n > 0:
-                idx[i, :n] = np.arange(cursor, cursor + n, dtype=np.int32)
-                idx[i, n:] = cursor  # padding rows (masked out by counts)
+            idx[i, :n] = np.arange(cursor, cursor + n, dtype=np.int32)
             cursor += n
-        self._client_rows = idx  # host copy (packed-round schedule builder)
-        self.client_idx = jnp.asarray(idx)
+        self._client_rows = idx  # pack_round reads a client's first n_i entries
         self.client_counts = jnp.asarray(counts)
         from ...models.hub import data_storage_dtype
 
@@ -295,33 +318,13 @@ class XLASimulator:
         self.x_all = jax.device_put(x_np, repl)
         self.y_all = jax.device_put(np.concatenate(ys, 0), repl)
         logger.info(
-            "packed %d clients (max_n=%d padded_n=%d) data %s (%s) into HBM",
-            self.num_clients, self.max_client_n, self.padded_n, self.x_all.shape,
-            self.x_all.dtype,
+            "packed %d clients (max_n=%d) data %s (%s) into HBM",
+            self.num_clients, self.max_client_n, self.x_all.shape, self.x_all.dtype,
         )
 
     # ------------------------------------------------------------------
     # the compiled round
     # ------------------------------------------------------------------
-    def _resolve_chunk(self, per_dev: int) -> int:
-        """Clients vmapped together per scan step (effective batch k*B, scan
-        runs per_dev/k steps).  Default is 1: measured on TPU v5e with the
-        bench model (ResNet-56/CIFAR, batch 64), vmapping clients did NOT
-        help — per-step time grew linearly with k (the ops are bandwidth/
-        lane-padding bound, not launch-bound), and fp32 chunk=8 was 1.6x
-        SLOWER than unchunked.  The knob stays for models where per-step cost
-        is launch-dominated (tiny dense models).  Must divide per_dev."""
-        req = int(getattr(self.args, "xla_client_chunk", 0) or 0)
-        if req <= 0:
-            return 1
-        k = max(d for d in range(1, min(req, per_dev) + 1) if per_dev % d == 0)
-        if k != req:
-            logger.warning(
-                "xla_client_chunk=%d does not divide clients/device=%d; using %d",
-                req, per_dev, k,
-            )
-        return k
-
     def _ldp_hook(self):
         """Pure per-client noise fn when local DP is enabled (the mechanism's
         add_noise is jax-traceable), else None."""
@@ -330,126 +333,6 @@ class XLASimulator:
             return None
         mechanism = dp.mechanism
         return lambda tree, key: mechanism.add_noise(tree, key)
-
-    def _build_round_fn(self):
-        mesh = self.mesh
-        algo = self.algo
-        stacked = self.needs_stack
-        sharded = self.sharded_state
-        post_train = self._ldp_hook()
-        local_train = build_local_train(
-            self.module, self.args, self.batch_size, self.padded_n,
-            grad_hook=algo.grad_hook(), loss=self.loss_kind,
-        )
-
-        def fedml_round_padded(variables, server_state, x_all, y_all, idx_l,
-                               counts_l, rngs_l, cex_l):
-            # idx_l: [C/n_dev, padded_n]; counts_l: [C/n_dev]; rngs_l: [C/n_dev, 2]
-            # cex_l: per-client algorithm inputs (leading axis C/n_dev)
-            per_dev = idx_l.shape[0]
-            k = self._resolve_chunk(per_dev)
-            zeros = jax.tree_util.tree_map(
-                lambda v: jnp.zeros_like(v, dtype=jnp.float32), variables
-            )
-
-            def one_client(idx_row, n_i, rng, cex):
-                with jax.named_scope("fed.gather"):
-                    x = jnp.take(x_all, idx_row, axis=0)
-                    y = jnp.take(y_all, idx_row, axis=0)
-                with jax.named_scope("fed.local_step"):
-                    result = local_train(
-                        variables, x, y, n_i, rng,
-                        extra=algo.engine_extra(cex, server_state),
-                    )
-                if post_train is not None:
-                    # in-mesh local DP: per-client noise before aggregation
-                    result = result._replace(variables=post_train(
-                        result.variables, jax.random.fold_in(rng, 104729)
-                    ))
-                w = n_i.astype(jnp.float32)
-                real = (n_i > 0).astype(jnp.float32)
-                with jax.named_scope("fed.flush"):
-                    wv = jax.tree_util.tree_map(
-                        lambda p: w * p.astype(jnp.float32), result.variables
-                    )
-                    contrib = algo.client_contrib(
-                        variables, result, w, real, cex, server_state)
-                    out = algo.client_out(variables, result, real, cex, server_state)
-                if stacked:
-                    # per-client update stack for the security program (the
-                    # weights are the host-known sample counts); "tau" = the
-                    # engine's step count so the security tail can recompute
-                    # ext contributions (FedNova) from the defended stack
-                    out = {"algo": out,
-                           "update": jax.tree_util.tree_map(
-                               lambda p: p.astype(jnp.float32), result.variables),
-                           "tau": result.steps}
-                return wv, w, result.loss * w, contrib, out
-
-            vclients = jax.vmap(one_client)
-
-            def train_chunk(carry, inp):
-                acc, wsum, lsum, ext = carry
-                wv, w, wl, contrib, out = vclients(*inp)  # leading axis k
-                with jax.named_scope("fed.flush"):
-                    acc = jax.tree_util.tree_map(lambda a, p: a + p.sum(0), acc, wv)
-                    ext = jax.tree_util.tree_map(
-                        lambda e, c: e + c.sum(0), ext, contrib)
-                return (acc, wsum + w.sum(), lsum + wl.sum(), ext), out
-
-            chunked = jax.tree_util.tree_map(
-                lambda t: t.reshape((per_dev // k, k) + t.shape[1:]),
-                (idx_l, counts_l, rngs_l, cex_l),
-            )
-            (acc, wsum, lsum, ext), outs = jax.lax.scan(
-                train_chunk,
-                (zeros, 0.0, 0.0, algo.zero_contrib(variables)),
-                chunked,
-            )
-            # un-chunk the stacked per-client outputs: [per_dev/k, k, ...] -> [per_dev, ...]
-            outs = jax.tree_util.tree_map(
-                lambda o: o.reshape((per_dev,) + o.shape[2:]), outs
-            )
-            # the "fedml_nccl_reduce": one psum over ICI
-            with jax.named_scope("fed.exchange"):
-                wsum = jax.lax.psum(wsum, "client")
-                lsum = jax.lax.psum(lsum, "client")
-                ext = jax.lax.psum(ext, "client")
-            mean_loss = lsum / jnp.maximum(wsum, 1e-9)
-            if stacked:
-                # aggregation + server step move to the security program,
-                # which consumes the sharded update stack (XLA drops the
-                # unused acc accumulator — no wasted model-size psum)
-                return mean_loss, outs, ext
-            with jax.named_scope("fed.exchange"):
-                acc = jax.lax.psum(acc, "client")
-            if sharded:
-                # server_state=sharded: the algorithm's server step moves to
-                # the separate model-sharded GSPMD tail program — this
-                # program ends at the reduced accumulator
-                return acc, wsum, ext, mean_loss, outs
-            # algorithm server step, replicated — still inside the XLA program
-            with jax.named_scope("fed.server_step"):
-                new_global, new_state = algo.server_update(
-                    acc, wsum, ext, variables, server_state
-                )
-            return new_global, new_state, mean_loss, outs
-
-        if stacked:
-            out_specs = (P(), P("client"), P())
-        elif sharded:
-            out_specs = (P(), P(), P(), P(), P("client"))
-        else:
-            out_specs = (P(), P(), P(), P("client"))
-        self._round_fn = jax.jit(
-            shard_map(
-                fedml_round_padded,
-                mesh=mesh,
-                in_specs=(P(), P(), P(), P(), P("client"), P("client"), P("client"), P("client")),
-                out_specs=out_specs,
-                check_vma=False,
-            )
-        )
 
     def _build_server_tail(self):
         """server_state=sharded: the algorithm's server step as its own
@@ -507,12 +390,14 @@ class XLASimulator:
             out_shardings=(var_sh, state_sh))
 
     def _build_packed_round_fn(self):
-        """Packed ragged round (ml/engine/packed.py): no per-client padding
-        to the global max — each client contributes exactly ceil(n_i/B)*E
-        batches, streamed through one while_loop per device.  Enabled by
-        ``args.xla_pack``."""
-        from ...ml.engine.packed import build_packed_device_fn, s_max_for
-
+        """The round program (one per simulator): ml/engine/packed.py's
+        per-device stream under shard_map, the psum over the client axis and,
+        unless another program takes the round from there (the security
+        program, the model-sharded tail), the algorithm's server step.
+        benchmark/tests build it on a bare object: it reads ``args``,
+        ``module``, ``mesh``, ``n_dev``, ``clients_per_round``, ``batch_size``,
+        ``max_client_n``, ``needs_stack``, ``sharded_state``, ``loss_kind`` and
+        ``algo`` and sets ``slots``, ``s_max`` and ``_round_fn``."""
         mesh = self.mesh
         algo = self.algo
         self.slots = -(-self.clients_per_round // self.n_dev)
@@ -520,13 +405,12 @@ class XLASimulator:
             self.max_client_n, self.slots, self.batch_size,
             int(getattr(self.args, "epochs", 1)),
         )
+        self._seen_buckets = set()  # stream buckets a round has compiled
         stacked = self.needs_stack
         sharded = self.sharded_state
         device_fn = build_packed_device_fn(
             self.module, self.args, algo, self.batch_size, self.slots,
             loss=self.loss_kind,
-            pregather=bool(getattr(self.args, "xla_pregather", False)),
-            stream=str(getattr(self.args, "xla_stream", "while")),
             post_train=self._ldp_hook(),
             capture_updates=stacked,
         )
@@ -554,7 +438,7 @@ class XLASimulator:
                 wsum = jax.lax.psum(wsum, "client")
             if sharded:
                 # program ends at the reduced accumulator; the model-sharded
-                # tail applies the server step (same split as _build_round_fn)
+                # tail applies the server step
                 return (acc, wsum, ext, mean_loss, outs) + counted
             with jax.named_scope("fed.server_step"):
                 new_global, new_state = algo.server_update(
@@ -709,45 +593,25 @@ class XLASimulator:
         return self._defense_state
 
     def _packed_inputs(self, ids: np.ndarray, counts: np.ndarray, round_idx: int):
-        from ...ml.engine.packed import pack_round
-
-        ids2d = ids.reshape(self.n_dev, self.slots)
-        counts2d = counts.reshape(self.n_dev, self.slots)
-        sched = pack_round(
-            ids2d, counts2d,
+        """The round's stream, trimmed to its bucket and uploaded: the six
+        arrays between ``y_all`` and the device keys in the round program's
+        arguments."""
+        sched = trim_to_bucket(pack_round(
+            ids.reshape(self.n_dev, self.slots), counts.reshape(self.n_dev, self.slots),
             lambda cid: self._client_rows[cid],
             self.batch_size, int(getattr(self.args, "epochs", 1)),
-            int(getattr(self.args, "random_seed", 0)), round_idx, self.s_max,
-        )
-        # trim the stream buffers to a quantized bucket of the round's real
-        # max steps: uploads, the scan-stream tail, and (with xla_pregather)
-        # the round's data gather all scale with the bucket, not the global
-        # worst case.  Quantum = s_max/8 -> at most 8 distinct shapes per
-        # run (each compiles once, then caches — flip-flopping between
-        # already-compiled levels costs nothing) and <= one quantum of
-        # overshoot, vs up to 2x for the old monotone power-of-two ladder.
-        s_used = max(int(sched.n_steps.max()), 1)
-        quantum = max(1, -(-self.s_max // 8))
-        s_bucket = min(-(-s_used // quantum) * quantum, self.s_max)
-        seen = getattr(self, "_seen_buckets", None)
-        if seen is None:
-            seen = self._seen_buckets = set()
+            int(getattr(self.args, "random_seed", 0)), round_idx, self.s_max), self.s_max)
+        self._s_bucket = sched.idx.shape[1]
+        self._steps_max = max(int(sched.n_steps.max()), 1)
         # first round at a new bucket shape pays an XLA recompile: flag it so
-        # train() keeps that wall time out of the runtime model's fit
-        self._bucket_compiling = s_bucket not in seen
-        seen.add(s_bucket)
-        self._s_bucket = s_bucket
-        self._steps_max = s_used
-        sched = sched._replace(
-            idx=sched.idx[:, :s_bucket], mask=sched.mask[:, :s_bucket],
-            boundary=sched.boundary[:, :s_bucket], weight=sched.weight[:, :s_bucket],
-            slot=sched.slot[:, :s_bucket],
-        )
+        # the round keeps that wall time out of the runtime model's fit
+        self._bucket_compiling = self._s_bucket not in self._seen_buckets
+        self._seen_buckets.add(self._s_bucket)
         self._h2d_bytes = sum(int(a.nbytes) for a in sched)
         return tuple(jnp.asarray(a) for a in sched)
 
     def _client_steps(self, n: int) -> int:
-        """A client's cost in the packed round's native unit: compiled steps
+        """A client's cost in the round's native unit: compiled steps
         (ceil(n/B) per epoch) — the quantity the while_loop actually runs."""
         if n <= 0:
             return 0
@@ -759,15 +623,11 @@ class XLASimulator:
         observed).  Returns (client_ids [C_pad], is_real [C_pad]) laid out so
         that reshape(n_dev, -1) gives each device its contiguous schedule.
 
-        Cost units match what each round variant executes: the packed stream
-        runs ceil(n/B)*E steps per client (a 1-sample client costs a whole
-        batch step), the padded round always runs padded_n/B steps, so LPT
-        balances packed rounds on STEP counts and the runtime model is fed
-        the same unit (see the record() call in train())."""
-        if self.packed:
-            sizes = [self._client_steps(self.local_num_dict[int(c)]) for c in sampled]
-        else:
-            sizes = [self.local_num_dict[int(c)] for c in sampled]
+        Cost units match what the round executes: the stream runs
+        ceil(n/B)*E steps per client (a 1-sample client costs a whole batch
+        step), so LPT balances on STEP counts and the runtime model is fed
+        the same unit (see the record() call in _close)."""
+        sizes = [self._client_steps(self.local_num_dict[int(c)]) for c in sampled]
         ids2d, mask2d, _ = self.scheduler.schedule(sampled, sizes)
         return ids2d.reshape(-1), mask2d.reshape(-1)
 
@@ -777,85 +637,6 @@ class XLASimulator:
         return np.asarray(
             self.population.select(round_idx, self.clients_per_round), np.int64
         )
-
-    # ------------------------------------------------------------------
-    # buffered-async virtual-arrival driver (fl_mode=async)
-    # ------------------------------------------------------------------
-    def _async_init(self):
-        """Deterministic virtual-time schedule: per-client durations drawn
-        once from ``random_seed`` (the sp FedBuffAPI idiom), a fixed cohort
-        (the round-0 population draw — async cycles re-dispatch the same
-        pool, matching the message-plane servers), and a flush size of
-        ``async_buffer_size`` arrivals.  Each XLA round is one flush."""
-        from ...core.async_fl import VirtualArrivalQueue
-        from ...core.checkpoint import maybe_checkpointer
-
-        if maybe_checkpointer(self.args) is not None:
-            raise NotImplementedError(
-                "fl_mode=async does not checkpoint mid-run in the XLA "
-                "simulator (the virtual arrival queue is not persisted)")
-        cap = int(getattr(self.args, "async_buffer_size", 0) or 0) \
-            or self.clients_per_round
-        if cap > self.clients_per_round:
-            logger.warning("async_buffer_size=%d exceeds the cohort (%d): "
-                           "clamping", cap, self.clients_per_round)
-            cap = self.clients_per_round
-        self._async_cap = cap
-        self._async_max_staleness = int(
-            getattr(self.args, "async_max_staleness", 0) or 0)
-        rng = np.random.RandomState(int(getattr(self.args, "random_seed", 0)))
-        self._async_durations = 0.5 + rng.exponential(
-            1.0, size=self.num_clients)
-        self._async_cohort = [int(c) for c in self._client_sampling(0)]
-        self._async_version = 0
-        self._async_dispatched = {c: 0 for c in self._async_cohort}
-        self._async_queue = VirtualArrivalQueue()
-        for c in self._async_cohort:
-            self._async_queue.push(c, float(self._async_durations[c]))
-        self._async_t = 0.0
-        self._async_dropped_stale = 0
-
-    def _async_next_flush(self) -> Tuple[np.ndarray, Dict[int, int]]:
-        """Pop arrivals off the virtual queue until one buffer's worth
-        accrues; returns (cohort sorted by id, staleness by id).  Sorting
-        keeps the mesh layout id-deterministic — and makes the
-        full-participation constant-weight config schedule-identical to the
-        sync loop (the arrival ORDER carries no weight information; the
-        staleness map does)."""
-        picked: List[int] = []
-        stal: Dict[int, int] = {}
-        v = self._async_version
-        while len(picked) < self._async_cap:
-            t, cid = self._async_queue.pop()
-            self._async_t = t
-            s = v - self._async_dispatched[cid]
-            if s > self._async_max_staleness:
-                # too stale to aggregate: fresh work beats idling
-                self._async_dropped_stale += 1
-                obs.counter_inc("async.dropped_stale")
-                self._async_dispatched[cid] = v
-                self._async_queue.push(cid, t + float(self._async_durations[cid]))
-                continue
-            picked.append(cid)
-            stal[cid] = int(s)
-            obs.histogram_observe("async.staleness", float(s))
-            if self._async_max_staleness >= 1 and len(picked) < self._async_cap:
-                # FedBuff: the client keeps training while its delta waits
-                self._async_dispatched[cid] = v
-                self._async_queue.push(cid, t + float(self._async_durations[cid]))
-        return np.asarray(sorted(picked), np.int64), stal
-
-    def _async_round_end(self):
-        """The flush applied: bump the version and re-dispatch every idle
-        cohort member on the fresh global at the flush's virtual time."""
-        self._async_version += 1
-        obs.counter_inc("async.flushes", labels={"reason": "full"})
-        in_flight = set(self._async_queue.clients())
-        for c in self._async_cohort:
-            if c not in in_flight:
-                self._async_dispatched[c] = self._async_version
-                self._async_queue.push(
-                    c, self._async_t + float(self._async_durations[c]))
 
     def train(self) -> Dict[str, Any]:
         # train()'s own preamble and tail on the same line as the rounds: the
@@ -911,8 +692,8 @@ class XLASimulator:
         # no per-client wall times, so the remote "client.train" leg covers
         # the whole cohort's execute time) — keeps the trace_report shape
         # identical between simulation and distributed runs
-        tele_cap = obs.make_client_telemetry(0)
-        tele_merger = obs.make_telemetry_merger()
+        run = _Run(comm_round, ckpt, obs.make_client_telemetry(0),
+                   obs.make_telemetry_merger())
         try:
             for round_idx in range(start_round, comm_round):
                 if prof_dir is not None and round_idx == prof_first:
@@ -920,8 +701,7 @@ class XLASimulator:
                     profiling = True
                     logger.info("jax profiler trace of rounds %d-%d -> %s",
                                 prof_first, prof_last, prof_dir)
-                evaluated = self._run_round(round_idx, comm_round, ckpt,
-                                            tele_cap, tele_merger)
+                evaluated = self._run_round(round_idx, run)
                 if evaluated is not None:
                     last = evaluated
                 if profiling and round_idx == prof_last:
@@ -937,17 +717,11 @@ class XLASimulator:
                 comm_round - 1)
         return last
 
-    def _run_round(self, round_idx: int, comm_round: int, ckpt, tele_cap,
-                   tele_merger):
+    def _run_round(self, round_idx: int, run: _Run):
         """One round, split into the host phases ``round.select`` / ``pack`` /
         ``dispatch`` / ``wait`` / ``close`` (children of the ``round`` root,
         each timed once by :class:`_Phase` into the span and into
         ``round_log``).  Returns the eval record where the round evaluated."""
-        from ...core import mlops
-        from ...core.checkpoint import checkpoint_frequency
-
-        freq = int(getattr(self.args, "frequency_of_the_test", 10))
-        epochs = int(getattr(self.args, "epochs", 1))
         rec: Dict[str, Any] = {"round": round_idx}
         t0 = time.perf_counter()
         compile_s0 = obs.compile_seconds_total()
@@ -955,300 +729,30 @@ class XLASimulator:
             round_idx, annotate=True,
             mode="simulation_xla_async" if self.async_mode else "simulation_xla")
         with _Phase(rec, "round.select", rsp.ctx, round_idx) as ph:
-            if self.async_mode:
-                sampled, stal_map = self._async_next_flush()
-                self.algo.set_staleness(stal_map)
-            else:
-                sampled = self._client_sampling(round_idx)
-            ids, real = self._schedule(sampled)
-            counts = np.where(real > 0, np.asarray(self.client_counts)[ids], 0)
-            # participation mask as the compiled round sees it: a sampled
-            # client with zero local samples contributes nothing in-mesh
-            participated = (counts > 0).astype(np.float32)
-            cex = self.algo.gather_client_extras(
-                self.client_state, ids, participated, round_idx
-            )
-            prev_global = self.variables  # defense reference (pre-round global)
-            dp = FedMLDifferentialPrivacy.get_instance()
-            if dp.is_local_dp_enabled():
-                # account BEFORE the round releases anything (matching the sp
-                # path, where add_noise spends before producing the noised
-                # update): budget exhaustion must abort the round, not trail it
-                dp.spend_budget(int(participated.sum()))
-            ph.attrs["n_sampled"] = len(sampled)
+            cohort = self._select(round_idx)
+            ph.attrs["n_sampled"] = len(cohort.sampled)
         with _Phase(rec, "round.pack", rsp.ctx, round_idx) as ph:
-            self._rng, sub = jax.random.split(self._rng)
-            if self.packed:
-                packed = self._packed_inputs(np.asarray(ids), counts, round_idx)
-                dev_rngs = jax.random.split(
-                    jax.random.fold_in(sub, round_idx), self.n_dev
-                )
-                round_inputs = (self.variables, self.server_state, self.x_all,
-                                self.y_all, *packed, dev_rngs, cex)
-                ph.attrs.update(s_bucket=self._s_bucket, steps_max=self._steps_max,
-                                h2d_bytes=self._h2d_bytes)
-                if self._bucket_compiling:
-                    obs.span_event("bucket_compile", ph.ctx, round_idx=round_idx,
-                                   s_bucket=self._s_bucket)
-            else:
-                rngs = jax.random.split(jax.random.fold_in(sub, round_idx), len(ids))
-                ids_up, counts_up = jnp.asarray(ids), jnp.asarray(counts)
-                idx_rows = self.client_idx[ids_up]
-                round_inputs = (self.variables, self.server_state, self.x_all,
-                                self.y_all, idx_rows, counts_up, rngs, cex)
-                # shape-static: every client pays padded_n, on every device
-                ph.attrs.update(
-                    s_bucket=0, h2d_bytes=int(ids_up.nbytes + counts_up.nbytes),
-                    steps_max=(len(ids) // self.n_dev)
-                    * (self.padded_n // self.batch_size) * epochs)
+            sub, round_inputs = self._pack(round_idx, cohort, ph)
             rec.update(ph.attrs)
         with _Phase(rec, "round.dispatch", rsp.ctx, round_idx) as ph:
-            if self.needs_stack:
-                # security path: the round returns the sharded per-client
-                # update stack; the second jitted program runs stacked model
-                # attacks + robust aggregation + the server step on device
-                mean_loss, outs, ext, *counters = self._round_fn(*round_inputs)
-                stack = outs["update"]
-                taus = outs["tau"]
-                outs = outs["algo"]
-                real_sel = np.where(counts > 0)[0]
-                if real_sel.size > 0:
-                    attacker = FedMLAttacker.get_instance()
-                    mal = np.zeros(real_sel.size, np.float32)
-                    if self.model_attacked:
-                        bad = set(attacker.get_byzantine_idxs(self.num_clients))
-                        mal = np.array(
-                            [1.0 if int(ids[i]) in bad else 0.0 for i in real_sel],
-                            np.float32,
-                        )
-                    dstate = self._ensure_defense_state(int(real_sel.size))
-                    # derive the security key from the round's sub-key, NOT by
-                    # splitting the main stream: the round-r data/rng layout
-                    # must be identical with and without the security tail
-                    # (one split per round is the replayable invariant)
-                    skey = jax.random.fold_in(sub, 999331)
-                    meta = self.algo.security_meta(taus, cex, jnp.asarray(real_sel))
-                    sec_inputs = (
-                        stack,
-                        jnp.asarray(counts[real_sel], jnp.float32),
-                        jnp.asarray(real_sel),
-                        jnp.asarray(mal),
-                        meta,
-                        self.variables,
-                        self.server_state,
-                        ext,
-                        skey,
-                        dstate,
-                    )
-                    with obs.span("aggregate.reduce", ph.ctx,
-                                  round_idx=round_idx,
-                                  n_clients=int(real_sel.size),
-                                  mode="inmesh"):
-                        if self.sharded_state:
-                            # defended + model-sharded: the security program
-                            # stops at the robust accumulator; the GSPMD
-                            # server tail applies the step on donated
-                            # resident buffers (the same two-program split
-                            # the undefended sharded round uses)
-                            acc_d, wsum_d, ext_d, self._defense_state = (
-                                self._security_fn(*sec_inputs))
-                            var_sh, state_sh, repl = self._tail_shardings
-                            t_tail = time.perf_counter()
-                            with warnings.catch_warnings():
-                                warnings.filterwarnings(
-                                    "ignore",
-                                    message="Some donated buffers were not usable")
-                                self.variables, self.server_state = self._server_tail(
-                                    jax.device_put(self.variables, var_sh),
-                                    jax.device_put(self.server_state, state_sh),
-                                    jax.device_put(acc_d, var_sh),
-                                    jax.device_put(wsum_d, repl),
-                                    jax.device_put(ext_d, repl),
-                                )
-                            jax.block_until_ready(self.variables)
-                            obs.histogram_observe(
-                                "server_opt.step_seconds", time.perf_counter() - t_tail,
-                                labels={"policy": type(self.algo).__name__,
-                                        "mode": "inmesh"})
-                            if self._tail_subset:
-                                full = NamedSharding(self.mesh, P())
-                                self.variables = jax.device_put(
-                                    self.variables, full)
-                                self.server_state = jax.device_put(
-                                    self.server_state, full)
-                        else:
-                            self.variables, self.server_state, self._defense_state = (
-                                self._security_fn(*sec_inputs))
-                            jax.block_until_ready(self.variables)
-                    if self.analysis_attacked and round_idx % max(
-                        1, int(getattr(self.args, "dlg_frequency", 1))
-                    ) == 0:
-                        # privacy/analysis attack (dlg, invert_gradient,
-                        # revealing_labels): run on ONE intercepted update (a
-                        # single model-size host pull; dlg_frequency gates the
-                        # per-round gradient-matching cost)
-                        bad = set(attacker.get_byzantine_idxs(self.num_clients))
-                        victims = [int(i) for i in real_sel
-                                   if int(ids[i]) in bad] or [int(real_sel[0])]
-                        row = jax.tree_util.tree_map(
-                            lambda t: t[victims[0]], stack
-                        )
-                        attacker.analyze_update(
-                            self.module, prev_global, row,
-                            (int(getattr(self.args, "dlg_batch_size", 1)),)
-                            + tuple(self.x_all.shape[1:]),
-                            self.class_num,
-                        )
-            elif self.sharded_state:
-                # two programs: the client-axis training round ends at the
-                # psum'd accumulator; the model-sharded GSPMD tail applies
-                # the algorithm's server step on donated resident buffers
-                acc, wsum, ext, mean_loss, outs, *counters = self._round_fn(*round_inputs)
-                var_sh, state_sh, repl = self._tail_shardings
-                t_tail = time.perf_counter()
-                with obs.span("round.server_update", ph.ctx,
-                              round_idx=round_idx,
-                              n_clients=int(participated.sum()),
-                              mode="inmesh", policy=type(self.algo).__name__):
-                    with warnings.catch_warnings():
-                        # donation is a no-op on CPU backends; expected there
-                        warnings.filterwarnings(
-                            "ignore",
-                            message="Some donated buffers were not usable")
-                        self.variables, self.server_state = self._server_tail(
-                            jax.device_put(self.variables, var_sh),
-                            jax.device_put(self.server_state, state_sh),
-                            jax.device_put(acc, var_sh),
-                            jax.device_put(wsum, repl),
-                            jax.device_put(ext, repl),
-                        )
-                    jax.block_until_ready(self.variables)
-                obs.histogram_observe(
-                    "server_opt.step_seconds", time.perf_counter() - t_tail,
-                    labels={"policy": type(self.algo).__name__,
-                            "mode": "inmesh"})
-                if self._tail_subset:
-                    full = NamedSharding(self.mesh, P())
-                    self.variables = jax.device_put(self.variables, full)
-                    self.server_state = jax.device_put(self.server_state, full)
-            else:
-                (self.variables, self.server_state, mean_loss, outs,
-                 *counters) = self._round_fn(*round_inputs)
+            mean_loss, outs, counters = self._dispatch(round_idx, cohort, sub, round_inputs, ph.ctx)
         with _Phase(rec, "round.wait", rsp.ctx, round_idx) as ph:
-            self.client_state = self.algo.apply_client_outs(self.client_state, ids, outs)
-            self.algo.host_round_end(ids, participated, round_idx)
-            if self.async_mode:
-                # the flush's record span (the aggregation itself ran inside
-                # the compiled round): staleness distribution + buffer shape
-                # for trace_report's async columns
-                svals = list(stal_map.values()) or [0]
-                with obs.span("buffer.flush", ph.ctx, round_idx=round_idx,
-                              n_deltas=len(sampled), reason="full",
-                              capacity=self._async_cap,
-                              staleness_min=int(min(svals)),
-                              staleness_mean=round(
-                                  float(np.mean(svals)), 4),
-                              staleness_max=int(max(svals))):
-                    pass
-                self._async_round_end()
-            # host-side hooks (attack/defense need per-client updates and run
-            # in the host path; central DP applies here)
-            if dp.is_global_dp_enabled():
-                self.variables = dp.add_global_noise(self.variables)
-            jax.block_until_ready(self.variables)
+            self._wait(round_idx, cohort, outs, ph.ctx)
         # the round's wall time: its start to the new global model being ready
         dt = time.perf_counter() - t0
-        evaluated = None
         with _Phase(rec, "round.close", rsp.ctx, round_idx) as ph:
-            if obs.enabled() and len(self.round_times) >= 3:
-                med = float(np.median(self.round_times))
-                if dt > obs.slow_round_factor() * med:
-                    obs.span_event("slow_round", rsp.ctx, round_idx=round_idx,
-                                   dt_s=round(dt, 4), median_s=round(med, 4))
-            obs.histogram_observe("round.seconds", float(dt))
-            obs.counter_inc("agg.bytes_reduced",
-                            int(participated.sum()) * self._model_bytes,
-                            labels={"path": "inmesh"})
-            # compile-vs-execute attribution: the jax.monitoring listener
-            # accumulated every backend compile this round triggered (round
-            # fn, security fn); the rest of the wall time is execute + host
-            # orchestration
+            # compile-vs-execute attribution: the jax.monitoring listener accumulated every
+            # backend compile this round triggered (round fn, security fn); the rest of the
+            # wall time is execute + host orchestration
             compile_s = max(0.0, obs.compile_seconds_total() - compile_s0)
             loss = float(mean_loss)
-            # what the module counted in the compiled round (the packed round
-            # returns it last; the padded round has none): into round_log and
-            # the registry under the module's own names
-            for name, value in (counters[0] if counters else {}).items():
+            # what the module counted in the compiled round: into round_log and the registry
+            # under the module's own names
+            for name, value in counters.items():
                 rec[name] = float(value)
                 obs.counter_inc(name, rec[name])
-            if tele_cap is not None and tele_merger is not None:
-                tctx = tele_cap.record_span(
-                    "client.train", max(0.0, dt - compile_s), parent=rsp.ctx,
-                    round_idx=round_idx, cohort=int(participated.sum()))
-                if compile_s > 0.0:
-                    tele_cap.record_span(
-                        "client.train.compile", compile_s, parent=tctx,
-                        round_idx=round_idx)
-                tele_cap.sample_resources()
-                tele_blob = tele_cap.drain()
-                if tele_blob:
-                    tele_merger.merge(tele_blob)
-            obs.maybe_export_metrics()
-            self.round_times.append(dt)
-            self.round_losses.append(loss)
-            if round_idx > 0:  # round 0 is dominated by XLA compile
-                # The round's wall time is set by the heaviest mesh slot.
-                # Packed: record max device STEPS — the while_loop's actual
-                # trip count, so round time is genuinely load-dependent and
-                # the fitted slope drives next rounds' LPT balancing (in the
-                # same step units _schedule passes as costs).  Padded: the
-                # round is shape-static (every client pays padded_n), so the
-                # model degenerates to count-balancing there by design.
-                if self.packed:
-                    if self._bucket_compiling:
-                        pass  # compile-dominated round: would poison the fit
-                    else:
-                        steps2d = -(-counts.reshape(self.n_dev, -1)
-                                    // self.batch_size) * epochs
-                        self.runtime_estimator.record(
-                            0, int(steps2d.sum(axis=1).max()), dt
-                        )
-                else:
-                    dev_loads = counts.reshape(self.n_dev, -1).sum(axis=1)
-                    self.runtime_estimator.record(0, int(dev_loads.max()), dt)
-            samples = int(counts.sum()) * epochs
-            self.samples_per_round.append(samples)
-            self.samples_trained += samples
-            self.metrics.log(
-                {"round": round_idx, "round_time_s": round(dt, 4), "train_loss": loss}
-            )
-            mlops.log_round_info(comm_round, round_idx)
-            # population accounting for the synchronous round: everyone
-            # sampled was invited and reported; emits cohort_stats
-            self.population.observe_round(round_idx, sampled, seconds=dt)
-            if ckpt is not None and (
-                round_idx % checkpoint_frequency(self.args) == 0 or round_idx == comm_round - 1
-            ):
-                from flax import serialization
-
-                with obs.span("round.checkpoint", ph.ctx, round_idx=round_idx):
-                    state = {"variables": self.variables, "rng": self._rng,
-                             "server_state": serialization.to_state_dict(self.server_state)}
-                    if self.client_state is not None:
-                        state["client_state"] = serialization.to_state_dict(self.client_state)
-                    host = self.algo.host_state()
-                    if host:
-                        state["algo_host_state"] = host
-                    if self.defended and self._defense_state:
-                        state["defense_state"] = {
-                            k: np.asarray(v) for k, v in self._defense_state.items()
-                        }
-                        state["defense_n"] = self._defense_n
-                    ckpt.save(round_idx, state)
-            if freq > 0 and (round_idx % freq == 0 or round_idx == comm_round - 1):
-                # freq <= 0 disables eval (throughput benches)
-                with obs.span("round.eval", ph.ctx, round_idx=round_idx):
-                    evaluated = self._test_global(round_idx)
+            samples, evaluated = self._close(
+                round_idx, run, cohort, dt, compile_s, loss, rsp.ctx, ph.ctx)
         rec.update(wall_s=dt, compile_s=compile_s, samples=samples, loss=loss)
         self.round_log.append(rec)
         # the root ends after round.close, so the tree nests; compile_s and
@@ -1256,6 +760,232 @@ class XLASimulator:
         rsp.end(reason="closed", loss=loss, compile_s=round(compile_s, 6),
                 execute_s=round(max(0.0, dt - compile_s), 6))
         return evaluated
+
+    def _select(self, round_idx: int) -> _Cohort:
+        """``round.select``: draw the cohort, lay it over the mesh slots and
+        gather what the algorithm keeps per client."""
+        stal_map: Dict[int, int] = {}
+        if self.async_mode:
+            sampled, stal_map = self._arrivals.next_flush()
+            self.algo.set_staleness(stal_map)
+        else:
+            sampled = self._client_sampling(round_idx)
+        ids, real = self._schedule(sampled)
+        counts = np.where(real > 0, np.asarray(self.client_counts)[ids], 0)
+        # participation mask as the compiled round sees it: a sampled
+        # client with zero local samples contributes nothing in-mesh
+        participated = (counts > 0).astype(np.float32)
+        cex = self.algo.gather_client_extras(self.client_state, ids, participated, round_idx)
+        dp = FedMLDifferentialPrivacy.get_instance()
+        if dp.is_local_dp_enabled():
+            # account BEFORE the round releases anything (matching the sp path, where
+            # add_noise spends before producing the noised update): budget exhaustion must
+            # abort the round, not trail it
+            dp.spend_budget(int(participated.sum()))
+        return _Cohort(sampled, stal_map, ids, counts, participated, cex)
+
+    def _pack(self, round_idx: int, cohort: _Cohort, ph: _Phase):
+        """``round.pack``: the round's one rng split and its packed stream.
+        Returns (the round's sub-key, the round program's twelve arguments)."""
+        self._rng, sub = jax.random.split(self._rng)
+        packed = self._packed_inputs(np.asarray(cohort.ids), cohort.counts, round_idx)
+        dev_rngs = jax.random.split(jax.random.fold_in(sub, round_idx), self.n_dev)
+        round_inputs = (self.variables, self.server_state, self.x_all, self.y_all,
+                        *packed, dev_rngs, cohort.cex)
+        ph.attrs.update(s_bucket=self._s_bucket, steps_max=self._steps_max,
+                        h2d_bytes=self._h2d_bytes)
+        if self._bucket_compiling:
+            obs.span_event("bucket_compile", ph.ctx, round_idx=round_idx, s_bucket=self._s_bucket)
+        return sub, round_inputs
+
+    def _dispatch(self, round_idx: int, cohort: _Cohort, sub, round_inputs, ctx):
+        """``round.dispatch``: call the round program and whichever program
+        takes the round from where it ends — none (the server step is inside
+        it), the model-sharded server tail, or the security program.  Returns
+        (mean loss, per-slot algorithm outs, the module's round counters)."""
+        if self.needs_stack:
+            # the round returns the sharded per-client update stack
+            mean_loss, outs, ext, *counters = self._round_fn(*round_inputs)
+            self._secure_aggregate(round_idx, cohort, sub, outs, ext, ctx)
+            outs = outs["algo"]
+        elif self.sharded_state:
+            # the client-axis training round ends at the psum'd accumulator
+            acc, wsum, ext, mean_loss, outs, *counters = self._round_fn(*round_inputs)
+            with obs.span("round.server_update", ctx, round_idx=round_idx,
+                          n_clients=int(cohort.participated.sum()),
+                          mode="inmesh", policy=type(self.algo).__name__):
+                self._apply_server_tail(acc, wsum, ext)
+        else:
+            self.variables, self.server_state, mean_loss, outs, *counters = self._round_fn(
+                *round_inputs)
+        return mean_loss, outs, (counters[0] if counters else {})
+
+    def _apply_server_tail(self, acc, wsum, ext):
+        """server_state=sharded: the algorithm's server step, by the GSPMD tail
+        program on donated resident buffers, from the accumulator at which
+        the round program or the security program stopped."""
+        var_sh, state_sh, repl = self._tail_shardings
+        t_tail = time.perf_counter()
+        with warnings.catch_warnings():
+            # donation is a no-op on CPU backends; expected there
+            warnings.filterwarnings("ignore", message="Some donated buffers were not usable")
+            self.variables, self.server_state = self._server_tail(
+                jax.device_put(self.variables, var_sh),
+                jax.device_put(self.server_state, state_sh),
+                jax.device_put(acc, var_sh),
+                jax.device_put(wsum, repl),
+                jax.device_put(ext, repl),
+            )
+        jax.block_until_ready(self.variables)
+        obs.histogram_observe(
+            "server_opt.step_seconds", time.perf_counter() - t_tail,
+            labels={"policy": type(self.algo).__name__, "mode": "inmesh"})
+        if self._tail_subset:
+            full = NamedSharding(self.mesh, P())
+            self.variables = jax.device_put(self.variables, full)
+            self.server_state = jax.device_put(self.server_state, full)
+
+    def _secure_aggregate(self, round_idx: int, cohort: _Cohort, sub, outs, ext, ctx):
+        """The security path: the second jitted program runs stacked model
+        attacks + robust aggregation + the server step on the round's sharded
+        per-client update stack, on device."""
+        ids, counts = cohort.ids, cohort.counts
+        stack = outs["update"]
+        real_sel = np.where(counts > 0)[0]
+        if real_sel.size == 0:
+            return
+        prev_global = self.variables  # the analysis attacks' reference
+        attacker = FedMLAttacker.get_instance()
+        # the malicious clients: whom a model attack corrupts, whom an analysis attack reads
+        attacked = self.model_attacked or self.analysis_attacked
+        bad = set(attacker.get_byzantine_idxs(self.num_clients)) if attacked else set()
+        mal = np.array([float(self.model_attacked and int(ids[i]) in bad) for i in real_sel],
+                       np.float32)
+        dstate = self._ensure_defense_state(int(real_sel.size))
+        # derive the security key from the round's sub-key, NOT by splitting the main
+        # stream: the round-r data/rng layout must be identical with and without the
+        # security tail (one split per round is the replayable invariant)
+        skey = jax.random.fold_in(sub, 999331)
+        meta = self.algo.security_meta(outs["tau"], cohort.cex, jnp.asarray(real_sel))
+        sec_inputs = (stack, jnp.asarray(counts[real_sel], jnp.float32), jnp.asarray(real_sel),
+                      jnp.asarray(mal), meta, self.variables, self.server_state, ext, skey, dstate)
+        with obs.span("aggregate.reduce", ctx, round_idx=round_idx,
+                      n_clients=int(real_sel.size), mode="inmesh"):
+            if self.sharded_state:
+                # defended + model-sharded: the security program stops at the robust
+                # accumulator (the same two-program split the undefended sharded round uses)
+                acc, wsum, ext, self._defense_state = self._security_fn(*sec_inputs)
+                self._apply_server_tail(acc, wsum, ext)
+            else:
+                self.variables, self.server_state, self._defense_state = (
+                    self._security_fn(*sec_inputs))
+                jax.block_until_ready(self.variables)
+        dlg_every = max(1, int(getattr(self.args, "dlg_frequency", 1)))
+        if self.analysis_attacked and round_idx % dlg_every == 0:
+            # privacy/analysis attack (dlg, invert_gradient, revealing_labels): run on ONE
+            # intercepted update (a single model-size host pull; dlg_frequency gates the
+            # per-round gradient-matching cost)
+            victims = [int(i) for i in real_sel if int(ids[i]) in bad] or [int(real_sel[0])]
+            row = jax.tree_util.tree_map(lambda t: t[victims[0]], stack)
+            attacker.analyze_update(
+                self.module, prev_global, row,
+                (int(getattr(self.args, "dlg_batch_size", 1)),) + tuple(self.x_all.shape[1:]),
+                self.class_num)
+
+    def _wait(self, round_idx: int, cohort: _Cohort, outs, ctx):
+        """``round.wait``: fold the per-slot outs into the client state and
+        wait for the new global model."""
+        ids = cohort.ids
+        self.client_state = self.algo.apply_client_outs(self.client_state, ids, outs)
+        self.algo.host_round_end(ids, cohort.participated, round_idx)
+        if self.async_mode:
+            # the flush's record span (the aggregation itself ran inside the compiled round):
+            # staleness distribution + buffer shape for trace_report's async columns
+            svals = list(cohort.staleness.values()) or [0]
+            with obs.span("buffer.flush", ctx, round_idx=round_idx, n_deltas=len(cohort.sampled),
+                          reason="full", capacity=self._arrivals.cap,
+                          staleness_min=int(min(svals)),
+                          staleness_mean=round(float(np.mean(svals)), 4),
+                          staleness_max=int(max(svals))):
+                pass
+            self._arrivals.flushed()
+        # central DP applies here, on the host
+        dp = FedMLDifferentialPrivacy.get_instance()
+        if dp.is_global_dp_enabled():
+            self.variables = dp.add_global_noise(self.variables)
+        jax.block_until_ready(self.variables)
+
+    def _close(self, round_idx: int, run: _Run, cohort: _Cohort, dt: float,
+               compile_s: float, loss: float, round_ctx, ctx):
+        """``round.close``: the round's bookkeeping once the new global model
+        is ready.  Returns (samples trained, the eval record or None)."""
+        from ...core import mlops
+        from ...core.checkpoint import checkpoint_frequency
+
+        counts, participated = cohort.counts, cohort.participated
+        if obs.enabled() and len(self.round_times) >= 3:
+            med = float(np.median(self.round_times))
+            if dt > obs.slow_round_factor() * med:
+                obs.span_event("slow_round", round_ctx, round_idx=round_idx,
+                               dt_s=round(dt, 4), median_s=round(med, 4))
+        obs.histogram_observe("round.seconds", float(dt))
+        obs.counter_inc("agg.bytes_reduced", int(participated.sum()) * self._model_bytes,
+                        labels={"path": "inmesh"})
+        if run.tele_cap is not None and run.tele_merger is not None:
+            tctx = run.tele_cap.record_span(
+                "client.train", max(0.0, dt - compile_s), parent=round_ctx,
+                round_idx=round_idx, cohort=int(participated.sum()))
+            if compile_s > 0.0:
+                run.tele_cap.record_span(
+                    "client.train.compile", compile_s, parent=tctx, round_idx=round_idx)
+            run.tele_cap.sample_resources()
+            tele_blob = run.tele_cap.drain()
+            if tele_blob:
+                run.tele_merger.merge(tele_blob)
+        obs.maybe_export_metrics()
+        self.round_times.append(dt)
+        self.round_losses.append(loss)
+        # round 0 is dominated by XLA compile, and so is the first round at a new bucket
+        # shape: either would poison the runtime model's fit
+        if round_idx > 0 and not self._bucket_compiling:
+            # The round's wall time is set by the heaviest mesh slot: record max device
+            # STEPS — the while_loop's actual trip count, so round time is genuinely
+            # load-dependent and the fitted slope drives next rounds' LPT balancing (in the
+            # same step units _schedule passes as costs).
+            self.runtime_estimator.record(0, self._steps_max, dt)
+        samples = int(counts.sum()) * int(getattr(self.args, "epochs", 1))
+        self.samples_per_round.append(samples)
+        self.samples_trained += samples
+        self.metrics.log({"round": round_idx, "round_time_s": round(dt, 4), "train_loss": loss})
+        mlops.log_round_info(run.comm_round, round_idx)
+        # population accounting for the synchronous round: everyone sampled was invited and
+        # reported; emits cohort_stats
+        self.population.observe_round(round_idx, cohort.sampled, seconds=dt)
+        last = round_idx == run.comm_round - 1
+        if run.ckpt is not None and (round_idx % checkpoint_frequency(self.args) == 0 or last):
+            with obs.span("round.checkpoint", ctx, round_idx=round_idx):
+                self._checkpoint(round_idx, run.ckpt)
+        freq = int(getattr(self.args, "frequency_of_the_test", 10))
+        # freq <= 0 disables eval (throughput benches)
+        if freq > 0 and (round_idx % freq == 0 or last):
+            with obs.span("round.eval", ctx, round_idx=round_idx):
+                return samples, self._test_global(round_idx)
+        return samples, None
+
+    def _checkpoint(self, round_idx: int, ckpt):
+        from flax import serialization
+
+        state = {"variables": self.variables, "rng": self._rng,
+                 "server_state": serialization.to_state_dict(self.server_state)}
+        if self.client_state is not None:
+            state["client_state"] = serialization.to_state_dict(self.client_state)
+        host = self.algo.host_state()
+        if host:
+            state["algo_host_state"] = host
+        if self.defended and self._defense_state:
+            state["defense_state"] = {k: np.asarray(v) for k, v in self._defense_state.items()}
+            state["defense_n"] = self._defense_n
+        ckpt.save(round_idx, state)
 
     def _test_global(self, round_idx: int) -> Dict[str, Any]:
         self.aggregator.set_model_params(self.variables)
@@ -1284,8 +1014,6 @@ class XLASimulator:
         the upload round still weighs in.  mean_round_s keeps the
         warmup-inclusive average for comparison.  All zeros if no round ran.
         """
-        import numpy as _np
-
         times = self.round_times[1:] if len(self.round_times) > 1 else self.round_times
         samples = (
             self.samples_per_round[1:]
@@ -1295,9 +1023,9 @@ class XLASimulator:
         if not times:
             return {"rounds_per_sec": 0.0, "mean_round_s": 0.0,
                     "median_round_s": 0.0, "samples_per_sec": 0.0}
-        med = float(_np.median(times))
+        med = float(np.median(times))
         # per-round pairing preserved: median of the per-round ratios
-        sps = float(_np.median([s / max(t, 1e-9) for s, t in zip(samples, times)]))
+        sps = float(np.median([s / max(t, 1e-9) for s, t in zip(samples, times)]))
         return {
             "rounds_per_sec": 1.0 / max(med, 1e-9),
             "mean_round_s": sum(times) / len(times),

@@ -3,7 +3,7 @@
 Run as:  python multihost_child.py <rank> <coordinator_port>
 Env must set JAX_PLATFORMS=cpu and XLA_FLAGS device-count BEFORE jax loads
 (the parent test does this via the subprocess env).  Prints one final line
-``MHOK <padded_norm> <packed_norm> <defended_norm>`` consumed by the parent.
+``MHOK <round_norm> <defended_norm>`` consumed by the parent.
 """
 
 import os
@@ -57,12 +57,7 @@ def main(rank: int, port: str) -> None:
     model = models.create(args, out_dim)
     sim = XLASimulator(args, dataset, model)
     sim.train()
-    padded = norm(sim)
-
-    args2 = fedml_tpu.init(build_args(xla_pack=True), should_init_logs=False)
-    sim2 = XLASimulator(args2, dataset, model)
-    sim2.train()
-    packed = norm(sim2)
+    plain = norm(sim)
 
     # the security path: the per-client update stack stays P('client')-
     # sharded (NOT fully addressable from either process) and the stacked
@@ -71,7 +66,7 @@ def main(rank: int, port: str) -> None:
     from fedml_tpu.core.security.fedml_attacker import FedMLAttacker
     from fedml_tpu.core.security.fedml_defender import FedMLDefender
 
-    args3 = build_args(xla_pack=True, enable_attack=True,
+    args3 = build_args(enable_attack=True,
                        attack_type="byzantine", attack_mode="random",
                        byzantine_client_num=2, enable_defense=True,
                        defense_type="krum")
@@ -86,7 +81,7 @@ def main(rank: int, port: str) -> None:
         FedMLAttacker._attacker_instance = None
         FedMLDefender._defender_instance = None
 
-    print(f"MHOK {padded:.6f} {packed:.6f} {defended:.6f}", flush=True)
+    print(f"MHOK {plain:.6f} {defended:.6f}", flush=True)
 
 
 if __name__ == "__main__":

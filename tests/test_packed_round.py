@@ -1,6 +1,8 @@
-"""Packed ragged round (ml/engine/packed.py, args.xla_pack): must train to
-the same quality as the padded round without per-client padding waste, and
-support the in-mesh algorithm zoo."""
+"""The in-mesh round's packed stream (ml/engine/packed.py): ``pack_round`` and
+``s_max_for`` as units (the rule ``benchmark/reference.py`` re-derives for its
+feed order), and the compiled round over it: it must train to the sp oracle's
+quality, walk ceil(n_i/B) steps a client and support the in-mesh algorithm
+zoo."""
 
 import jax
 import numpy as np
@@ -8,10 +10,11 @@ import pytest
 
 import fedml_tpu
 from fedml_tpu.arguments import Arguments
+from fedml_tpu.ml.engine.packed import pack_round, s_max_for
 from fedml_tpu.parallel.mesh import create_fl_mesh
 from fedml_tpu.simulation.xla.fed_sim import XLASimulator
 
-pytestmark = pytest.mark.heavy  # long XLA compiles; see pytest.ini
+heavy = pytest.mark.heavy  # long XLA compiles; see pytest.ini
 
 
 def _args(**over):
@@ -35,7 +38,6 @@ def _args(**over):
                 "batch_size": 32,
                 "client_optimizer": "sgd",
                 "learning_rate": 0.1,
-                "xla_pack": True,
             },
             "validation_args": {"frequency_of_the_test": 2},
             "comm_args": {"backend": "XLA"},
@@ -53,33 +55,169 @@ def _build(args):
     return args, dataset, model
 
 
+# (name, batch, epochs, counts [n_dev, slots]; 0 = a dummy slot)
+SHAPES = [
+    ("batch_one", 1, 1, [[3, 2], [4, 1]]),
+    ("ragged_with_a_one_sample_client", 4, 1, [[1, 9, 4], [7, 8, 5]]),
+    ("two_epochs", 4, 2, [[5, 8], [3, 12]]),
+    ("a_dummy_slot", 3, 1, [[6, 0], [2, 7]]),
+    ("more_slots_than_clients", 2, 2, [[5, 0, 0], [0, 0, 0], [0, 3, 0]]),
+]
+
+
+def _packed(batch, epochs, counts, seed=7, round_idx=2, ids2d=None):
+    """A schedule over clients whose rows are consecutive ranges of the
+    global arrays; returns (schedule, ids2d, counts2d, rows by client id)."""
+    counts2d = np.asarray(counts, np.int64)
+    if ids2d is None:
+        ids2d = np.arange(counts2d.size).reshape(counts2d.shape)[:, ::-1].copy()
+    starts = {}
+    for cid, n in sorted(zip(ids2d.ravel().tolist(), counts2d.ravel().tolist())):
+        starts[cid] = (sum(m for _, m in starts.values()), n)
+    rows = {cid: np.arange(a, a + n) for cid, (a, n) in starts.items()}
+    s_max = s_max_for(int(counts2d.max()), counts2d.shape[1], batch, epochs)
+    # the simulator's table is wider than a short client's shard
+    table = lambda cid: np.concatenate([rows[cid], np.zeros(3, np.int64)])  # noqa: E731
+    return (pack_round(ids2d, counts2d, table, batch, epochs, seed, round_idx, s_max),
+            ids2d, counts2d, rows)
+
+
+def _client_steps(sched, d, ls):
+    """The steps of device ``d`` that belong to local slot ``ls``, below n_steps."""
+    live = np.arange(sched.idx.shape[1]) < sched.n_steps[d]
+    return np.flatnonzero(live & (sched.slot[d] == ls))
+
+
+shapes = pytest.mark.parametrize("batch,epochs,counts", [s[1:] for s in SHAPES],
+                                 ids=[s[0] for s in SHAPES])
+
+
+class TestPackRound:
+    """Pure numpy: what the compiled stream relies on, and what
+    ``benchmark/reference.py`` re-derives."""
+
+    @shapes
+    def test_every_row_is_fed_once_an_epoch(self, batch, epochs, counts):
+        sched, ids2d, counts2d, rows = _packed(batch, epochs, counts)
+        for d, ls in np.ndindex(*counts2d.shape):
+            steps = _client_steps(sched, d, ls) if counts2d[d, ls] else []
+            fed = sched.idx[d, steps][sched.mask[d, steps] > 0]
+            want = np.repeat(rows[int(ids2d[d, ls])], epochs) if counts2d[d, ls] else []
+            assert sorted(fed.tolist()) == sorted(np.asarray(want).tolist())
+
+    @shapes
+    def test_padding_sits_only_in_an_epochs_last_batch(self, batch, epochs, counts):
+        sched, _, counts2d, _ = _packed(batch, epochs, counts)
+        for d, ls in np.ndindex(*counts2d.shape):
+            n = int(counts2d[d, ls])
+            if not n:
+                continue
+            m = sched.mask[d, _client_steps(sched, d, ls)]
+            assert m.sum() == n * epochs
+            per_epoch = m.reshape(epochs, -(-n // batch), batch)
+            assert (per_epoch[:, :-1] == 1).all()
+            # a last batch is real rows first, then padding
+            tail = per_epoch[:, -1]
+            assert (tail.sum(axis=1) == n - (-(-n // batch) - 1) * batch).all()
+            assert (np.diff(tail, axis=1) <= 0).all()
+
+    @shapes
+    def test_one_boundary_a_client_on_its_last_step_with_its_weight(self, batch, epochs, counts):
+        sched, _, counts2d, _ = _packed(batch, epochs, counts)
+        for d in range(counts2d.shape[0]):
+            marked = np.flatnonzero(sched.boundary[d])
+            real = [ls for ls in range(counts2d.shape[1]) if counts2d[d, ls]]
+            assert marked.tolist() == [int(_client_steps(sched, d, ls)[-1]) for ls in real]
+            assert sched.weight[d, marked].tolist() == [float(counts2d[d, ls]) for ls in real]
+            assert set(np.unique(sched.boundary[d])) <= {0.0, 1.0}
+            assert (np.delete(sched.weight[d], marked) == 0).all()
+
+    @shapes
+    def test_step_counts_and_no_step_that_is_all_padding(self, batch, epochs, counts):
+        """``n_steps`` is the sum of ceil(n_i / B) x E, a dummy slot adds no
+        step, nothing is set beyond it — and every step below it holds a
+        real row, which is why the compiled step advances optimizer state
+        and mutable collections without a guard."""
+        sched, _, counts2d, _ = _packed(batch, epochs, counts)
+        want = (-(-counts2d // batch) * epochs).sum(axis=1)
+        assert sched.n_steps.tolist() == want.tolist()
+        for d, n in enumerate(sched.n_steps):
+            assert (sched.mask[d, :n].sum(axis=1) > 0).all()
+            for field in (sched.idx, sched.mask, sched.boundary, sched.weight, sched.slot):
+                assert not field[d, n:].any()
+
+    @shapes
+    def test_slot_is_device_local(self, batch, epochs, counts):
+        sched, _, counts2d, _ = _packed(batch, epochs, counts)
+        for d, n in enumerate(sched.n_steps):
+            real = [ls for ls in range(counts2d.shape[1]) if counts2d[d, ls]]
+            runs = [int(ls) for i, ls in enumerate(sched.slot[d, :n])
+                    if i == 0 or ls != sched.slot[d, i - 1]]
+            assert runs == real  # slots in order, each one run, none of another device
+
+    @shapes
+    def test_s_max_for_bounds_the_schedule(self, batch, epochs, counts):
+        sched, _, counts2d, _ = _packed(batch, epochs, counts)
+        s_max = s_max_for(int(counts2d.max()), counts2d.shape[1], batch, epochs)
+        assert sched.idx.shape[1] == s_max and int(sched.n_steps.max()) <= s_max
+        # the bound is reached by a device whose every slot holds the largest client
+        full = np.full(counts2d.shape, counts2d.max())
+        assert int(_packed(batch, epochs, full)[0].n_steps.max()) == s_max
+
+    def test_same_seed_and_round_same_arrays_another_round_another_order(self):
+        _, batch, epochs, counts = SHAPES[2]
+        a, b = _packed(batch, epochs, counts)[0], _packed(batch, epochs, counts)[0]
+        assert all(np.array_equal(x, y) for x, y in zip(a, b))
+        other = _packed(batch, epochs, counts, round_idx=3)[0]
+        assert not np.array_equal(a.idx, other.idx)
+        for x, y in zip(a[1:], other[1:]):  # the layout is the sizes' alone
+            assert np.array_equal(x, y)
+        assert not np.array_equal(a.idx, _packed(batch, epochs, counts, seed=8)[0].idx)
+
+    def test_a_clients_order_does_not_depend_on_where_it_was_scheduled(self):
+        _, batch, epochs, counts = SHAPES[1]
+        a, ids_a, counts_a, _ = _packed(batch, epochs, counts)
+        moved = ids_a[::-1, ::-1].copy()  # every client on the other device, another slot
+        b, ids_b, counts_b, _ = _packed(batch, epochs, np.asarray(counts)[::-1, ::-1], ids2d=moved)
+
+        def order(sched, ids2d, cid):
+            d, ls = map(int, np.argwhere(ids2d == cid)[0])
+            steps = _client_steps(sched, d, ls)
+            return sched.idx[d, steps].tolist(), sched.mask[d, steps].tolist()
+
+        for cid in ids_a.ravel():
+            assert order(a, ids_a, cid) == order(b, ids_b, cid)
+
+    def test_overflow_past_s_max_raises(self):
+        with pytest.raises(ValueError, match="overflow"):
+            pack_round(np.array([[0, 1]]), np.array([[9, 9]]), lambda cid: np.arange(9),
+                       4, 1, 0, 0, s_max=5)
+
+
+@heavy
 class TestPackedRound:
     def test_learns_on_8dev_mesh(self):
         args, dataset, model = _build(_args())
         sim = XLASimulator(args, dataset, model)
-        assert sim.packed
         metrics = sim.train()
         assert metrics["test_acc"] > 0.5
 
-    def test_matches_padded_round_quality(self):
-        """Packed and padded rounds use different shuffle streams so results
-        differ bitwise, but trained quality must match closely."""
-        args_p, dataset, model = _build(_args())
-        sim_p = XLASimulator(args_p, dataset, model)
-        m_packed = sim_p.train()
+    def test_matches_sp_fedavg_quality(self):
+        """The stream and the sp FedAvg oracle shuffle differently, so
+        results differ bitwise, but trained quality must match closely."""
+        from fedml_tpu.simulation.simulator import create_simulator
 
-        args_d, dataset_d, model_d = _build(_args(xla_pack=False))
-        sim_d = XLASimulator(args_d, dataset_d, model_d)
-        m_padded = sim_d.train()
-        assert abs(m_packed["test_acc"] - m_padded["test_acc"]) < 0.1, (
-            m_packed, m_padded,
-        )
+        args_p, dataset, model = _build(_args())
+        m_packed = XLASimulator(args_p, dataset, model).train()
+
+        args_sp, dataset_sp, model_sp = _build(_args(backend="sp"))
+        m_sp = create_simulator(
+            args_sp, fedml_tpu.device.get_device(args_sp), dataset_sp, model_sp).run()
+        assert abs(m_packed["test_acc"] - m_sp["test_acc"]) < 0.1, (m_packed, m_sp)
 
     def test_packed_step_count_is_ragged(self):
         """The packed stream runs ceil(n_i/B) steps per client, not the
-        padded global max."""
-        from fedml_tpu.ml.engine.packed import pack_round
-
+        global max."""
         args, dataset, model = _build(_args())
         sim = XLASimulator(args, dataset, model)
         sampled = sim._client_sampling(0)
@@ -93,8 +231,8 @@ class TestPackedRound:
         )
         expected = sum(2 * (-(-int(c) // sim.batch_size)) for c in counts if c > 0)
         assert int(sched.n_steps.sum()) == expected
-        padded_steps = 2 * (-(-sim.padded_n // sim.batch_size)) * (counts > 0).sum()
-        assert expected < padded_steps  # strictly less work than padding
+        padded_steps = 2 * (-(-sim.max_client_n // sim.batch_size)) * (counts > 0).sum()
+        assert expected < padded_steps  # strictly less work than padding to the max
 
     def test_async_fedavg_packed_trains(self):
         """Regression: algorithms that consume cex in client_contrib WITHOUT
@@ -104,7 +242,6 @@ class TestPackedRound:
             federated_optimizer="async_fedavg", comm_round=2,
         ))
         sim = XLASimulator(args, dataset, model)
-        assert sim.packed
         metrics = sim.train()
         assert np.isfinite(metrics["test_acc"])
 
@@ -112,8 +249,6 @@ class TestPackedRound:
         """Control-variate algorithm on the packed path: equivalence against
         an explicit host replay with the same host-side shuffles."""
         import jax.numpy as jnp
-
-        from fedml_tpu.ml.engine.packed import pack_round
 
         N = 4
         args, dataset, model = _build(_args(
@@ -211,50 +346,7 @@ class TestPackedRound:
         )
 
 
-class TestPregather:
-    def test_pregather_matches_per_step_gather(self):
-        """xla_pregather is a pure execution-strategy change: identical
-        round outputs to the per-step-gather packed round."""
-        outs = {}
-        for pregather in (False, True):
-            args, dataset, model = _build(_args(xla_pregather=pregather,
-                                                comm_round=2))
-            sim = XLASimulator(args, dataset, model)
-            sim.train()
-            leaves = jax.tree_util.tree_leaves(sim.variables)
-            outs[pregather] = [np.asarray(l) for l in leaves]
-        for a, b in zip(outs[False], outs[True]):
-            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
-
-
-class TestScanStream:
-    def test_scan_matches_while_loop(self):
-        """xla_stream='scan' is a pure execution-strategy change: the
-        bucketed tail carries all-zero masks, so outputs are identical to
-        the while_loop walk."""
-        outs = {}
-        for stream in ("while", "scan"):
-            args, dataset, model = _build(_args(xla_stream=stream, comm_round=2))
-            sim = XLASimulator(args, dataset, model)
-            sim.train()
-            outs[stream] = [np.asarray(l) for l in jax.tree_util.tree_leaves(sim.variables)]
-        for a, b in zip(outs["while"], outs["scan"]):
-            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
-
-    def test_scan_matches_with_grad_hook(self):
-        """FedProx's hook is nonzero on zero grads; the scan tail must be
-        masked, not merely zero-grad."""
-        outs = {}
-        for stream in ("while", "scan"):
-            args, dataset, model = _build(_args(xla_stream=stream, comm_round=2,
-                                                proximal_mu=0.1))
-            sim = XLASimulator(args, dataset, model)
-            sim.train()
-            outs[stream] = [np.asarray(l) for l in jax.tree_util.tree_leaves(sim.variables)]
-        for a, b in zip(outs["while"], outs["scan"]):
-            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
-
-
+@heavy
 class TestStepScheduling:
     """The packed round schedules and models runtime in its native unit:
     compiled steps (ceil(n/B)*E), with a quantized stream bucket."""
@@ -318,6 +410,7 @@ class TestStepScheduling:
         assert sim._s_bucket == expect, (sim._s_bucket, expect, s_used, sim.s_max)
 
 
+@heavy
 class TestDataStorageDtype:
     def test_bf16_storage_matches_fp32_storage(self):
         """Under bf16 compute the model's entry cast makes a stored-bf16
@@ -360,3 +453,25 @@ class TestDataStorageDtype:
         ))
         sim = XLASimulator(args, dataset, model)
         assert np.issubdtype(np.asarray(sim.x_all[:1]).dtype, np.integer)
+
+
+RETIRED = [key for key, _ in Arguments.RETIRED_ROUND_KEYS]  # pack, stream, pre-gather, client chunk
+
+
+class TestRetiredKeys:
+    """The keys that selected among rounds that no longer exist: a config
+    that still asks for one of them is refused, not silently trained on
+    another."""
+
+    @pytest.mark.parametrize("key,value", zip(RETIRED, (False, "scan", True, 4)), ids=RETIRED)
+    def test_a_value_that_asks_for_a_removed_round_is_refused(self, key, value):
+        with pytest.raises(ValueError, match=key + ".*packed stream is the only round"):
+            _args(**{key: value})
+
+    @heavy
+    def test_the_values_that_meant_the_packed_stream_still_build_and_train(self):
+        args, dataset, model = _build(_args(
+            comm_round=1, **dict(zip(RETIRED, (True, "while", False, 1)))))
+        sim = XLASimulator(args, dataset, model)
+        sim.train()
+        assert np.isfinite(sim.round_losses[-1]) and sim.round_log[-1]["steps_max"] > 0

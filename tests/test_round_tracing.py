@@ -1,10 +1,10 @@
 """The in-mesh round seen from inside (ISSUE 25): the host phase spans under
 the ``round`` root, ``round_log`` / ``startup_log``, the device scopes and
-kernel names in the lowered programs, and the start-up counters of
-``core/obs``.  CPU, tiny sizes."""
+kernel names in the lowered programs, the start-up counters of ``core/obs``,
+and the seam through which ``benchmark/tests`` build the round program on a
+bare object.  CPU, tiny sizes."""
 
 import os
-import re
 import sys
 
 import jax
@@ -28,7 +28,7 @@ SCOPES = ("fed.gather", "fed.local_step", "fed.flush", "fed.exchange", "fed.serv
 ROUNDS = 3
 
 
-def _simulator(pack: bool, obs_on: bool, run_id: str, **train):
+def _simulator(obs_on: bool, run_id: str, **train):
     args = Arguments.from_dict({
         "common_args": {"training_type": "simulation", "random_seed": 0, "run_id": run_id},
         "data_args": {"dataset": "mnist", "data_cache_dir": "",
@@ -37,7 +37,7 @@ def _simulator(pack: bool, obs_on: bool, run_id: str, **train):
         "train_args": {"federated_optimizer": "FedAvg", "client_num_in_total": 4,
                        "client_num_per_round": 4, "comm_round": ROUNDS, "epochs": 1,
                        "batch_size": 16, "client_optimizer": "sgd", "learning_rate": 0.1,
-                       "xla_pack": pack, **train},
+                       **train},
         "validation_args": {"frequency_of_the_test": 0},
         "comm_args": {"backend": "XLA"},
         "tracking_args": {"obs_trace": obs_on},
@@ -53,16 +53,15 @@ def _simulator(pack: bool, obs_on: bool, run_id: str, **train):
     return XLASimulator(args, dataset, model), mem
 
 
-@pytest.fixture(scope="module", params=["packed", "padded"])
-def traced(request):
-    """One traced run and one with obs off, of the same round builder."""
-    pack = request.param == "packed"
+@pytest.fixture(scope="module")
+def traced():
+    """One traced run and one with obs off."""
     try:
-        sim, mem = _simulator(pack, True, "rt-" + request.param)
+        sim, mem = _simulator(True, "rt-packed")
         sim.train()
     finally:
         mlops.finish()
-    quiet, _ = _simulator(pack, False, "rt-" + request.param)
+    quiet, _ = _simulator(False, "rt-packed")
     quiet.train()
     return sim, mem, quiet
 
@@ -119,7 +118,7 @@ def test_round_span_attributes(traced):
             assert pack_end[key] == rec[key]
         assert rec["steps_max"] > 0 and rec["h2d_bytes"] > 0
     events = [e["event"] for e in mem.by_topic("span_event")]
-    assert events.count("bucket_compile") == (1 if sim.packed else 0)
+    assert events.count("bucket_compile") == 1
 
 
 def test_round_log_is_the_spans_numbers(traced):
@@ -177,9 +176,8 @@ def test_taken_out_metrics_stay_out(traced):
     assert {"round.seconds", "agg.bytes_reduced", "startup.init_seconds"} <= names
 
 
-@pytest.mark.parametrize("pack", [True, False], ids=["packed", "padded"])
-def test_scopes_and_program_name_in_lowered_round(pack):
-    sim, _ = _simulator(pack, False, "rt-lower", comm_round=1)
+def test_scopes_and_program_name_in_lowered_round():
+    sim, _ = _simulator(False, "rt-lower", comm_round=1)
     real, seen = sim._round_fn, {}
 
     def spy(*inputs):
@@ -189,14 +187,63 @@ def test_scopes_and_program_name_in_lowered_round(pack):
     sim._round_fn = spy
     sim.train()
     text = real.lower(*seen["inputs"]).as_text(debug_info=True)
-    name = "fedml_round_packed" if pack else "fedml_round_padded"
-    assert f"module @jit_{name}" in text
-    for scope in SCOPES:  # under vmap (the padded round) a scope reads vmap(<scope>)/
-        assert re.search(re.escape(scope) + r"\)?/", text), scope
+    assert "module @jit_fedml_round_packed" in text
+    for scope in SCOPES:
+        assert scope + "/" in text, scope
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_round_builds_on_a_bare_object_as_the_benchmark_builds_it(n_dev):
+    """``benchmark/tests/test_compile_v5e.py`` (not in tier-1) compiles the
+    cells' rounds without a data upload: ``XLASimulator.__new__``, eleven
+    attributes, ``_build_packed_round_fn()``, then twelve arguments shaped
+    from ``s_max`` / ``slots``.  The same recipe, lowered here."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from fedml_tpu.ml.engine.train import init_variables
+    from fedml_tpu.simulation.xla.algorithms import create_inmesh_algorithm
+
+    args = Arguments.from_dict({
+        "common_args": {"training_type": "simulation", "random_seed": 0},
+        "model_args": {"model": "lr"}, "data_args": {"dataset": "mnist"},
+        "train_args": {"federated_optimizer": "FedAvg", "client_num_in_total": 8,
+                       "client_num_per_round": 8, "comm_round": 1, "epochs": 1,
+                       "batch_size": 4, "client_optimizer": "sgd", "learning_rate": 0.1},
+        "comm_args": {"backend": "XLA"}}).validate()
+    sizes, width, b = [3, 5, 5, 7, 7, 9, 9, 11], 784, 4
+    mesh = Mesh(np.array(jax.devices()[:n_dev]), ("client",))
+    sim = XLASimulator.__new__(XLASimulator)
+    sim.args, sim.module, sim.mesh, sim.n_dev = args, fedml_tpu.models.create(args, 10), mesh, n_dev
+    sim.clients_per_round, sim.batch_size, sim.max_client_n = len(sizes), b, max(sizes)
+    sim.needs_stack = sim.sharded_state = False
+    sim.loss_kind, sim.algo = "ce", create_inmesh_algorithm(args)
+    sim._build_packed_round_fn()
+    assert sim.slots == len(sizes) // n_dev and sim.s_max == sim.slots * 3
+
+    steps = -(-sum(-(-n // b) for n in sizes) // n_dev)
+    quantum = max(1, -(-sim.s_max // 8))
+    bucket = min(-(-steps // quantum) * quantum, sim.s_max)
+    repl, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("client"))
+
+    def s(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    variables = jax.tree_util.tree_map(
+        lambda v: s(v.shape, v.dtype, repl),
+        jax.eval_shape(lambda: init_variables(sim.module, jnp.zeros((1, width)), seed=0)))
+    inputs = (variables, (), s((sum(sizes), width), jnp.float32, repl), s((sum(sizes),), jnp.int32, repl),
+              s((n_dev, bucket, b), jnp.int32, split), s((n_dev, bucket, b), jnp.float32, split),
+              s((n_dev, bucket), jnp.float32, split), s((n_dev, bucket), jnp.float32, split),
+              s((n_dev, bucket), jnp.int32, split), s((n_dev,), jnp.int32, split),
+              s((n_dev, 2), jnp.uint32, split), s((n_dev * sim.slots,), jnp.float32, split))
+    text = sim._round_fn.lower(*inputs).as_text(debug_info=True)
+    assert "module @jit_fedml_round_packed" in text
+    for scope in SCOPES:
+        assert scope + "/" in text, scope
 
 
 def test_server_tail_has_its_scope():
-    sim, _ = _simulator(True, False, "rt-tail", comm_round=1, server_state="sharded",
+    sim, _ = _simulator(False, "rt-tail", comm_round=1, server_state="sharded",
                         federated_optimizer="FedOpt", server_optimizer="adam")
     real, seen = sim._server_tail, {}
 

@@ -75,29 +75,13 @@ class TestXLASimulator:
         sim = XLASimulator(args, dataset, model, mesh=mesh)
         w0 = sim.variables
 
-        # replicate the round on the host path using the same engine fn + rngs
-        import jax.numpy as jnp
-
+        # replay the round's packed stream on the host, client by client
         from fedml_tpu.core.aggregate import weighted_mean
-        from fedml_tpu.ml.engine.train import build_local_train, pad_to
+        from packed_replay import replay_clients
 
-        sampled = sim._client_sampling(0)
-        ids, real = sim._schedule(sampled)
-        counts = np.where(real > 0, np.asarray(sim.client_counts)[ids], 0)
-        rng = jax.random.PRNGKey(int(args.random_seed) + 11)
-        _, sub = jax.random.split(rng)
-        rngs = jax.random.split(jax.random.fold_in(sub, 0), len(ids))
-
-        fn = build_local_train(model, args, int(args.batch_size), sim.padded_n)
-        updates = []
-        for slot, cid in enumerate(ids):
-            if counts[slot] == 0:
-                continue
-            idx_row = np.asarray(sim.client_idx[cid])
-            x = jnp.asarray(np.asarray(sim.x_all)[idx_row])
-            y = jnp.asarray(np.asarray(sim.y_all)[idx_row])
-            res = fn(w0, x, y, int(counts[slot]), rngs[slot])
-            updates.append((float(counts[slot]), res.variables))
+        ids, real = sim._schedule(sim._client_sampling(0))
+        updates = [(n, res.variables) for _, n, res in
+                   replay_clients(sim, model, args, ids, real, 0, w0)]
         expected = weighted_mean(updates)
 
         sim.train()
@@ -133,13 +117,12 @@ class TestGraftEntry:
 class TestDeterministicReplay:
     """SURVEY §5 race-detection rebuild note: JAX's functional model replaces
     sanitizers with determinism guarantees — same seed, bitwise-same round
-    outputs, for both execution strategies."""
+    outputs."""
 
-    @pytest.mark.parametrize("pack", [False, True])
-    def test_two_runs_bitwise_identical(self, pack):
+    def test_two_runs_bitwise_identical(self):
         outs = []
         for _ in range(2):
-            args, dataset, model = _build(_args(comm_round=2, xla_pack=pack))
+            args, dataset, model = _build(_args(comm_round=2))
             sim = XLASimulator(args, dataset, model)
             sim.train()
             outs.append([np.asarray(l) for l in jax.tree_util.tree_leaves(sim.variables)])
@@ -152,15 +135,14 @@ class TestInMeshLocalDP:
     aggregation (the mechanism's add_noise is jax-pure), budget accounted
     host-side per participating client."""
 
-    @pytest.mark.parametrize("pack", [False, True])
-    def test_ldp_noises_and_accounts(self, pack):
+    def test_ldp_noises_and_accounts(self):
         from fedml_tpu.core.dp.fedml_differential_privacy import (
             FedMLDifferentialPrivacy,
         )
 
         results = {}
         for enable in (False, True):
-            args, dataset, model = _build(_args(comm_round=2, xla_pack=pack))
+            args, dataset, model = _build(_args(comm_round=2))
             args.enable_dp = enable
             args.dp_type = "ldp"
             args.mechanism_type = "gaussian"
@@ -190,9 +172,9 @@ def _reset_security():
     return FedMLAttacker.get_instance(), FedMLDefender.get_instance()
 
 
-def _run_security(attack=None, defense=None, pack=False, comm_round=2, **extra):
+def _run_security(attack=None, defense=None, comm_round=2, **extra):
     """One XLA run with the given attack/defense config; returns (sim, metrics)."""
-    args, dataset, model = _build(_args(comm_round=comm_round, xla_pack=pack))
+    args, dataset, model = _build(_args(comm_round=comm_round))
     for k, v in extra.items():
         setattr(args, k, v)
     if attack:
@@ -215,13 +197,14 @@ def _run_security(attack=None, defense=None, pack=False, comm_round=2, **extra):
 class TestInMeshDefense:
     """Robust aggregation on the XLA backend: the compiled round returns the
     sharded per-client update stack; a second jitted program substitutes the
-    robust aggregate (core/security/stacked.py) — both execution strategies,
-    every aggregates_via_acc algorithm."""
+    robust aggregate (core/security/stacked.py) — every aggregates_via_acc
+    algorithm."""
 
     @pytest.mark.parametrize("defense,extra", [
         ("coordinate_wise_median", {}),
         ("krum", {"byzantine_client_num": 1}),
         ("norm_diff_clipping", {"norm_bound": 5.0}),
+        ("geometric_median", {}),
     ])
     def test_defended_round_learns(self, defense, extra):
         sim, metrics = _run_security(defense=defense, **extra)
@@ -233,19 +216,9 @@ class TestInMeshDefense:
         # median != weighted mean on heterogeneous clients
         assert clean["test_loss"] != defended["test_loss"]
 
-    @pytest.mark.parametrize("defense,extra", [
-        ("krum", {"byzantine_client_num": 1}),
-        ("geometric_median", {}),
-    ])
-    def test_packed_defended_round_learns(self, defense, extra):
-        sim, metrics = _run_security(defense=defense, pack=True, **extra)
-        assert metrics["test_acc"] > 0.5, (defense, metrics)
-
-    @pytest.mark.parametrize("pack", [False, True])
-    def test_defense_composes_with_scaffold(self, pack):
+    def test_defense_composes_with_scaffold(self):
         _, metrics = _run_security(
-            defense="coordinate_wise_median", pack=pack,
-            federated_optimizer="SCAFFOLD",
+            defense="coordinate_wise_median", federated_optimizer="SCAFFOLD",
         )
         assert metrics["test_acc"] > 0.5, metrics
 
@@ -345,15 +318,14 @@ class TestInMeshAttack:
     (reference fedml_attacker.py:28-30 — one simulator runs the whole
     matrix)."""
 
-    @pytest.mark.parametrize("pack", [False, True])
-    def test_byzantine_degrades_and_krum_recovers(self, pack):
-        _, clean = _run_security(pack=pack, comm_round=3)
+    def test_byzantine_degrades_and_krum_recovers(self):
+        _, clean = _run_security(comm_round=3)
         _, attacked = _run_security(
-            attack="byzantine", pack=pack, comm_round=3,
+            attack="byzantine", comm_round=3,
             attack_mode="random", byzantine_client_num=8,
         )
         _, defended = _run_security(
-            attack="byzantine", defense="krum", pack=pack, comm_round=3,
+            attack="byzantine", defense="krum", comm_round=3,
             attack_mode="random", byzantine_client_num=8,
         )
         # 8/16 random-garbage clients wreck plain FedAvg; krum survives

@@ -3,10 +3,10 @@ single-process server math (reference ``simulation/mpi/{fedopt,fednova,...}``
 semantics) exactly.
 
 Each test runs the compiled in-mesh simulator for 2 rounds, then replays the
-same rounds on the host with an INDEPENDENT formulation: per-client calls to
-the engine's local_train plus the explicit published update rule (the same
-formulas the sp implementations use), and asserts the final global variables
-match."""
+same rounds on the host with an INDEPENDENT formulation: a per-client replay of
+the round's packed stream (tests/packed_replay.py) plus the explicit published
+update rule (the same formulas the sp implementations use), and asserts the
+final global variables match."""
 
 import jax
 import jax.numpy as jnp
@@ -15,9 +15,9 @@ import pytest
 
 import fedml_tpu
 from fedml_tpu.arguments import Arguments
-from fedml_tpu.ml.engine.train import build_local_train
 from fedml_tpu.parallel.mesh import create_fl_mesh
 from fedml_tpu.simulation.xla.fed_sim import XLASimulator
+from packed_replay import replay_clients
 
 pytestmark = pytest.mark.heavy  # long XLA compiles; see pytest.ini
 
@@ -81,28 +81,11 @@ class Replay:
         return self.sim.variables
 
     def local_results(self, round_idx, w_global, grad_hook=None, extras=None):
-        """Per-client engine runs for one round, in schedule order.
+        """Per-client host runs for one round, in stream order.
         Returns [(cid, n_i, LocalTrainResult)] for real clients."""
-        sim, args = self.sim, self.args
-        fn = build_local_train(self.model, args, int(args.batch_size), sim.padded_n,
-                               grad_hook=grad_hook)
         ids, real = self.schedules[round_idx]
-        counts = np.where(real > 0, np.asarray(sim.client_counts)[ids], 0)
-        rng = jax.random.PRNGKey(int(args.random_seed) + 11)
-        for _ in range(round_idx + 1):
-            rng, sub = jax.random.split(rng)
-        rngs = jax.random.split(jax.random.fold_in(sub, round_idx), len(ids))
-        out = []
-        for slot, cid in enumerate(ids):
-            if counts[slot] == 0:
-                continue
-            idx_row = np.asarray(sim.client_idx[cid])
-            x = jnp.asarray(np.asarray(sim.x_all)[idx_row])
-            y = jnp.asarray(np.asarray(sim.y_all)[idx_row])
-            extra = None if extras is None else extras[int(cid)]
-            res = fn(w_global, x, y, int(counts[slot]), rngs[slot], extra=extra)
-            out.append((int(cid), float(counts[slot]), res))
-        return out
+        return replay_clients(self.sim, self.model, self.args, ids, real, round_idx,
+                              w_global, grad_hook=grad_hook, extras=extras)
 
 
 def assert_trees_close(a, b, rtol=2e-4, atol=2e-5):
@@ -144,6 +127,44 @@ class TestXLAZoo:
             updates, opt_state = tx.update(pseudo, opt_state, w["params"])
             w = dict(avg, params=optax.apply_updates(w["params"], updates))
         assert_trees_close(got, w)
+
+    def test_fedprox_matches_host_math(self):
+        """The proximal pull toward the round-start parameters (the engine's
+        hook from ``proximal_mu``, anchored at the stream's ``params0``)."""
+        rp = Replay(federated_optimizer="FedProx", proximal_mu=0.1)
+        got = rp.run_sim()
+
+        def hook(grads, params, anchor, extra):
+            return jax.tree_util.tree_map(
+                lambda g, p, a: g + 0.1 * (p - a), grads, params, anchor)
+
+        w = rp.w0
+        for r in range(ROUNDS):
+            w = wavg(rp.local_results(r, w, grad_hook=hook), w)
+        assert_trees_close(got, w)
+        # the pull is in the trajectory: plain FedAvg ends elsewhere
+        plain = Replay().run_sim()
+        assert max(float(jnp.abs(a - b).max()) for a, b in zip(
+            jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(plain))) > 1e-5
+
+    def test_adam_clients_on_uneven_streams_match_host_math(self):
+        """A stateful client optimizer: its state advances on every step of
+        a client and starts anew at each boundary, on devices whose streams
+        end at different steps."""
+        rp = Replay(client_optimizer="adam", learning_rate=0.01,
+                    client_num_in_total=8, client_num_per_round=8,
+                    partition_method="hetero", partition_alpha=0.5,
+                    synthetic_train_size=1280)
+        got = rp.run_sim()
+        w = rp.w0
+        for r in range(ROUNDS):
+            results = rp.local_results(r, w)
+            w = wavg(results, w)
+        assert_trees_close(got, w)
+        steps = [-(-int(rp.sim.local_num_dict[int(c)]) // int(rp.args.batch_size))
+                 for c in rp.schedules[0][0]]
+        per_device = np.asarray(steps).reshape(rp.sim.n_dev, -1).sum(axis=1)
+        assert len(set(per_device.tolist())) > 1, per_device
 
     def test_fednova_matches_host_math(self):
         rp = Replay(federated_optimizer="FedNova")
