@@ -7,14 +7,20 @@ belongs to, so no client is padded to another's size:
 
 * each client contributes ceil(n_i/B) batches per epoch (its own padding is
   at most B-1 samples), clients back-to-back;
-* a ``lax.while_loop`` walks the stream: ordinary SGD steps, and at each
-  client BOUNDARY the carry flushes (weighted accumulation + algorithm
-  contributions + per-slot outputs) and resets params/optimizer to the
-  round-start state;
-* the loop trip count is a TRACED scalar (different per device and per
-  round) over statically-shaped index buffers sized for the worst case —
-  no recompile when the sampled client sizes change, and devices stop after
-  their own last real step.
+* two nested ``lax.while_loop``s walk the stream and share no branch: the
+  OUTER one walks clients and carries only what outlives a client (the
+  stream position, the weighted accumulator, the algorithm's contributions,
+  the per-slot outputs, the sums); the INNER one walks one client's SGD
+  steps from the round-start params/optimizer (its initial carry) until
+  the step it has just run carried the client's BOUNDARY; after it, once a
+  client, the boundary work (weighted accumulation + algorithm contributions
+  + per-slot outputs).  A step that ends no client moves nothing but its
+  own work: no ``lax.cond`` whose untaken branch would copy the model;
+* both trip counts are TRACED (a device's ``n_steps`` and the stream's
+  ``boundary`` marks, different per device and per round) over
+  statically-shaped index buffers sized for the worst case — no recompile
+  when the sampled client sizes change, and devices stop after their own
+  last real step (one with no step runs neither loop).
 
 Shuffling is host-side (numpy, seeded per (seed, round, client, epoch)) since
 the batch order IS the data layout here; the device does not permute.
@@ -200,9 +206,8 @@ def build_packed_device_fn(
             return (optax.apply_updates(params, updates), updated or other,
                     new_opt, lval, bmask, counts)
 
-        def body(carry):
-            (step, params, other, opt_state, c_steps, c_loss, c_cnt,
-             acc, wsum, lsum, cnt, ext, outs, ctr) = carry
+        def client_step(carry):
+            (step, params, other, opt_state, c_steps, c_loss, c_cnt, ctr, _) = carry
             with jax.named_scope("fed.gather"):
                 bx = jnp.take(x_all, idx[step], axis=0)
                 by = jnp.take(y_all, idx[step], axis=0)
@@ -214,18 +219,26 @@ def build_packed_device_fn(
             c_steps = c_steps + valid
             c_loss = c_loss + lval * jnp.sum(bmask)
             c_cnt = c_cnt + jnp.sum(bmask)
+            return (step + 1, params, other, opt_state, c_steps, c_loss, c_cnt,
+                    ctr, boundary[step] > 0)
 
-            def flush(ops):
-                (params, other, opt_state, c_steps, c_loss, c_cnt,
-                 acc, wsum, lsum, cnt, ext, outs) = ops
-                w = weight[step]
+        def client(carry):
+            step, acc, wsum, lsum, cnt, ext, outs, ctr = carry
+            # one client: from the round-start state until the step just run
+            # carried its boundary (a stream ends on one; n_steps is the guard)
+            (step, params, other, _, c_steps, c_loss, c_cnt, ctr, _) = jax.lax.while_loop(
+                lambda c: ~c[-1] & (c[0] < n_steps), client_step,
+                (step, params0, other0, opt0, 0.0, 0.0, 0.0, ctr, jnp.bool_(False)))
+            last = step - 1  # the client's boundary step
+            with jax.named_scope("fed.flush"):
+                w = weight[last]
                 real = (w > 0).astype(jnp.float32)
                 out_vars = dict(other, params=params)
                 if post_train is not None:
                     # in-mesh local DP: noise this client's update at its
                     # boundary, keyed by (device rng, stream position)
                     out_vars = post_train(
-                        out_vars, jax.random.fold_in(rng, step + 104729)
+                        out_vars, jax.random.fold_in(rng, last + 104729)
                     )
                 result = LocalTrainResult(
                     out_vars,
@@ -233,7 +246,7 @@ def build_packed_device_fn(
                     c_cnt,
                     c_steps,
                 )
-                s = slot[step]
+                s = slot[last]
                 # cex feeds client_contrib/client_out for ALL algorithms
                 # (uses_extra only gates the grad-hook extra, not this)
                 cex_i = jax.tree_util.tree_map(
@@ -255,27 +268,12 @@ def build_packed_device_fn(
                     ),
                     outs, out_i,
                 )
-                return (params0, other0, opt0, 0.0, 0.0, 0.0,
-                        acc, wsum + w, lsum + c_loss, cnt + c_cnt, ext, outs)
+            return (step, acc, wsum + w, lsum + c_loss, cnt + c_cnt, ext, outs, ctr)
 
-            def keep(ops):
-                return ops
-
-            with jax.named_scope("fed.flush"):
-                (params, other, opt_state, c_steps, c_loss, c_cnt,
-                 acc, wsum, lsum, cnt, ext, outs) = jax.lax.cond(
-                    boundary[step] > 0, flush, keep,
-                    (params, other, opt_state, c_steps, c_loss, c_cnt,
-                     acc, wsum, lsum, cnt, ext, outs),
-                )
-            return (step + 1, params, other, opt_state, c_steps, c_loss, c_cnt,
-                    acc, wsum, lsum, cnt, ext, outs, ctr)
-
-        init = (jnp.int32(0), params0, other0, opt0, 0.0, 0.0, 0.0,
-                zeros_vars, 0.0, 0.0, 0.0, ext0, outs0,
+        init = (jnp.int32(0), zeros_vars, 0.0, 0.0, 0.0, ext0, outs0,
                 {n: jnp.zeros((), jnp.float32) for n in counter_names})
-        final = jax.lax.while_loop(lambda c: c[0] < n_steps, body, init)
-        (_, _, _, _, _, _, _, acc, wsum, lsum, cnt, ext, outs, ctr) = final
+        _, acc, wsum, lsum, cnt, ext, outs, ctr = jax.lax.while_loop(
+            lambda c: c[0] < n_steps, client, init)
         return acc, wsum, lsum, cnt, ext, outs, ctr
 
     return device_fn

@@ -608,6 +608,9 @@ class XLASimulator:
         self._bucket_compiling = self._s_bucket not in self._seen_buckets
         self._seen_buckets.add(self._s_bucket)
         self._h2d_bytes = sum(int(a.nbytes) for a in sched)
+        # how often the stream's inner loop runs and how often it ends a client
+        self._stream_counts = {"round.local_steps": int(sched.n_steps.sum()),
+                               "round.client_boundaries": int(sched.boundary.sum())}
         return tuple(jnp.asarray(a) for a in sched)
 
     def _client_steps(self, n: int) -> int:
@@ -746,9 +749,9 @@ class XLASimulator:
             # wall time is execute + host orchestration
             compile_s = max(0.0, obs.compile_seconds_total() - compile_s0)
             loss = float(mean_loss)
-            # what the module counted in the compiled round: into round_log and the registry
-            # under the module's own names
-            for name, value in counters.items():
+            # what the host packed into the stream and what the module counted in the compiled
+            # round: into round_log and the registry, the module's under its own names
+            for name, value in (*self._stream_counts.items(), *counters.items()):
                 rec[name] = float(value)
                 obs.counter_inc(name, rec[name])
             samples, evaluated = self._close(

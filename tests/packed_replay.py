@@ -21,11 +21,13 @@ def device_keys(args, round_idx, n_dev):
 
 
 def replay_clients(sim, model, args, ids, real, round_idx, w_global,
-                   grad_hook=None, extras=None):
+                   grad_hook=None, extras=None, counters=None):
     """One round's real clients trained on the host, in stream order:
     ``[(cid, n_i, LocalTrainResult)]``.  ``ids`` / ``real`` are what
     ``sim._schedule`` returned for the round; ``extras[cid]`` is the grad
-    hook's fourth argument.  Models with ``params`` alone."""
+    hook's fourth argument.  Models with ``params`` alone.  ``counters``: a
+    dict that gets, by name, the stream's sums of what the module sows a step
+    into its ``counters`` collection."""
     assert set(w_global) == {"params"}
     counts = np.where(real > 0, np.asarray(sim.client_counts)[ids], 0)
     ids2d = np.asarray(ids).reshape(sim.n_dev, sim.slots)
@@ -40,14 +42,15 @@ def replay_clients(sim, model, args, ids, real, round_idx, w_global,
     @jax.jit
     def step(params, opt_state, bx, by, bm, key, extra):
         def loss(p):
-            logits = model.apply({"params": p}, bx, train=True, rngs={"dropout": key})
-            return softmax_ce_loss(logits, by, bm)[0]
+            logits, sown = model.apply({"params": p}, bx, train=True, rngs={"dropout": key},
+                                       mutable=["counters"])
+            return softmax_ce_loss(logits, by, bm)[0], sown
 
-        lval, grads = jax.value_and_grad(loss)(params)
+        (lval, sown), grads = jax.value_and_grad(loss, has_aux=True)(params)
         if grad_hook is not None:
             grads = grad_hook(grads, params, params0, extra)
         updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, lval
+        return optax.apply_updates(params, updates), opt_state, lval, sown
 
     out = []
     for d in range(sim.n_dev):
@@ -55,9 +58,12 @@ def replay_clients(sim, model, args, ids, real, round_idx, w_global,
         for s in range(int(sched.n_steps[d])):
             cid = int(ids2d[d, sched.slot[d, s]])
             bm = sched.mask[d, s]
-            params, opt_state, lval = step(
+            params, opt_state, lval, sown = step(
                 params, opt_state, x_all[sched.idx[d, s]], y_all[sched.idx[d, s]], bm,
                 jax.random.fold_in(keys[d], s), None if extras is None else extras[cid])
+            if counters is not None:
+                for path, v in jax.tree_util.tree_flatten_with_path(sown)[0]:
+                    counters[path[-1].key] = counters.get(path[-1].key, 0.0) + float(v)
             steps, loss_sum, seen = steps + 1, loss_sum + float(lval) * bm.sum(), seen + bm.sum()
             if sched.boundary[d, s] > 0:
                 out.append((cid, float(sched.weight[d, s]), LocalTrainResult(

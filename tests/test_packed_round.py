@@ -5,6 +5,7 @@ quality, walk ceil(n_i/B) steps a client and support the in-mesh algorithm
 zoo."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -192,6 +193,267 @@ class TestPackRound:
         with pytest.raises(ValueError, match="overflow"):
             pack_round(np.array([[0, 1]]), np.array([[9, 9]]), lambda cid: np.arange(9),
                        4, 1, 0, 0, s_max=5)
+
+
+def _round_builder(module, args, n_dev, slots, batch, max_client_n, stacked=False):
+    """The round program on a client mesh of ``n_dev`` of the CPU's devices,
+    without a simulator's data upload: a bare ``XLASimulator`` with what
+    ``_build_packed_round_fn`` reads."""
+    from fedml_tpu.simulation.xla.algorithms import create_inmesh_algorithm
+
+    sim = XLASimulator.__new__(XLASimulator)
+    sim.args, sim.module, sim.mesh, sim.n_dev = args, module, create_fl_mesh(n_dev), n_dev
+    sim.clients_per_round, sim.batch_size, sim.max_client_n = n_dev * slots, batch, max_client_n
+    sim.needs_stack, sim.sharded_state = stacked, False
+    sim.loss_kind, sim.algo = "ce", create_inmesh_algorithm(args)
+    sim._build_packed_round_fn()
+    return sim
+
+
+def _compiled_and_replayed(module, x_all, y_all, batch, epochs, counts, optimizer="FedAvg",
+                           capture_updates=False, round_idx=2):
+    """One round over ``counts``' schedule, twice: the round program
+    (``_build_packed_round_fn`` on a client mesh of ``len(counts)`` of the
+    CPU's devices) and tests/packed_replay.py's host loop.  Returns (what
+    the program returned, the replay's ``[(cid, n_i, LocalTrainResult)]``,
+    the replay's counters, and what both started from: variables, server
+    state, per-slot client extras, ids2d, the algorithm)."""
+    import types
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from packed_replay import device_keys, replay_clients
+
+    sched, ids2d, counts2d, rows = _packed(batch, epochs, counts, seed=0, round_idx=round_idx)
+    n_dev, slots = counts2d.shape
+    args = _args(federated_optimizer=optimizer, epochs=epochs, batch_size=batch,
+                 client_num_in_total=counts2d.size, client_num_per_round=counts2d.size)
+    sim = _round_builder(module, args, n_dev, slots, batch, int(counts2d.max()), capture_updates)
+    assert (sim.slots, sim.s_max) == (slots, sched.idx.shape[1])
+
+    variables = module.init(jax.random.PRNGKey(3), x_all[:1], train=False)
+    # a server state and client extras that are not zero, so that an
+    # algorithm's hook, contribution and per-slot output all show
+    noise = lambda tree, seed, lead=(): jax.tree_util.tree_map(  # noqa: E731
+        lambda v: 0.05 * jax.random.normal(jax.random.PRNGKey(seed), lead + v.shape), tree)
+    server_state = noise(sim.algo.init_server_state(variables), 4)
+    table = sim.algo.init_client_state(counts2d.size, variables)
+    ids = ids2d.reshape(-1)
+    real = (counts2d.reshape(-1) > 0).astype(np.float32)
+    cex = sim.algo.gather_client_extras(
+        None if table is None else noise(table, 5), ids, real, round_idx)
+
+    split = NamedSharding(sim.mesh, P("client"))
+    got = sim._round_fn(
+        variables, server_state, jnp.asarray(x_all), jnp.asarray(y_all),
+        *(jax.device_put(jnp.asarray(a), split) for a in sched),
+        jax.device_put(device_keys(args, round_idx, n_dev), split), jax.device_put(cex, split))
+
+    by_cid = dict(zip(ids.tolist(), range(len(ids))))
+    host = types.SimpleNamespace(
+        n_dev=n_dev, slots=slots, batch_size=batch, s_max=sim.s_max, x_all=x_all, y_all=y_all,
+        client_counts=np.asarray([counts2d.reshape(-1)[by_cid[c]] for c in range(len(ids))]),
+        _client_rows={cid: np.concatenate([r, np.zeros(3, np.int64)]) for cid, r in rows.items()})
+    extras = None
+    if sim.algo.grad_hook() is not None:
+        extras = {int(c): sim.algo.engine_extra(
+            jax.tree_util.tree_map(lambda t: t[by_cid[c]], cex), server_state) for c in ids}
+    counters = {}
+    clients = replay_clients(host, module, args, ids, real, round_idx, variables,
+                             grad_hook=sim.algo.grad_hook(), extras=extras, counters=counters)
+    return got, clients, counters, (variables, server_state, cex, ids2d, sim)
+
+
+def _lr_data(n_rows=64):
+    from fedml_tpu.models.linear import LogisticRegression
+
+    rng = np.random.default_rng(1)
+    return (LogisticRegression(10), rng.normal(size=(n_rows, 12)).astype(np.float32),
+            rng.integers(0, 10, n_rows).astype(np.int32))
+
+
+def _close(got, want, rtol=2e-5, atol=2e-6):
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=rtol, atol=atol),
+        got, want)
+
+
+class TestCompiledRoundIsTheHostReplay:
+    """The round program's two loops — clients outside, a client's steps
+    inside — against the host replay of the same stream: a client of one
+    step, two epochs, dummy slots and a device with nothing to run."""
+
+    @shapes
+    def test_new_global_state_loss_and_per_slot_outs(self, batch, epochs, counts):
+        """SCAFFOLD reads everything a client's boundary has: the grad hook's
+        extra a step, ``client_contrib``, ``client_out`` and its step count."""
+        got, clients, _, (variables, c, cex, ids2d, sim) = _compiled_and_replayed(
+            *_lr_data(), batch, epochs, counts, optimizer="SCAFFOLD")
+        new_global, new_c, mean_loss, outs = got
+        slot_of = {int(cid): i for i, cid in enumerate(ids2d.reshape(-1))}
+        acc = jax.tree_util.tree_map(jnp.zeros_like, variables)
+        ext = sim.algo.zero_contrib(variables)
+        want_outs = jax.tree_util.tree_map(np.zeros_like, jax.tree_util.tree_map(np.asarray, outs))
+        for cid, n_i, result in clients:
+            cex_i = jax.tree_util.tree_map(lambda t: t[slot_of[cid]], cex)
+            acc = jax.tree_util.tree_map(lambda a, p: a + n_i * p, acc, result.variables)
+            ext = jax.tree_util.tree_map(
+                jnp.add, ext, sim.algo.client_contrib(variables, result, n_i, 1.0, cex_i, c))
+            out_i = sim.algo.client_out(variables, result, 1.0, cex_i, c)
+            for buf, o in zip(jax.tree_util.tree_leaves(want_outs), jax.tree_util.tree_leaves(out_i)):
+                buf[slot_of[cid]] = np.asarray(o)
+        wsum = sum(n_i for _, n_i, _ in clients)
+        assert wsum == float(np.sum(counts))
+        want_global, want_c = sim.algo.server_update(acc, wsum, ext, variables, c)
+        _close(new_global, want_global)
+        _close(new_c, want_c)
+        _close(outs, want_outs)  # a dummy slot's stays zero
+        seen = sum(float(r.seen) for _, _, r in clients)
+        assert seen == float(np.sum(counts)) * epochs
+        np.testing.assert_allclose(
+            float(mean_loss), sum(float(r.loss) * float(r.seen) for _, _, r in clients) / seen,
+            rtol=2e-5)
+        # the walk itself: ceil(n_i / B) steps an epoch, a one-step client among them
+        assert ([float(r.steps) for _, _, r in clients]
+                == [float(-(-int(n) // batch) * epochs) for n in np.ravel(counts) if n])
+
+    @shapes
+    def test_captured_updates_and_tau_of_each_slot(self, batch, epochs, counts):
+        """The defended round's stack: a slot's ``update`` is its client's
+        final variables, ``tau`` its step count, a dummy slot's both zero."""
+        got, clients, _, (variables, _, _, ids2d, sim) = _compiled_and_replayed(
+            *_lr_data(), batch, epochs, counts, capture_updates=True)
+        mean_loss, outs, ext = got
+        slot_of = {int(cid): i for i, cid in enumerate(ids2d.reshape(-1))}
+        want = jax.tree_util.tree_map(np.zeros_like, jax.tree_util.tree_map(np.asarray, outs))
+        for cid, _, result in clients:
+            want["tau"][slot_of[cid]] = float(result.steps)
+            for buf, v in zip(jax.tree_util.tree_leaves(want["update"]),
+                              jax.tree_util.tree_leaves(result.variables)):
+                buf[slot_of[cid]] = np.asarray(v)
+        assert float(np.sum(want["tau"])) == float(sum(-(-n // batch) * epochs for n in np.ravel(counts)))
+        _close(outs, want)
+        assert np.isfinite(float(mean_loss)) and float(ext) == 0.0
+
+    def test_a_device_with_nothing_to_run_adds_nothing(self):
+        """``n_steps == 0``: neither loop runs, the device hands back zeros."""
+        from fedml_tpu.ml.engine.packed import build_packed_device_fn
+        from fedml_tpu.simulation.xla.algorithms import create_inmesh_algorithm
+
+        _, batch, epochs, counts = SHAPES[4]
+        sched = _packed(batch, epochs, counts)[0]
+        d = int(np.flatnonzero(sched.n_steps == 0)[0])
+        module, x_all, y_all = _lr_data()
+        args = _args(epochs=epochs, batch_size=batch)
+        fn = jax.jit(build_packed_device_fn(
+            module, args, create_inmesh_algorithm(args), batch, len(counts[0]), capture_updates=True))
+        variables = module.init(jax.random.PRNGKey(3), x_all[:1], train=False)
+        acc, wsum, lsum, cnt, ext, outs, counters = fn(
+            variables, (), jnp.asarray(x_all), jnp.asarray(y_all), *(a[d] for a in sched),
+            jax.random.PRNGKey(0), jnp.zeros(len(counts[0])))
+        assert (float(wsum), float(lsum), float(cnt), float(ext), counters) == (0.0, 0.0, 0.0, 0.0, {})
+        for leaf in jax.tree_util.tree_leaves((acc, outs)):
+            assert not np.asarray(leaf).any()
+
+    def test_a_modules_round_counters_are_the_replays(self):
+        """The tiny ``kimi_linear`` preset names ``round_counters``: the sums
+        that come out of the round beside the loss are the host loop's."""
+        import os
+
+        import benchmark
+
+        config = os.path.join(os.path.dirname(benchmark.__file__), "configs", "tiny-kimi-linear.json")
+        args = Arguments.from_dict({"model_args": {"model": "kimi_linear", "model_config": config}})
+        module = fedml_tpu.models.create(args.validate(for_training=False), 64)
+        rng = np.random.default_rng(3)
+        x_all, y_all = (rng.integers(0, 64, (12, 24)).astype(np.int32) for _ in range(2))
+        _, batch, epochs, counts = SHAPES[0]
+        got, clients, counters, (variables, *_) = _compiled_and_replayed(
+            module, x_all, y_all, batch, epochs, counts)
+        new_global, _, mean_loss, _, counted = got
+        assert set(counted) == set(module.round_counters) == set(counters)
+        steps = int(np.sum(counts))  # batch 1, one epoch
+        assert float(counted["moe.assignments_total"]) == counters["moe.assignments_total"]
+        assert counters["moe.assignments_total"] % steps == 0 and counters["moe.assignments_total"] > 0
+        assert float(counted["moe.assignments_dropped"]) == counters["moe.assignments_dropped"] == 0.0
+        # where the assignments went is the router's: a token whose scores part
+        # by less than the two programs' rounding changes expert (one in a
+        # thousand here on most seeds), and its expert's update with it
+        for name in module.round_counters:
+            np.testing.assert_allclose(float(counted[name]), counters[name], rtol=1e-2)
+        wsum = sum(n_i for _, n_i, _ in clients)
+        want = jax.tree_util.tree_map(
+            lambda *ps: sum(n_i * p for (_, n_i, _), p in zip(clients, ps)) / wsum,
+            *(r.variables for _, _, r in clients))
+
+        def distance(a, b):
+            return float(np.sqrt(sum(np.sum((np.asarray(x) - np.asarray(y)) ** 2) for x, y in zip(
+                jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))))
+
+        assert distance(new_global, want) < 0.1 * distance(variables, want)
+        seen = sum(float(r.seen) for _, _, r in clients)
+        np.testing.assert_allclose(
+            float(mean_loss), sum(float(r.loss) * float(r.seen) for _, _, r in clients) / seen,
+            rtol=1e-3)
+
+    def test_the_lowered_round_is_two_nested_loops_and_no_branch(self):
+        """No ``lax.cond`` in the stream: a step that ends no client moves
+        nothing but its own work.  The three scopes still name operations."""
+        _, batch, epochs, counts = SHAPES[1]
+        sched = _packed(batch, epochs, counts)[0]
+        module, x_all, y_all = _lr_data()
+        sim = _round_builder(module, _args(epochs=epochs, batch_size=batch), 2, 3, batch, 9)
+        variables = module.init(jax.random.PRNGKey(3), x_all[:1], train=False)
+        lowered = sim._round_fn.lower(
+            variables, (), jnp.asarray(x_all), jnp.asarray(y_all), *(jnp.asarray(a) for a in sched),
+            jax.random.split(jax.random.PRNGKey(0), 2), jnp.zeros(6))
+        text = lowered.as_text()
+        assert "stablehlo.if" not in text and "stablehlo.case" not in text
+        # the round itself (threefry's rounds are a loop in a function of their own)
+        main = text[text.index("func.func public @main"):].split("func.func private")[0]
+        at = [i for i in range(len(main)) if main.startswith("stablehlo.while", i)]
+        assert len(at) == 2
+        depth = [main[:i].count("{") - main[:i].count("}") for i in at]
+        assert depth[1] > depth[0]  # the second opens inside the first's body
+        named = lowered.as_text(debug_info=True)
+        for scope in ("fed.flush", "fed.gather", "fed.local_step"):
+            assert scope in named, scope
+
+
+class TestStreamCounters:
+    def test_round_log_and_registry_carry_the_schedules_sums(self, monkeypatch):
+        """``round.local_steps`` and ``round.client_boundaries``: how often
+        the inner loop runs and how often it ends a client, from the schedule
+        the host has just packed."""
+        from fedml_tpu.core import obs
+        from fedml_tpu.simulation.xla import fed_sim
+
+        packed = []
+
+        def spy(*a, **k):
+            packed.append(pack_round(*a, **k))
+            return packed[-1]
+
+        monkeypatch.setattr(fed_sim, "pack_round", spy)
+
+        def totals():
+            return {r["metric"]: r["value"] for r in obs.registry().export()
+                    if r["metric"] in ("round.local_steps", "round.client_boundaries")}
+
+        args, dataset, model = _build(_args(comm_round=2))
+        sim = XLASimulator(args, dataset, model)
+        before = totals()
+        sim.train()
+        assert len(packed) == len(sim.round_log) == 2
+        for rec, sched in zip(sim.round_log, packed):
+            assert rec["round.local_steps"] == float(sched.n_steps.sum()) > 0
+            live = np.arange(sched.boundary.shape[1])[None, :] < sched.n_steps[:, None]
+            assert rec["round.client_boundaries"] == float(sched.boundary[live].sum())
+            assert 0 < rec["round.client_boundaries"] <= int(args.client_num_per_round)
+            assert rec["round.client_boundaries"] < rec["round.local_steps"]  # two epochs
+        for name, total in totals().items():
+            assert total - before.get(name, 0.0) == sum(rec[name] for rec in sim.round_log)
+        assert set(totals()) == {"round.local_steps", "round.client_boundaries"}
 
 
 @heavy
