@@ -210,7 +210,7 @@ def kimi_linear_ops():
     import jax
     import jax.numpy as jnp
 
-    from fedml_tpu.models import kimi_linear
+    from fedml_tpu.models import expert_lm
     from fedml_tpu.ops.flash_attention import flash_attention, reference_attention
 
     L, H, errors = KIMI["L"], KIMI["H"], {}
@@ -248,18 +248,19 @@ def kimi_linear_ops():
                     for key in keys[1:3])
     w_down = jax.random.normal(keys[3], (held, f, d), jnp.bfloat16) * f ** -0.5
     scores = jax.nn.sigmoid(jax.random.normal(keys[4], (L, KIMI["routed"]), jnp.float32))
-    chosen, weights = kimi_linear.route(scores, jnp.zeros(KIMI["routed"]), KIMI["top"], 2.446, True)
+    chosen, weights = expert_lm.route(scores, jnp.zeros(KIMI["routed"]), KIMI["top"], 2.446, True)
     cot = jax.random.normal(keys[5], (L, d), jnp.float32)
 
     def grouped(h, w_gate, w_up, w_down):
-        return kimi_linear.grouped_experts(h, chosen, weights, (0, held), w_gate, w_up, w_down)[0]
+        return expert_lm.grouped_experts(h, chosen, weights, (0, held), w_gate, w_up, w_down,
+                                         KIMI["routed"])[0]
 
     def dense(h, w_gate, w_up, w_down):
         h, w_gate, w_up, w_down = (x.astype(jnp.float32) for x in (h, w_gate, w_up, w_down))
         out = jnp.zeros_like(h)
         for e in range(held):
             weight = jnp.sum(jnp.where(chosen == e, weights, 0.0), -1)
-            out = out + weight[:, None] * kimi_linear.swiglu(h, w_gate[e], w_up[e], w_down[e])
+            out = out + weight[:, None] * expert_lm.swiglu(h, w_gate[e], w_up[e], w_down[e])
         return out
 
     def value_and_grads(fn):
@@ -276,7 +277,8 @@ def kimi_linear_ops():
         errors["fwd_grouped_experts"] = _rel_err(out, jax.jit(dense)(h, w_gate, w_up, w_down))
     for name, g_, e in zip(("h", "w_gate", "w_up", "w_down"), got, exp):
         errors[f"d{name}_grouped_experts"] = _rel_err(g_, e)
-    counters = kimi_linear.grouped_experts(h, chosen, weights, (0, held), w_gate, w_up, w_down)[1]
+    counters = expert_lm.grouped_experts(h, chosen, weights, (0, held), w_gate, w_up, w_down,
+                                         KIMI["routed"])[1]
     _check(float(counters["moe.assignments_dropped"]) == 0.0, f"assignments dropped: {counters}")
     return errors
 

@@ -1,0 +1,297 @@
+"""What the config-driven sparse decoders share (``kimi_linear.py``,
+``smallthinker.py``): the expert layer — routing, the grouped products over the
+experts held here, the round's counters — RMSNorm, the dense gated MLP, the
+initialiser and the LM shell (embedding -> blocks under per-layer ``remat`` ->
+final RMSNorm -> untied head).
+
+A model's config dataclass gives the shared parts these fields:
+``hidden_size``, ``num_hidden_layers``, ``vocab_size``, ``rms_norm_eps``,
+``moe_intermediate_size``, ``n_routed_experts``, ``experts_held`` ([lo, hi): the
+experts this process holds), ``num_experts_per_token``, ``num_shared_experts``,
+``dtype``, ``remat``; a model whose expert layer routes on its own input
+(``kimi_linear``: sigmoid scores + correction bias) also gives
+``routed_scaling_factor`` and ``moe_renormalize``.
+
+Expert layer (:class:`ExpertShare`): the router scores all
+``n_routed_experts`` in float32; this process adds the part of the experts it
+holds only (beside a shared expert where the model has one).  No assignment is
+dropped: the assignments that land here are sorted by expert and worked off in
+blocks through grouped products (``jax.lax.ragged_dot``); the number of blocks
+steps up with theirs (:func:`grouped_experts`, :func:`expert_blocks`).  When
+applied with the ``counters`` collection mutable and ``train=True``, every
+expert layer sows the round's counters (``COUNTERS``) there; the packed round
+sums them.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+from typing import Any, Callable, ClassVar, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+# what an expert layer sows a step; the packed round returns their sums
+COUNTERS = ("moe.assignments_local", "moe.assignments_total", "moe.expert_load_max",
+            "moe.expert_load_mean", "moe.assignments_dropped")
+
+
+def load_config(model_config) -> dict:
+    """``model_config`` as ``arguments.py`` validates it: a dict, or the path
+    of a JSON file that holds one."""
+    if isinstance(model_config, (str, os.PathLike)):
+        with open(model_config) as f:
+            return json.load(f)
+    return dict(model_config)
+
+
+def compute_dtype(cfg: dict):
+    return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
+        cfg.get("compute_dtype", "float32")]
+
+
+def held_range(cfg: dict, total: int) -> Tuple[int, int]:
+    """``experts_held`` of a configuration, checked: a range [lo, hi) inside the
+    router's ``total`` outputs (default: all of them)."""
+    held = tuple(int(e) for e in cfg.get("experts_held", (0, total)))
+    if not (len(held) == 2 and 0 <= held[0] < held[1] <= total):
+        raise ValueError(f"experts_held must be a range [lo, hi) inside 0..{total}: {held}")
+    return held
+
+
+def _normal(fan_in: int):
+    return nn.initializers.normal(stddev=fan_in ** -0.5)
+
+
+def rms_norm(x, scale, eps):
+    """Float32 statistics, the input's dtype out."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+class DenseMLP(nn.Module):
+    cfg: Any
+    width: int
+
+    @nn.compact
+    def __call__(self, h):
+        d, f, dt = self.cfg.hidden_size, self.width, self.cfg.dtype
+        w = {n: self.param(n, _normal(fi), s, jnp.float32).astype(dt) for n, s, fi in (
+            ("w_gate", (d, f), d), ("w_up", (d, f), d), ("w_down", (f, d), f))}
+        return swiglu(h, w["w_gate"], w["w_up"], w["w_down"])
+
+
+def route(scores, bias, top_k: int, scaling: float = 1.0, renormalize: bool = False,
+          softmax_chosen: bool = False):
+    """scores: [T, E] float32.  The top ``top_k`` experts of score + bias per
+    token (``bias`` None: of the score), and their weights.  Two forms:
+    sigmoid scores in, the chosen scores (without the bias) out, renormalised
+    over ALL chosen where asked, times ``scaling``; or, ``softmax_chosen``, the
+    router's logits in and the softmax over the chosen logits out (they sum
+    to 1)."""
+    _, chosen = jax.lax.top_k(scores if bias is None else scores + bias, top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    if softmax_chosen:
+        return chosen, jax.nn.softmax(picked, axis=-1)
+    if renormalize:
+        picked = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
+    return chosen, picked * scaling
+
+
+EXPERT_BLOCKS = 8  # a block of the expert layer holds at least an eighth of a step's assignments
+
+
+def expert_blocks(total: int, held: int, routed: int) -> Tuple[int, Tuple[int, ...], bool]:
+    """(rows a block, blocks each tier runs, whether a tier's work is padded to
+    its blocks) of an expert layer that holds ``held`` of ``routed`` experts and
+    sees ``total`` assignments a step.  The even share of the assignments that
+    land here is ``held / routed`` of them.
+
+    A block is the larger of an eighth of the assignments and three times the
+    even share; as many blocks run as hold every assignment that landed here,
+    in tiers of 1, 2 and all of them, so the even share lies at a third of the
+    first tier or below and the last tier holds whatever the router does.  8 of
+    256: blocks of T k / 8 (four times the even share) in tiers of 1 / 2 / 8; 16
+    of 64: blocks of 3 T k / 4 in tiers of 1 / 2.  Why three times: under
+    random routers a layer's share is far from even (16 of 64, 16 seeds on the
+    v5e, PR 31: a layer's mean share 10-47 %, a sequence's up to 59 %, over 50 %
+    in 2 of the 16 seeds, never over 75 %), and a layer-step that leaves the
+    first tier runs a second block: with blocks of twice the even share those
+    two seeds' rounds took 1.0 and 2.8 % longer than the others'.
+
+    Where the share sets the block, a tier's grouped products are padded to its
+    rows (``padded``): a grouped product's time goes by the rows that belong to a
+    group, not by its operand's rows, so without the padding a layer's time
+    follows its router — at 16 of 64 the seeds' shares of 20-30 % were rounds of
+    13.0-13.5 s, a spread of 1.6 % (v5e, PR 31) — and with it every layer-step
+    inside a tier is the same work.  Where the floor of an eighth sets it, a
+    block is four or more times the even share: padding would multiply the
+    layer's work to even out a difference that is small beside it."""
+    floor = -(-total // EXPERT_BLOCKS)
+    base = min(total, max(floor, -(-3 * total * held // routed)))
+    n_blocks = -(-total // base)
+    return base, tuple(sorted({1, min(2, n_blocks), n_blocks})), base > floor
+
+
+def grouped_experts(h, chosen, weights, held: Tuple[int, int], w_gate, w_up, w_down,
+                    n_routed: int, activation: Callable = jax.nn.silu):
+    """Sum over the chosen experts that are held here of weight x gated expert
+    (``activation(x W_gate) * (x W_up)) W_down``).  h: [T, d]; chosen, weights:
+    [T, k]; w_*: [E_held, ...]; ``n_routed``: the router's width.  Returns
+    ([T, d], counters).
+
+    The T*k assignments are sorted by expert, those of absent experts last.
+    The local ones are then worked off in blocks (gather the tokens, three
+    grouped products over the block's rows of each expert, weight, scatter
+    back), each block recomputed on the way back; as many blocks run as hold
+    every local assignment, in the tiers :func:`expert_blocks` gives for the
+    share held (``lax.switch``: a loop with a traced trip count has no reverse
+    mode, and a ``lax.cond`` a block inside one ``lax.scan`` kept 2.5 GiB more
+    live).  So nothing is dropped whatever the routing, work steps up with what
+    lands here, and memory is a block's.  Why a block is no smaller: with
+    blocks of a sixteenth at 8 of 256 experts, seeds whose router sent one
+    layer more than 6.25 % of its assignments here ran two blocks there and
+    their rounds took 1.6 % longer than the others' (v5e, PR 27); and a block
+    costs about 6 ms on the way back whatever its rows (the scatter-add that
+    transposes its gather; v5e, PR 31)."""
+    T, k = chosen.shape
+    d = h.shape[-1]
+    lo, hi = held
+    n_held, total = hi - lo, T * k
+    base, tiers, padded = expert_blocks(total, n_held, n_routed)
+    n_blocks = tiers[-1]
+    flat = chosen.reshape(-1)
+    with jax.named_scope("lm.moe.dispatch"):
+        local = (flat >= lo) & (flat < hi)
+        key = jnp.where(local, flat - lo, n_held)
+        order = jnp.pad(jnp.argsort(key, stable=True), (0, n_blocks * base - total))
+        sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
+        ends = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
+        n_local = ends[-1]
+        tier = jnp.sum(n_local > base * jnp.asarray(tiers[:-1], jnp.int32))
+        if padded:
+            # the tier's other rows join the last expert's group: zeros in, zeros
+            # out and no gradient, and the products' work is the tier's rows
+            ends = ends.at[-1].set(base * jnp.asarray(tiers, jnp.int32)[tier])
+    flat_weights = weights.reshape(-1)
+
+    @jax.checkpoint
+    def one_block(i):
+        with jax.named_scope("lm.moe.dispatch"):
+            start = i * base
+            rows = jax.lax.dynamic_slice(order, (start,), (base,))
+            token = rows // k
+            live = start + jnp.arange(base) < n_local
+            inside = jnp.clip(ends, start, start + base)
+            block_sizes = inside[1:] - inside[:-1]  # this block's rows of each expert
+            # rows past the local assignments belong to no expert: what a grouped
+            # product leaves in rows outside its groups is undefined, forward and
+            # backward, so they are cut off on the way in as on the way out
+            x = jnp.where(live[:, None], h[token], 0)
+        with jax.named_scope("lm.moe.experts"):
+            gate = jax.lax.ragged_dot(x, w_gate, block_sizes)
+            up = jax.lax.ragged_dot(x, w_up, block_sizes)
+            y = jax.lax.ragged_dot(activation(gate) * up, w_down, block_sizes)
+        with jax.named_scope("lm.moe.combine"):
+            w = jnp.where(live, flat_weights[rows], 0.0).astype(y.dtype)
+            return token, jnp.where(live[:, None], y * w[:, None], 0.0)
+
+    def run(blocks):
+        def branch():
+            token, y = jax.lax.map(one_block, jnp.arange(blocks))
+            with jax.named_scope("lm.moe.combine"):
+                return jnp.zeros_like(h).at[token.reshape(-1)].add(y.reshape(-1, d))
+        return branch
+
+    out = jax.lax.switch(tier, [run(b) for b in tiers])
+    processed = jnp.minimum(n_local, base * jnp.asarray(tiers, jnp.int32)[tier])
+    counters = {
+        "moe.assignments_local": n_local, "moe.assignments_total": total,
+        "moe.expert_load_max": jnp.max(sizes), "moe.expert_load_mean": n_local / n_held,
+        "moe.assignments_dropped": n_local - processed}
+    return out, {n: jnp.asarray(v, jnp.float32) for n, v in counters.items()}
+
+
+class ExpertShare(nn.Module):
+    """The part of an expert layer that the experts held here give (plus the
+    shared expert, which every process computes alike).  ``routing``: the
+    (chosen, weights) a block decided elsewhere (``smallthinker``: before the
+    attention, on the attention's input); left ``None`` the layer routes on
+    the tensor it transforms, by sigmoid scores and a correction bias."""
+    cfg: Any
+    activation: Callable = jax.nn.silu
+
+    @nn.compact
+    def __call__(self, h, train: bool = False, routing=None):
+        from ..core import obs
+
+        cfg = self.cfg
+        d, f, dt = cfg.hidden_size, cfg.moe_intermediate_size, cfg.dtype
+        lo, hi = cfg.experts_held
+        obs.gauge_set("moe.experts_held", hi - lo)
+        obs.gauge_set("moe.experts_total", cfg.n_routed_experts)
+        flat = h.reshape(-1, d)
+        if routing is None:
+            with jax.named_scope("lm.moe.route"):
+                w_r = self.param("router", _normal(d), (d, cfg.n_routed_experts), jnp.float32)
+                bias = self.param("router_bias", nn.initializers.zeros,
+                                  (cfg.n_routed_experts,), jnp.float32)
+                scores = jax.nn.sigmoid(jnp.matmul(
+                    flat.astype(jnp.float32), w_r, precision=jax.lax.Precision.HIGHEST))
+                routing = route(scores, bias, cfg.num_experts_per_token,
+                                cfg.routed_scaling_factor, cfg.moe_renormalize)
+        chosen, weights = routing
+        experts = {n: self.param(n, _normal(fi), (hi - lo,) + s, jnp.float32).astype(dt)
+                   for n, s, fi in (("e_gate", (d, f), d), ("e_up", (d, f), d),
+                                    ("e_down", (f, d), f))}
+        out, counters = grouped_experts(
+            flat, chosen, weights, (lo, hi), experts["e_gate"], experts["e_up"],
+            experts["e_down"], cfg.n_routed_experts, self.activation)
+        if train:
+            for name, value in counters.items():
+                self.sow("counters", name, value, reduce_fn=jnp.add,
+                         init_fn=lambda: jnp.zeros((), jnp.float32))
+        if cfg.num_shared_experts:
+            with jax.named_scope("lm.moe.shared"):
+                out = out + DenseMLP(cfg, f * cfg.num_shared_experts, name="shared")(flat)
+        return out.reshape(h.shape)
+
+
+class DecoderLM(nn.Module):
+    """The LM shell: token embedding, ``cfg.num_hidden_layers`` blocks
+    (``block_cls(cfg, index, name="layer<i>")(x, train)``, each recomputed in
+    the backward pass where ``cfg.remat``), final RMSNorm, untied head.  A
+    model subclasses it and names its block."""
+    cfg: Any
+    # the packed round asks for these sums beside the loss (ml/engine/packed.py)
+    round_counters: Tuple[str, ...] = COUNTERS
+
+    block_cls: ClassVar[Any] = None
+    # no parameter's shape depends on the length: an eager ``init`` at a round's
+    # 8,192 or 16,384 tokens would run (and compile, op by op) the whole forward
+    # pass for shapes alone, so ``init`` looks at this many tokens
+    init_length: ClassVar[int] = 64
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False):
+        cfg = self.cfg
+        if self.is_initializing():
+            tokens = tokens[:, :self.init_length]
+        embed = self.param("embed", _normal(cfg.hidden_size),
+                           (cfg.vocab_size, cfg.hidden_size), jnp.float32)
+        x = embed.astype(cfg.dtype)[tokens]
+        block_cls = (nn.remat(self.block_cls, static_argnums=(2,)) if cfg.remat
+                     else self.block_cls)
+        for i in range(cfg.num_hidden_layers):
+            x = block_cls(cfg, i, name=f"layer{i}")(x, train)
+        scale = self.param("final_norm", nn.initializers.ones, (cfg.hidden_size,), jnp.float32)
+        head = self.param("head", _normal(cfg.hidden_size),
+                          (cfg.hidden_size, cfg.vocab_size), jnp.float32)
+        return rms_norm(x, scale, cfg.rms_norm_eps) @ head.astype(cfg.dtype)

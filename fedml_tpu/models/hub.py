@@ -18,8 +18,9 @@ logger = logging.getLogger(__name__)
 
 _BF16_MODELS = {"resnet20", "resnet56", "resnet18", "resnet18_gn"}
 # built from ``args.model_config`` (a published config.json's keys), which
-# states its own compute dtype
-_CONFIG_MODELS = {"kimi_linear"}
+# states its own compute dtype.  The module ``models/<name>.py`` has the
+# classes ``<prefix>Config`` (with ``from_dict``) and ``<prefix>LM``
+_CONFIG_MODELS = {"kimi_linear": "KimiLinear", "smallthinker": "SmallThinker"}
 
 
 def create(args: Any, output_dim: int) -> nn.Module:
@@ -29,13 +30,17 @@ def create(args: Any, output_dim: int) -> nn.Module:
     import jax.numpy as jnp
 
     if name in _CONFIG_MODELS:
-        from .kimi_linear import KimiLinearConfig, KimiLinearLM, load_config
+        import importlib
+
+        from .expert_lm import load_config
 
         config = getattr(args, "model_config", None)
         if config is None:
             raise ValueError(f"model {name!r} is built from model_config (a dict or a JSON "
                              "file with the published config.json's keys); none was given")
-        return KimiLinearLM(KimiLinearConfig.from_dict(load_config(config)))
+        module, prefix = importlib.import_module(f"{__package__}.{name}"), _CONFIG_MODELS[name]
+        return getattr(module, prefix + "LM")(
+            getattr(module, prefix + "Config").from_dict(load_config(config)))
     if _dtype(args) is not jnp.float32 and name not in _BF16_MODELS:
         logger.warning(
             "compute_dtype=%s is only plumbed into %s; model %r runs fp32",

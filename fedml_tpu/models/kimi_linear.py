@@ -17,26 +17,24 @@ FFN(RMSNorm(x))``; final RMSNorm; untied output head.
   key part that is NOT rotated, full-rank queries; q and k of width
   ``qk_nope_head_dim + qk_rope_head_dim`` and v of ``v_head_dim`` go through
   ``ops.flash_attention.attention``.
-* Expert layer (:class:`ExpertShare`): sigmoid scores over all
+* Expert layer (``expert_lm.ExpertShare``): sigmoid scores over all
   ``n_routed_experts`` in float32, the top ``num_experts_per_token`` of score +
   correction bias, weights renormalised over all chosen and scaled by
   ``routed_scaling_factor``; this process holds the experts ``experts_held =
   [lo, hi)`` and adds their part only, beside the shared expert.  No
   assignment is dropped: the assignments that land here are sorted by expert
   and worked off in blocks through grouped products (``jax.lax.ragged_dot``);
-  the number of blocks steps up with theirs (:func:`grouped_experts`).
+  the number of blocks steps up with theirs (``expert_lm.grouped_experts``).
 
 Activations and matrix products run in ``compute_dtype``; parameters, router
 scores, gates and the KDA state are float32.  When applied with the
 ``counters`` collection mutable and ``train=True``, every expert layer sows
-the round's counters (``COUNTERS``) there; the packed round sums them.
+the round's counters (``expert_lm.COUNTERS``) there; the packed round sums them.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import json
-import os
 from typing import Any, Tuple
 
 import flax.linen as nn
@@ -44,11 +42,10 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import kda as kda_ops
-
-# what an expert layer sows a step; the packed round returns their sums
-COUNTERS = ("moe.assignments_local", "moe.assignments_total", "moe.expert_load_max",
-            "moe.expert_load_mean", "moe.assignments_dropped")
-
+# the expert layer, the norm, the dense MLP and the LM shell are shared with
+# ``smallthinker.py``
+from .expert_lm import (DecoderLM, DenseMLP, ExpertShare, _normal, compute_dtype, held_range,
+                        rms_norm)
 
 @dataclasses.dataclass(frozen=True)
 class KimiLinearConfig:
@@ -101,11 +98,8 @@ class KimiLinearConfig:
             raise NotImplementedError(f"kimi_linear: no code for the given {bad}")
         lin = cfg["linear_attn_config"]
         total = int(cfg.get("n_routed_experts", cfg["num_experts"]))
-        held = tuple(int(e) for e in cfg.get("experts_held", (0, total)))
-        if not (len(held) == 2 and 0 <= held[0] < held[1] <= total):
-            raise ValueError(f"experts_held must be a range [lo, hi) inside 0..{total}: {held}")
-        dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
-            cfg.get("compute_dtype", "float32")]
+        held = held_range(cfg, total)
+        dtype = compute_dtype(cfg)
         return cls(
             hidden_size=int(cfg["hidden_size"]),
             num_hidden_layers=int(cfg["num_hidden_layers"]),
@@ -127,26 +121,6 @@ class KimiLinearConfig:
             routed_scaling_factor=float(cfg["routed_scaling_factor"]),
             moe_renormalize=bool(cfg["moe_renormalize"]),
             dtype=dtype, remat=bool(cfg.get("remat", False)))
-
-
-def load_config(model_config) -> dict:
-    """``model_config`` as ``arguments.py`` validates it: a dict, or the path
-    of a JSON file that holds one."""
-    if isinstance(model_config, (str, os.PathLike)):
-        with open(model_config) as f:
-            return json.load(f)
-    return dict(model_config)
-
-
-def _normal(fan_in: int):
-    return nn.initializers.normal(stddev=fan_in ** -0.5)
-
-
-def rms_norm(x, scale, eps):
-    """Float32 statistics, the input's dtype out."""
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
 def causal_conv(x, w):
@@ -224,141 +198,6 @@ class MLAMixer(nn.Module):
         return jnp.einsum("blhk,hkd->bld", o, param("wo", (H, dv, d), H * dv))
 
 
-def swiglu(x, w_gate, w_up, w_down):
-    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
-
-
-class DenseMLP(nn.Module):
-    cfg: KimiLinearConfig
-    width: int
-
-    @nn.compact
-    def __call__(self, h):
-        d, f, dt = self.cfg.hidden_size, self.width, self.cfg.dtype
-        w = {n: self.param(n, _normal(fi), s, jnp.float32).astype(dt) for n, s, fi in (
-            ("w_gate", (d, f), d), ("w_up", (d, f), d), ("w_down", (f, d), f))}
-        return swiglu(h, w["w_gate"], w["w_up"], w["w_down"])
-
-
-def route(scores, bias, top_k: int, scaling: float, renormalize: bool):
-    """scores: [T, E] sigmoid scores (float32).  The top ``top_k`` experts of
-    score + bias per token, and their weights: the chosen scores (without the
-    bias), renormalised over ALL chosen, times ``scaling``."""
-    _, chosen = jax.lax.top_k(scores + bias, top_k)
-    picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    if renormalize:
-        picked = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
-    return chosen, picked * scaling
-
-
-def grouped_experts(h, chosen, weights, held: Tuple[int, int], w_gate, w_up, w_down):
-    """Sum over the chosen experts that are held here of weight x SwiGLU
-    expert.  h: [T, d]; chosen, weights: [T, k]; w_*: [E_held, ...].  Returns
-    ([T, d], counters).
-
-    The T*k assignments are sorted by expert, those of absent experts last.
-    The local ones are then worked off in blocks of an eighth of T*k rows
-    (gather the tokens, three grouped products over the block's rows of each
-    expert, weight, scatter back), each block recomputed on the way back; as
-    many blocks run as hold every local assignment, in steps of 1, 2 and 8
-    (``lax.switch``: a loop with a traced trip count has no reverse mode, and
-    a ``lax.cond`` a block inside one ``lax.scan`` kept 2.5 GiB more live).
-    So nothing is dropped whatever the routing, work steps up with what lands
-    here, and memory is a block's.  One block holds four times the even share
-    of 8 of 256 experts: with blocks of a sixteenth, seeds whose router sent
-    one layer more than 6.25 % of its assignments here ran two blocks there
-    and their rounds took 1.6 % longer than the others' (v5e, PR 27)."""
-    T, k = chosen.shape
-    d = h.shape[-1]
-    lo, hi = held
-    n_held, total = hi - lo, T * k
-    base = -(-total // 8)
-    n_blocks = -(-total // base)
-    tiers = sorted({1, min(2, n_blocks), n_blocks})
-    flat = chosen.reshape(-1)
-    with jax.named_scope("lm.moe.dispatch"):
-        local = (flat >= lo) & (flat < hi)
-        key = jnp.where(local, flat - lo, n_held)
-        order = jnp.pad(jnp.argsort(key, stable=True), (0, n_blocks * base - total))
-        sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
-        ends = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
-        n_local = ends[-1]
-        tier = jnp.sum(n_local > base * jnp.asarray(tiers[:-1], jnp.int32))
-    flat_weights = weights.reshape(-1)
-
-    @jax.checkpoint
-    def one_block(i):
-        with jax.named_scope("lm.moe.dispatch"):
-            start = i * base
-            rows = jax.lax.dynamic_slice(order, (start,), (base,))
-            token = rows // k
-            live = start + jnp.arange(base) < n_local
-            inside = jnp.clip(ends, start, start + base)
-            block_sizes = inside[1:] - inside[:-1]  # this block's rows of each expert
-            # rows past the local assignments belong to no group: what a grouped
-            # product leaves in them is undefined, forward and backward, so they
-            # are cut off on the way in as on the way out
-            x = jnp.where(live[:, None], h[token], 0)
-        with jax.named_scope("lm.moe.experts"):
-            gate = jax.lax.ragged_dot(x, w_gate, block_sizes)
-            up = jax.lax.ragged_dot(x, w_up, block_sizes)
-            y = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, block_sizes)
-        with jax.named_scope("lm.moe.combine"):
-            w = jnp.where(live, flat_weights[rows], 0.0).astype(y.dtype)
-            return token, jnp.where(live[:, None], y * w[:, None], 0.0)
-
-    def run(blocks):
-        def branch():
-            token, y = jax.lax.map(one_block, jnp.arange(blocks))
-            with jax.named_scope("lm.moe.combine"):
-                return jnp.zeros_like(h).at[token.reshape(-1)].add(y.reshape(-1, d))
-        return branch
-
-    out = jax.lax.switch(tier, [run(b) for b in tiers])
-    processed = jnp.minimum(n_local, base * jnp.asarray(tiers, jnp.int32)[tier])
-    counters = {
-        "moe.assignments_local": n_local, "moe.assignments_total": total,
-        "moe.expert_load_max": jnp.max(sizes), "moe.expert_load_mean": n_local / n_held,
-        "moe.assignments_dropped": n_local - processed}
-    return out, {n: jnp.asarray(v, jnp.float32) for n, v in counters.items()}
-
-
-class ExpertShare(nn.Module):
-    cfg: KimiLinearConfig
-
-    @nn.compact
-    def __call__(self, h, train: bool = False):
-        from ..core import obs
-
-        cfg = self.cfg
-        d, f, dt = cfg.hidden_size, cfg.moe_intermediate_size, cfg.dtype
-        lo, hi = cfg.experts_held
-        obs.gauge_set("moe.experts_held", hi - lo)
-        obs.gauge_set("moe.experts_total", cfg.n_routed_experts)
-        flat = h.reshape(-1, d)
-        with jax.named_scope("lm.moe.route"):
-            w_r = self.param("router", _normal(d), (d, cfg.n_routed_experts), jnp.float32)
-            bias = self.param("router_bias", nn.initializers.zeros,
-                              (cfg.n_routed_experts,), jnp.float32)
-            scores = jax.nn.sigmoid(jnp.matmul(
-                flat.astype(jnp.float32), w_r, precision=jax.lax.Precision.HIGHEST))
-            chosen, weights = route(scores, bias, cfg.num_experts_per_token,
-                                    cfg.routed_scaling_factor, cfg.moe_renormalize)
-        experts = {n: self.param(n, _normal(fi), (hi - lo,) + s, jnp.float32).astype(dt)
-                   for n, s, fi in (("e_gate", (d, f), d), ("e_up", (d, f), d),
-                                    ("e_down", (f, d), f))}
-        out, counters = grouped_experts(flat, chosen, weights, (lo, hi), experts["e_gate"],
-                                        experts["e_up"], experts["e_down"])
-        if train:
-            for name, value in counters.items():
-                self.sow("counters", name, value, reduce_fn=jnp.add,
-                         init_fn=lambda: jnp.zeros((), jnp.float32))
-        with jax.named_scope("lm.moe.shared"):
-            if cfg.num_shared_experts:
-                out = out + DenseMLP(cfg, f * cfg.num_shared_experts, name="shared")(flat)
-        return out.reshape(h.shape)
-
-
 class Block(nn.Module):
     cfg: KimiLinearConfig
     index: int  # 0-based
@@ -384,26 +223,7 @@ class Block(nn.Module):
         return x + ExpertShare(cfg, name="moe")(norm("ffn_norm"), train)
 
 
-class KimiLinearLM(nn.Module):
+class KimiLinearLM(DecoderLM):
     cfg: KimiLinearConfig
-    # the packed round asks for these sums beside the loss (ml/engine/packed.py)
-    round_counters: Tuple[str, ...] = COUNTERS
-
-    @nn.compact
-    def __call__(self, tokens, train: bool = False):
-        cfg = self.cfg
-        if self.is_initializing():
-            # no parameter's shape depends on the length: an eager ``init`` at a
-            # round's 8,192 tokens would run (and compile, op by op) the whole
-            # forward pass for shapes alone
-            tokens = tokens[:, :kda_ops.CHUNK]
-        embed = self.param("embed", _normal(cfg.hidden_size),
-                           (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        x = embed.astype(cfg.dtype)[tokens]
-        block_cls = nn.remat(Block, static_argnums=(2,)) if cfg.remat else Block
-        for i in range(cfg.num_hidden_layers):
-            x = block_cls(cfg, i, name=f"layer{i}")(x, train)
-        scale = self.param("final_norm", nn.initializers.ones, (cfg.hidden_size,), jnp.float32)
-        head = self.param("head", _normal(cfg.hidden_size),
-                          (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-        return rms_norm(x, scale, cfg.rms_norm_eps) @ head.astype(cfg.dtype)
+    block_cls = Block
+    init_length = kda_ops.CHUNK

@@ -31,13 +31,25 @@ variable or run-time autotune.  What was chosen is left as the gauges
 name (docs/OBSERVABILITY.md).
 
 Dead tiles cost a step and no copy.  Under the causal mask a tile whose keys
-all lie after its queries (and any tile of padding) is dead: its step still
+all lie after its queries (and any tile of padding) is dead, and under a
+``window`` (query ``t`` sees the keys ``s`` with ``0 <= t - s < window``) so is
+one whose keys all lie before the window of all its queries: its step still
 runs — the grid is rectangular and no scalar-prefetched schedule is added —
-but computes nothing, and its BlockSpec index is clamped to the last live
-tile of the row (the first of the column in the keys-major pass), which is
-the block already in VMEM, so the pipeline issues no copy for it.  A live
-tile that the diagonal and the padded tail do not cross is all live and takes
-an unmasked path (no iotas, compare or select); only the others build a mask.
+but computes nothing, and its BlockSpec index is clamped into the live tiles
+of the row (of the column in the keys-major pass), which names the block
+already in VMEM, or the first live one, which it prefetches, so the pipeline
+issues no copy for it.  A live tile that the diagonal, the window's far edge
+and the padded tail do not cross is all live and takes an unmasked path (no
+iotas, compare or select); only the others build a mask.
+
+Grouped kv heads.  k and v may have fewer heads than q (``Hq % Hkv == 0``;
+query head ``h`` reads kv head ``h // (Hq // Hkv)``).  They are never repeated
+in HBM: the grids run over ``B*Hq`` query heads and the k/v BlockSpecs' index
+maps send a query head's index to its kv head's (``_kv_head``); the dK/dV
+pass runs over ``B*Hkv`` kv heads and its sequential inner axis walks the q
+blocks of each of the group's query heads in turn, so the group's sum forms in
+the VMEM accumulators and dK / dV leave at ``Hkv`` heads.  With equal head
+counts and no window every index map and kernel body is what it was.
 
 Layouts are the ones Mosaic accepts: per-row softmax statistics are
 ``[block_q, 1]`` columns inside a kernel (they broadcast along lanes against
@@ -68,20 +80,39 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
-def reference_attention(q, k, v, causal: bool = True):
+def reference_attention(q, k, v, causal: bool = True, window: int | None = None):
     """Fused-XLA attention, [B, L, H, D] layout (fallback, test oracle, and
     the single fused-attention definition — models/transformer.py delegates
-    here).  v may have another width than q and k."""
+    here).  v may have another width than q and k.  k and v may have fewer
+    heads than q (grouped-query attention: query head ``h`` reads kv head
+    ``h // (Hq // Hkv)``; the repeat is written out here).  ``window``: query
+    ``t`` sees the keys ``s`` with ``0 <= t - s < window`` (causal only)."""
     d = q.shape[-1]
+    group = _kv_group(q, k, causal, window)
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     scores = jnp.einsum("blhd,bmhd->bhlm", q, k).astype(jnp.float32) / jnp.sqrt(
         jnp.float32(d)
     )
     if causal:
         L, M = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((L, M), dtype=bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((L, M), dtype=bool), -window)
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhlm,bmhd->blhd", probs, v)
+
+
+def _kv_group(q, k, causal, window):
+    """Query heads a kv head serves (1: multi-head attention), from the
+    operands' head counts; checks what every entry point asks of them."""
+    Hq, Hkv = q.shape[2], k.shape[2]
+    if Hq % Hkv:
+        raise ValueError(f"{Hq} query heads are no multiple of {Hkv} key/value heads")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"a window is a causal one of at least 1 key: {window}, causal={causal}")
+    return Hq // Hkv
 
 
 def _col_to_row(x):
@@ -119,53 +150,71 @@ def _fold_block(s, v, m_ref, l_ref, acc_ref):
 # index grids): whether a tile holds any live pair, whether all of it is
 # live, and the mask of one that is neither.
 
-def _tile_live(qi, kj, *, block_q, block_k, causal, valid_len):
+def _tile_live(qi, kj, *, block_q, block_k, causal, valid_len, window=None):
     """Tile (qi, kj) holds at least one live (query, key) pair: neither block
-    is all padding and, under the causal mask, the tile's first key is not
-    after its last query.  A dead tile's step runs no FLOPs."""
+    is all padding, under the causal mask the tile's first key is not after
+    its last query and, under a window, its last key is not before the window
+    of its first query.  A dead tile's step runs no FLOPs."""
     live = (kj * block_k < valid_len) & (qi * block_q < valid_len)
     if causal:
         live = live & (kj * block_k <= (qi + 1) * block_q - 1)
+    if window is not None:
+        live = live & ((kj + 1) * block_k - 1 > qi * block_q - window)
     return live
 
 
-def _tile_interior(qi, kj, *, block_q, block_k, causal, valid_len):
-    """Every pair of tile (qi, kj) is live: its keys end inside ``valid_len``
-    and, under the causal mask, its last key is not after its first query.
-    Such a tile needs no mask.  (Padded QUERY rows need none either: their
-    outputs are cut off and their dO is zero.)"""
+def _tile_interior(qi, kj, *, block_q, block_k, causal, valid_len, window=None):
+    """Every pair of tile (qi, kj) is live: its keys end inside ``valid_len``,
+    under the causal mask its last key is not after its first query and, under
+    a window, its first key is inside the window of its last query.  Such a
+    tile needs no mask.  (Padded QUERY rows need none either: their outputs
+    are cut off and their dO is zero.)"""
     inside = (kj + 1) * block_k <= valid_len
     if causal:
         inside = inside & ((kj + 1) * block_k - 1 <= qi * block_q)
+    if window is not None:
+        inside = inside & (kj * block_k > (qi + 1) * block_q - 1 - window)
     return inside
 
 
-def _tile_mask(qi, kj, shape, q_dim, *, block_q, block_k, causal, valid_len):
-    """Live entries of a tile the diagonal or the padded tail crosses;
-    ``shape`` has queries along ``q_dim`` and keys along the other."""
+def _tile_mask(qi, kj, shape, q_dim, *, block_q, block_k, causal, valid_len,
+               window=None):
+    """Live entries of a tile the diagonal, the window's far edge or the
+    padded tail crosses; ``shape`` has queries along ``q_dim`` and keys along
+    the other."""
     k_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
     live = k_idx < valid_len - kj * block_k  # padded tail keys never contribute
     if causal:
         q_idx = jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
         live = live & (q_idx - k_idx >= kj * block_k - qi * block_q)
+        if window is not None:
+            live = live & (q_idx - k_idx < window + kj * block_k - qi * block_q)
     return live
 
 
-def _live_k_block(i, j, *, block_q, block_k, causal, valid_len):
-    """Index map of a K/V block in a queries-major grid: ``j`` clamped to the
-    last live tile of row ``i``, so a dead step names the block already
-    resident and the pipeline copies nothing."""
+def _live_k_block(i, j, *, block_q, block_k, causal, valid_len, window=None):
+    """Index map of a K/V block in a queries-major grid: ``j`` clamped into
+    the live tiles of row ``i`` (from the tile that holds the first key of the
+    first query's window to the one the diagonal or the tail ends in), so a
+    dead step names a block that is resident, or the row's first live one,
+    which it prefetches, and the pipeline copies nothing else."""
     last = (valid_len - 1) // block_k
     if causal:
         last = jnp.minimum(last, jax.lax.div((i + 1) * block_q - 1, block_k))
-    return jnp.minimum(j, last)
+    if window is None:
+        return jnp.minimum(j, last)
+    first = jax.lax.div(jnp.maximum(i * block_q - window + 1, 0), block_k)
+    return jnp.clip(j, jnp.minimum(first, last), last)
 
 
-def _live_q_block(i, j, *, block_q, block_k, causal, valid_len):
+def _live_q_block(i, j, *, block_q, block_k, causal, valid_len, window=None):
     """Index map of a q-side block (q, dO, lse, delta) in the keys-major
     grid: ``i`` clamped into the live tiles of column ``j``, which under the
-    causal mask start at the diagonal — the dead steps before it prefetch it."""
+    causal mask start at the diagonal — the dead steps before it prefetch it —
+    and under a window end with the last query that sees the column's last key."""
     last = (valid_len - 1) // block_q
+    if window is not None:
+        last = jnp.minimum(last, jax.lax.div((j + 1) * block_k + window - 2, block_q))
     first = jnp.minimum(jax.lax.div(j * block_k, block_q), last) if causal else 0
     return jnp.clip(i, first, last)
 
@@ -267,24 +316,36 @@ def _geometry(L, D, dtype, block_q, block_k, Dv=None):
     return blocks, -(-L // m) * m
 
 
-def _tiling(kernel, Lp, blocks, causal, valid_len):
+def _tiling(kernel, Lp, blocks, causal, valid_len, window=None, group=1):
     """One call's tile parameters (the keywords of the ``_tile_*`` predicates)
     and its grid extents (q blocks, k blocks).  Leaves what was chosen as
-    gauges per kernel name (trace time: Python, from shapes): the blocks, and
-    live grid steps over grid steps."""
+    gauges per kernel name (trace time: Python, from shapes): the blocks, live
+    grid steps over grid steps, the window (0: none) and the query heads a kv
+    head serves.  A windowed call's gauges carry the label ``window`` beside
+    ``kernel``, so that a model with both kinds of layer keeps both readings."""
     import numpy as np
 
     from ..core import obs
 
     block_q, block_k = blocks
     tile = dict(block_q=block_q, block_k=block_k, causal=causal, valid_len=valid_len)
+    labels = {"kernel": kernel}
+    if window is not None:
+        tile["window"] = labels["window"] = int(window)
     n_qb, n_kb = Lp // block_q, Lp // block_k
     live = _tile_live(np.arange(n_qb)[:, None], np.arange(n_kb)[None, :], **tile)
-    labels = {"kernel": kernel}
     obs.gauge_set("flash.block_q", block_q, labels)
     obs.gauge_set("flash.block_k", block_k, labels)
     obs.gauge_set("flash.live_step_share", float(np.mean(live)), labels)
+    obs.gauge_set("flash.window", window or 0, labels)
+    obs.gauge_set("flash.kv_group", group, labels)
     return tile, n_qb, n_kb
+
+
+def _kv_head(group):
+    """Grid index of a query head -> that of its kv head, both batch-major
+    ([B*Hq] and [B*Hkv]): ``b // group``; the identity for equal head counts."""
+    return (lambda b: b) if group == 1 else (lambda b: jax.lax.div(b, group))
 
 
 # batch·heads and the outer block axis are independent; the inner one carries
@@ -380,17 +441,21 @@ def _specs(D, Dv, block_q, block_k, q_index, k_index):
     return q_spec, k_spec, spec(block_q, Dv, q_index), spec(block_k, Dv, k_index)
 
 
-def _fwd_call(qb, kb, vb, blocks, causal, valid_len, interpret, scale=None):
-    """``flash_fwd`` over ``[B*H, Lp, D]`` q and k and ``[B*H, Lp, Dv]`` v:
-    (out [B*H, Lp, Dv], lse [B*H, 1, Lp]).  ``scale`` defaults to that of the
-    operands' own width (a caller that zero-padded q and k gives the real one)."""
+def _fwd_call(qb, kb, vb, blocks, causal, valid_len, interpret, scale=None,
+              window=None):
+    """``flash_fwd`` over ``[B*Hq, Lp, D]`` q, ``[B*Hkv, Lp, D]`` k and
+    ``[B*Hkv, Lp, Dv]`` v: (out [B*Hq, Lp, Dv], lse [B*Hq, 1, Lp]).  ``scale``
+    defaults to that of the operands' own width (a caller that zero-padded q
+    and k gives the real one)."""
     BH, Lp, D = qb.shape
     Dv = vb.shape[-1]
     block_q, block_k = blocks
-    tile, n_qb, n_kb = _tiling("flash_fwd", Lp, blocks, causal, valid_len)
+    group = BH // kb.shape[0]
+    tile, n_qb, n_kb = _tiling("flash_fwd", Lp, blocks, causal, valid_len, window, group)
+    kv_head = _kv_head(group)
     q_spec, k_spec, o_spec, v_spec = _specs(
         D, Dv, block_q, block_k, lambda b, i, j: (b, i),
-        lambda b, i, j: (b, _live_k_block(i, j, **tile)))
+        lambda b, i, j: (kv_head(b), _live_k_block(i, j, **tile)))
     return pl.pallas_call(
         functools.partial(_flash_kernel, n_kb=n_kb,
                           scale=float(scale or 1.0 / (D**0.5)), tile=tile),
@@ -411,15 +476,16 @@ def _fwd_call(qb, kb, vb, blocks, causal, valid_len, interpret, scale=None):
     )(qb, kb, vb)
 
 
-def _flash_forward(q, k, v, causal, block_q, block_k, interpret,
+def _flash_forward(q, k, v, causal, block_q, block_k, interpret, window=None,
                    with_lse: bool = False):
     B, L, H, _ = q.shape
+    Hkv = H // _kv_group(q, k, causal, window)
     D, Dv, Dp = _head_widths(q, v)
     blocks, Lp = _geometry(L, Dp, q.dtype, block_q, block_k, Dv)
-    qb, kb = (_to_bh(x, B, L, H, D, Lp, Dp) for x in (q, k))
-    vb = _to_bh(v, B, L, H, Dv, Lp)
+    qb, kb = _to_bh(q, B, L, H, D, Lp, Dp), _to_bh(k, B, L, Hkv, D, Lp, Dp)
+    vb = _to_bh(v, B, L, Hkv, Dv, Lp)
     out, lse = _fwd_call(qb, kb, vb, blocks["flash_fwd"], causal, L, interpret,
-                         scale=1.0 / (D**0.5))
+                         scale=1.0 / (D**0.5), window=window)
     out = _from_bh(out, B, L, H, Dv)
     return (out, lse) if with_lse else out
 
@@ -488,14 +554,19 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, n_qb, scale, tile):
-    """Grid cell (bh, kj, qi): accumulate k/v block kj's gradients over q
-    blocks (sequential innermost qi).  p is zero wherever q_pos < k_pos, so
-    the q blocks entirely above kj are dead tiles."""
+                          dk_ref, dv_ref, dk_acc, dv_acc, *, n_qb, scale, tile,
+                          group=1):
+    """Grid cell (kv head, kj, t): accumulate k/v block kj's gradients over
+    the q blocks (sequential innermost axis) of every query head the kv head
+    serves, one head's ``n_qb`` blocks after the other's (``t = g * n_qb +
+    qi``; one head: ``t = qi``), so a group's sum is formed in the VMEM
+    accumulators and dK / dV leave at the kv heads' count.  p is zero wherever
+    q_pos < k_pos, so the q blocks entirely above kj are dead tiles."""
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    t = pl.program_id(2)
+    qi = t if group == 1 else jax.lax.rem(t, n_qb)
 
-    @pl.when(qi == 0)
+    @pl.when(t == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -516,23 +587,26 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     _when_live(qi, kj, tile, _accum)
 
-    @pl.when(qi == n_qb - 1)
+    @pl.when(t == group * n_qb - 1)
     def _finish():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _dq_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret,
-             scale=None):
-    """``flash_bwd_dq`` over ``[B*H, Lp, D]`` q and k, ``[B*H, Lp, Dv]`` v and
-    dO and ``[B*H, 1, Lp]`` row statistics: dQ, queries-major like the forward."""
+             scale=None, window=None):
+    """``flash_bwd_dq`` over ``[B*Hq, Lp, D]`` q, ``[B*Hkv, Lp, D]`` k,
+    ``[B*Hkv, Lp, Dv]`` v, ``[B*Hq, Lp, Dv]`` dO and ``[B*Hq, 1, Lp]`` row
+    statistics: dQ, queries-major like the forward."""
     BH, Lp, D = qb.shape
     Dv = vb.shape[-1]
     block_q, block_k = blocks
-    tile, n_qb, n_kb = _tiling("flash_bwd_dq", Lp, blocks, causal, valid_len)
+    group = BH // kb.shape[0]
+    tile, n_qb, n_kb = _tiling("flash_bwd_dq", Lp, blocks, causal, valid_len, window, group)
+    kv_head = _kv_head(group)
     q_spec, k_spec, do_spec, v_spec = _specs(
         D, Dv, block_q, block_k, lambda b, i, j: (b, i),
-        lambda b, i, j: (b, _live_k_block(i, j, **tile)))
+        lambda b, i, j: (kv_head(b), _live_k_block(i, j, **tile)))
     row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
     return pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, n_kb=n_kb,
@@ -549,22 +623,33 @@ def _dq_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret,
 
 
 def _dkv_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret,
-              scale=None):
-    """``flash_bwd_dkv`` over the same operands: (dK, dV), keys-major — the
-    grid is (bh, kj, qi) and the q-side blocks follow the inner axis."""
-    BH, Lp, D = qb.shape
+              scale=None, window=None):
+    """``flash_bwd_dkv`` over the same operands: (dK, dV) at the kv heads'
+    count, keys-major — the grid is (kv head, kj, t) and the q-side blocks
+    follow the inner axis, which walks the q blocks of each query head of the
+    kv head's group in turn (``_flash_bwd_dkv_kernel``)."""
+    BH, Lp, D = kb.shape
     Dv = vb.shape[-1]
     block_q, block_k = blocks
-    tile, n_qb, n_kb = _tiling("flash_bwd_dkv", Lp, blocks, causal, valid_len)
+    group = qb.shape[0] // BH
+    tile, n_qb, n_kb = _tiling("flash_bwd_dkv", Lp, blocks, causal, valid_len, window, group)
+    if group == 1:
+        q_at = lambda b, j, t: (b, _live_q_block(t, j, **tile))
+    else:
+        q_at = lambda b, j, t: (b * group + jax.lax.div(t, n_qb),
+                                _live_q_block(jax.lax.rem(t, n_qb), j, **tile))
     q_spec, k_spec, do_spec, v_spec = _specs(
-        D, Dv, block_q, block_k, lambda b, j, i: (b, _live_q_block(i, j, **tile)),
-        lambda b, j, i: (b, j))
-    row_spec = pl.BlockSpec(
-        (1, 1, block_q), lambda b, j, i: (b, 0, _live_q_block(i, j, **tile)))
+        D, Dv, block_q, block_k, q_at, lambda b, j, t: (b, j))
+
+    def row_at(b, j, t):
+        head, block = q_at(b, j, t)
+        return head, 0, block
+
+    row_spec = pl.BlockSpec((1, 1, block_q), row_at)
     return pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, n_qb=n_qb,
+        functools.partial(_flash_bwd_dkv_kernel, n_qb=n_qb, group=group,
                           scale=float(scale or 1.0 / (D**0.5)), tile=tile),
-        grid=(BH, n_kb, n_qb),
+        grid=(BH, n_kb, group * n_qb),
         in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=[k_spec, v_spec],
         out_shape=[
@@ -579,27 +664,31 @@ def _dkv_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret,
     )(qb, kb, vb, dob, lse, delta)
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
+def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
+                    window=None):
     """Pallas flash backward: same blockwise structure as the forward — P is
     re-materialized per block from (q, k, lse), so backward memory is
     O(block² ) per core instead of the O(L²) probs matrix."""
     B, L, H, _ = q.shape
+    Hkv = k.shape[2]
     D, Dv, Dp = _head_widths(q, v)
     blocks, Lp = _geometry(L, Dp, q.dtype, block_q, block_k, Dv)
-    qb, kb = (_to_bh(x, B, L, H, D, Lp, Dp) for x in (q, k))
-    vb, dob, ob = (_to_bh(x, B, L, H, Dv, Lp) for x in (v, g.astype(q.dtype), out))
+    qb, kb = _to_bh(q, B, L, H, D, Lp, Dp), _to_bh(k, B, L, Hkv, D, Lp, Dp)
+    vb = _to_bh(v, B, L, Hkv, Dv, Lp)
+    dob, ob = (_to_bh(x, B, L, H, Dv, Lp) for x in (g.astype(q.dtype), out))
     # delta_i = rowsum(dO * O): tiny elementwise pass, fused by XLA
     delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
                     axis=-1)[:, None, :]  # [B*H, 1, Lp], like lse
     operands = (qb, kb, vb, dob, lse, delta)
     scale = 1.0 / (D**0.5)
-    dq = _dq_call(*operands, blocks["flash_bwd_dq"], causal, L, interpret, scale)
-    dk, dv = _dkv_call(*operands, blocks["flash_bwd_dkv"], causal, L, interpret, scale)
-    return (_from_bh(dq, B, L, H, D), _from_bh(dk, B, L, H, D),
-            _from_bh(dv, B, L, H, Dv))
+    dq = _dq_call(*operands, blocks["flash_bwd_dq"], causal, L, interpret, scale, window)
+    dk, dv = _dkv_call(*operands, blocks["flash_bwd_dkv"], causal, L, interpret, scale,
+                       window)
+    return (_from_bh(dq, B, L, H, D), _from_bh(dk, B, L, Hkv, D),
+            _from_bh(dv, B, L, Hkv, Dv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -608,25 +697,30 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
-    """Pallas blockwise attention. q/k: [B, L, H, D], v: [B, L, H, Dv] ->
-    [B, L, H, Dv] (``Dv`` may differ from ``D``: latent attention's 192 / 128).
+    """Pallas blockwise attention. q: [B, L, Hq, D], k: [B, L, Hkv, D], v:
+    [B, L, Hkv, Dv] -> [B, L, Hq, Dv] (``Dv`` may differ from ``D``: latent
+    attention's 192 / 128; ``Hkv`` may divide ``Hq``: query head ``h`` reads kv
+    head ``h // (Hq // Hkv)`` through the k/v BlockSpecs' index maps, k and v
+    are never repeated in HBM, and dK / dV come out at ``Hkv`` heads).
+    ``window``: query ``t`` sees the keys ``s`` with ``0 <= t - s < window``.
     ``block_q`` / ``block_k`` left ``None`` are chosen per kernel from the
     shape (:func:`_choose_blocks`); ragged L is padded internally, to its
     lane rounding then and to a common multiple of explicit blocks else."""
-    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)
+    return _flash_forward(q, k, v, causal, block_q, block_k, interpret, window)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret,
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
+    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret, window,
                               with_lse=True)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, block_q, block_k, interpret, window, res, g):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
-                           interpret)
+                           interpret, window)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -831,10 +925,10 @@ def flash_shard_update(q, k, v, q_pos, k_pos, m, l, o, causal: bool = True,
                                    block_q, block_k, interpret)
 
 
-def attention(q, k, v, causal: bool = True):
+def attention(q, k, v, causal: bool = True, window: int | None = None):
     """Dispatch on the default backend and nothing else: the pallas kernel on
     ``tpu`` (a kernel that does not compile raises — it never quietly becomes
     the reference), the fused-XLA reference on every other backend."""
     if jax.default_backend() == "tpu":
-        return flash_attention(q, k, v, causal=causal)
-    return reference_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal=causal, window=window)
+    return reference_attention(q, k, v, causal=causal, window=window)

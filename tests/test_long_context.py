@@ -201,7 +201,8 @@ class TestFlashAttentionKernel:
         out = flash_attention(q, k, v, causal=True, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
         gauges = {(r["metric"], r["labels"].get("kernel")): r["value"]
-                  for r in obs.registry().export() if r["metric"].startswith("flash.")}
+                  for r in obs.registry().export()
+                  if r["metric"].startswith("flash.") and "window" not in r["labels"]}
         assert gauges[("flash.block_q", "flash_fwd")] == 128
         assert gauges[("flash.block_k", "flash_fwd")] == 128
         assert gauges[("flash.live_step_share", "flash_fwd")] == 1.0

@@ -100,6 +100,43 @@ def test_unequal_head_widths_compile_under_mosaic(v5e, shape):
         assert jax.jit(fn).lower(*args).compile() is not None, name
 
 
+# grouped kv heads and a window: the benchmark's own shape (smallthinker-21b-a3b-sim:
+# 28 / 4 heads of 128, one sequence of 16,384, window 4,096 or none) and a ragged
+# length whose window is no multiple of a block
+GQA_SHAPES = [(1, 16384, 28, 4, 128, 4096, jnp.bfloat16), (1, 16384, 28, 4, 128, None, jnp.bfloat16),
+              (2, 1000, 14, 2, 128, 300, jnp.float32)]
+
+
+def _gqa_case(B, L, Hq, Hkv, D, window, dtype, sharding=None):
+    kw = {} if sharding is None else {"sharding": sharding}
+    q = jax.ShapeDtypeStruct((B, L, Hq, D), dtype, **kw)
+    kv = jax.ShapeDtypeStruct((B, L, Hkv, D), dtype, **kw)
+    forward = lambda q, k, v: flash_attention(q, k, v, causal=True, window=window)
+    grad = lambda q, k, v: jax.grad(lambda *a: forward(*a).astype(jnp.float32).sum(),
+                                    argnums=(0, 1, 2))(q, k, v)
+    return (("forward", forward), ("grad", grad)), (q, kv, kv)
+
+
+_gqa_id = lambda s: "x".join(map(str, s[:6]))
+
+
+@pytest.mark.parametrize("shape", GQA_SHAPES, ids=_gqa_id)
+def test_window_and_grouped_kv_heads_lower_for_tpu(shape):
+    fns, args = _gqa_case(*shape)
+    for name, fn in fns:
+        text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+        assert text.count("tpu_custom_call") == (1 if name == "forward" else 3), name
+
+
+@pytest.mark.parametrize("shape", GQA_SHAPES, ids=_gqa_id)
+def test_window_and_grouped_kv_heads_compile_under_mosaic(v5e, shape):
+    fns, args = _gqa_case(*shape, sharding=jax.sharding.SingleDeviceSharding(v5e))
+    for name, fn in fns:
+        compiled = jax.jit(fn).lower(*args).compile()
+        if name == "grad":  # dK and dV leave at the kv heads' count: k and v were never repeated
+            assert [o.shape for o in compiled.out_info] == [a.shape for a in args]
+
+
 # the KDA kernels at the benchmark cell's shape (kimi-linear-48b-a3b-sim, one
 # sequence) and at a ragged length under float32 inputs
 KDA_SHAPES = [(1, 8192, 32, 128, jnp.bfloat16), (2, 1000, 3, 128, jnp.float32)]
@@ -137,7 +174,7 @@ def test_kimi_linear_ops_compile_for_v5e(v5e):
     at the benchmark cell's shapes: KDA chunkwise (scans, the triangular
     solves) and the expert layer's grouped products (``ragged_dot``, which the
     TPU compiler turns into its own Mosaic kernels)."""
-    from fedml_tpu.models import kimi_linear
+    from fedml_tpu.models import expert_lm
     from fedml_tpu.ops import kda
 
     sharding = jax.sharding.SingleDeviceSharding(v5e)
@@ -152,8 +189,8 @@ def test_kimi_linear_ops_compile_for_v5e(v5e):
     assert jax.jit(kda_grad).lower(*kda_args).compile() is not None
 
     def experts(h, chosen, weights, w_gate, w_up, w_down):
-        out, counters = kimi_linear.grouped_experts(h, chosen, weights, (0, 8),
-                                                    w_gate, w_up, w_down)
+        out, counters = expert_lm.grouped_experts(h, chosen, weights, (0, 8),
+                                                  w_gate, w_up, w_down, 256)
         return out.astype(jnp.float32).sum() + counters["moe.assignments_dropped"]
 
     moe_args = (s((8192, 2304), jnp.bfloat16), s((8192, 8), jnp.int32),
