@@ -37,6 +37,12 @@ import jax.numpy as jnp
 COUNTERS = ("moe.assignments_local", "moe.assignments_total", "moe.expert_load_max",
             "moe.expert_load_mean", "moe.assignments_dropped")
 
+# what a block's recomputation keeps from its first forward: the results of the
+# token mixers' Pallas kernels, by the names their ``fwd`` rules place
+# (``ops/kept.py``).  A kernel's output is cheap to keep and dear to rebuild; its
+# inputs (norm, projections, rotation, convolutions, gates) are rebuilt
+KEPT = ("flash_fwd.out", "flash_fwd.lse", "kda_fwd.o", "kda_fwd.states")
+
 
 def load_config(model_config) -> dict:
     """``model_config`` as ``arguments.py`` validates it: a dict, or the path
@@ -267,8 +273,8 @@ class ExpertShare(nn.Module):
 class DecoderLM(nn.Module):
     """The LM shell: token embedding, ``cfg.num_hidden_layers`` blocks
     (``block_cls(cfg, index, name="layer<i>")(x, train)``, each recomputed in
-    the backward pass where ``cfg.remat``), final RMSNorm, untied head.  A
-    model subclasses it and names its block."""
+    the backward pass where ``cfg.remat``, all but what ``KEPT`` names), final
+    RMSNorm, untied head.  A model subclasses it and names its block."""
     cfg: Any
     # the packed round asks for these sums beside the loss (ml/engine/packed.py)
     round_counters: Tuple[str, ...] = COUNTERS
@@ -287,8 +293,9 @@ class DecoderLM(nn.Module):
         embed = self.param("embed", _normal(cfg.hidden_size),
                            (cfg.vocab_size, cfg.hidden_size), jnp.float32)
         x = embed.astype(cfg.dtype)[tokens]
-        block_cls = (nn.remat(self.block_cls, static_argnums=(2,)) if cfg.remat
-                     else self.block_cls)
+        block_cls = (nn.remat(self.block_cls, static_argnums=(2,),
+                              policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+                     if cfg.remat else self.block_cls)
         for i in range(cfg.num_hidden_layers):
             x = block_cls(cfg, i, name=f"layer{i}")(x, train)
         scale = self.param("final_norm", nn.initializers.ones, (cfg.hidden_size,), jnp.float32)

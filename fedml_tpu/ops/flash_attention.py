@@ -15,7 +15,10 @@ Differentiation: a ``jax.custom_vjp`` over dedicated pallas backward
 kernels — the forward additionally emits the per-row log-sum-exp, and the
 backward re-materializes P blockwise from (q, k, lse) in two passes (a dQ
 pass with k innermost, a dK/dV pass with q innermost), so backward memory
-is O(block²) per core like the forward, never the O(L²) probs matrix.
+is O(block²) per core like the forward, never the O(L²) probs matrix.  The
+forward rule names its output and the log-sum-exp (``flash_fwd.out``,
+``flash_fwd.lse``: ``ops/kept.py``), so a caller that recomputes its layers
+can keep those two and find the kernel's second call dead.
 
 How blocks are chosen.  A grid step costs about 0.35 µs on a v5e whatever it
 computes, and a 128 x 128 tile's matmuls a tenth of that, so the tiling, not
@@ -74,6 +77,8 @@ import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import kept
 
 _LANES = 128  # TPU vreg lane width: the alignment of every block's last dim
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
@@ -714,6 +719,8 @@ def flash_attention(
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
     out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret, window,
                               with_lse=True)
+    # a recomputing caller may keep these two (ops/kept.py); q, k and v it rebuilds
+    out, lse = kept.tag("flash_fwd", out=out, lse=lse)
     return out, (q, k, v, out, lse)
 
 

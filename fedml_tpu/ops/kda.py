@@ -52,6 +52,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kept
+
 CHUNK = 64  # tokens a chunk: the published kernels' size
 BLOCK = 16  # tokens a block of a chunk's gate factorisation
 _GROUP = 16  # chunks a group of a long sequence (see kda_chunked)
@@ -614,6 +616,8 @@ def _kda_rows(heads, n, interpret, q, k, v, g, beta):
 
 def _kda_rows_fwd(heads, n, interpret, *rows):
     o, states = _forward(heads, n, interpret, *rows)
+    # a recomputing caller may keep these two (ops/kept.py); the rows it rebuilds
+    o, states = kept.tag("kda_fwd", o=o, states=states)
     return o, (*rows, states)  # the inputs as the kernels read them, and 16 MiB a layer
 
 
