@@ -1,0 +1,16 @@
+"""The flash backward kernels' (``flash_bwd_dq`` and ``flash_bwd_dkv`` together) share of
+their roofline at latent attention's 256 / 256; see ``mla256_flash_fwd_roofline.py``.  The
+recomputation of the scores that a flash backward makes is not counted."""
+
+import importlib.util
+import os
+
+_spec = importlib.util.spec_from_file_location(
+    "mla256_flash_fwd_roofline",
+    os.path.join(os.path.dirname(__file__), "mla256_flash_fwd_roofline.py"))
+_fwd = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_fwd)
+
+
+def read(ctx):
+    return _fwd.read(ctx, kernels=("flash_bwd_dq", "flash_bwd_dkv"), backward=True)

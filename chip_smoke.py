@@ -22,7 +22,8 @@ before any data is generated.
   forward and ``jax.grad`` against ``reference_attention``, and
   ``flash_shard_update`` against ``shard_update_reference``, within
   TOLERANCE; at the ``kimi-linear-48b-a3b-sim`` cell's shapes flash at q/k
-  192 != v 128, KDA through the entry the model calls (the kernels ``kda_fwd``
+  192 != v 128 (and at ``glm-4.7-flash-sim``'s 256 / 256 over 20 heads), KDA
+  through the entry the model calls (the kernels ``kda_fwd``
   / ``kda_bwd``, or the leg fails) forward and ``jax.grad`` against the
   per-token recurrence (``kda_errors``) and the expert layer's grouped products
   against a dense masked loop (``kimi_linear_ops``); then TransformerLM
@@ -87,6 +88,8 @@ KERNEL_SHAPES = [(8, 1023, 16, 64, "bfloat16"), (2, 256, 8, 32, "float32"),
 # that the float32 scores and the per-token scan's saved states fit the chip.
 KIMI = dict(L=8192, H=32, qk=192, v=128, kda=128, d=2304, f=1024, held=8, routed=256, top=8)
 ORACLE_HEADS = 4
+# the glm-4.7-flash-sim cell's latent attention at the same length: (heads, q/k, v)
+GLM47_FLASH = (20, 256, 256)
 # one- vs four-device runs of the same seed train the same clients on the
 # same batches; they differ in summation order under bf16 compute (measured
 # 7.2e-5 over these four rounds on the v5e, this PR)
@@ -214,29 +217,31 @@ def kimi_linear_ops():
     from fedml_tpu.ops.flash_attention import flash_attention, reference_attention
 
     L, H, errors = KIMI["L"], KIMI["H"], {}
-    heads = [slice(h, h + ORACLE_HEADS) for h in range(0, H, ORACLE_HEADS)]
 
     def worst(name, value):
         errors[name] = max(errors.get(name, 0.0), value)
 
-    # flash at q/k 192 != v 128, every head in one call; the oracle by groups of heads
-    keys = jax.random.split(jax.random.PRNGKey(192), 4)
-    q, k = (jax.random.normal(key, (1, L, H, KIMI["qk"]), jnp.bfloat16) for key in keys[:2])
-    v = jax.random.normal(keys[2], (1, L, H, KIMI["v"]), jnp.bfloat16)
-    w = jax.random.normal(keys[3], (1, L, H, KIMI["v"]), jnp.float32)
-    flash = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
-    out = flash(q, k, v)
-    got = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash(q, k, v).astype(jnp.float32) * w),
-                           argnums=(0, 1, 2)))(q, k, v)
-    for hs in heads:
-        part = [x[:, :, hs] for x in (q, k, v)]
-        with jax.default_matmul_precision("highest"):
-            ref = lambda q, k, v: reference_attention(q, k, v, causal=True)
-            worst("fwd_mla_flash", _rel_err(out[:, :, hs], jax.jit(ref)(*part)))
-            exp = jax.jit(jax.grad(lambda *a: jnp.sum(ref(*a).astype(jnp.float32) * w[:, :, hs]),
-                                   argnums=(0, 1, 2)))(*part)
-        for name, g, e in zip("qkv", got, exp):
-            worst(f"d{name}_mla_flash", _rel_err(g[:, :, hs], e))
+    # flash at q/k 192 != v 128 and at glm-4.7-flash-sim's 256 / 256 over 20 heads, every
+    # head in one call; the oracle by groups of heads
+    for tag, n_heads, qk, dv in (("mla", H, KIMI["qk"], KIMI["v"]), ("mla256", *GLM47_FLASH)):
+        keys = jax.random.split(jax.random.PRNGKey(qk), 4)
+        q, k = (jax.random.normal(key, (1, L, n_heads, qk), jnp.bfloat16) for key in keys[:2])
+        v = jax.random.normal(keys[2], (1, L, n_heads, dv), jnp.bfloat16)
+        w = jax.random.normal(keys[3], (1, L, n_heads, dv), jnp.float32)
+        flash = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
+        out = flash(q, k, v)
+        got = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash(q, k, v).astype(jnp.float32) * w),
+                               argnums=(0, 1, 2)))(q, k, v)
+        for hs in (slice(h, h + ORACLE_HEADS) for h in range(0, n_heads, ORACLE_HEADS)):
+            part = [x[:, :, hs] for x in (q, k, v)]
+            with jax.default_matmul_precision("highest"):
+                ref = lambda q, k, v: reference_attention(q, k, v, causal=True)
+                worst(f"fwd_{tag}_flash", _rel_err(out[:, :, hs], jax.jit(ref)(*part)))
+                exp = jax.jit(jax.grad(
+                    lambda *a: jnp.sum(ref(*a).astype(jnp.float32) * w[:, :, hs]),
+                    argnums=(0, 1, 2)))(*part)
+            for name, g, e in zip("qkv", got, exp):
+                worst(f"d{name}_{tag}_flash", _rel_err(g[:, :, hs], e))
 
     errors.update(kda_errors(L, H, KIMI["kda"]))
 

@@ -186,27 +186,41 @@ def build_loss_fn(module, has_dropout: bool = True, loss: str = "ce",
     training step (``module.round_counters``).  Given any, the closure returns
     ``(loss_val, (updated_collections, {name: sum over the layers that sowed
     it}))``: numbers that come out of the compiled step beside the loss and
-    are no part of the model's state."""
+    are no part of the model's state.
+
+    A module that ``takes_targets`` (a decoder with a multi-token-prediction
+    module, ``models/expert_lm.py``) trains more than its logits: it is handed
+    ``targets=(labels, row mask)``, sows each further weighted loss term into
+    its ``losses`` collection, and the step trains ``loss_kind(logits) + their
+    sum``, which is the ``loss_val`` returned.  Where ``counters`` names
+    ``lm.loss_main`` it is filled with ``loss_kind(logits)`` alone."""
     loss_kind = LOSS_FNS[loss]
+    takes_targets = bool(getattr(module, "takes_targets", False))
 
     def loss_fn(params, other_vars, bx, by, bmask, rng):
         variables = dict(other_vars, params=params)
-        mutable = [k for k in other_vars.keys()] + (["counters"] if counters else [])
+        mutable = ([k for k in other_vars.keys()] + (["counters"] if counters else [])
+                   + (["losses"] if takes_targets else []))
         rngs = {"dropout": rng} if has_dropout else None
+        targets = {"targets": (by, bmask)} if takes_targets else {}
         if mutable:
             logits, updated = module.apply(
-                variables, bx, train=True, rngs=rngs, mutable=mutable
+                variables, bx, train=True, rngs=rngs, mutable=mutable, **targets
             )
         else:
             logits = module.apply(variables, bx, train=True, rngs=rngs)
             updated = {}
-        loss_val, _ = loss_kind(logits, by, bmask)
+        loss_val = main = loss_kind(logits, by, bmask)[0]
+        updated = dict(updated)
+        if takes_targets:
+            loss_val = main + sum(jax.tree_util.tree_leaves(updated.pop("losses", {})))
         if not counters:
             return loss_val, updated
-        updated = dict(updated)
         sown = jax.tree_util.tree_flatten_with_path(updated.pop("counters", {}))[0]
         sums = {name: sum((v for path, v in sown if path[-1].key == name),
                           jnp.zeros((), jnp.float32)) for name in counters}
+        if "lm.loss_main" in sums:
+            sums["lm.loss_main"] = main
         return loss_val, (updated, sums)
 
     return loss_fn

@@ -2,7 +2,8 @@
 ``smallthinker.py``): the expert layer — routing, the grouped products over the
 experts held here, the round's counters — RMSNorm, the dense gated MLP, the
 initialiser and the LM shell (embedding -> blocks under per-layer ``remat`` ->
-final RMSNorm -> untied head).
+final RMSNorm -> untied head, and after the blocks the multi-token-prediction
+module of a model that has one: :class:`PredictionModule`).
 
 A model's config dataclass gives the shared parts these fields:
 ``hidden_size``, ``num_hidden_layers``, ``vocab_size``, ``rms_norm_eps``,
@@ -10,7 +11,9 @@ A model's config dataclass gives the shared parts these fields:
 experts this process holds), ``num_experts_per_token``, ``num_shared_experts``,
 ``dtype``, ``remat``; a model whose expert layer routes on its own input
 (``kimi_linear``: sigmoid scores + correction bias) also gives
-``routed_scaling_factor`` and ``moe_renormalize``.
+``routed_scaling_factor`` and ``moe_renormalize``; a model with a
+multi-token-prediction module gives ``num_nextn_predict_layers`` (1) and
+``mtp_loss_weight``.
 
 Expert layer (:class:`ExpertShare`): the router scores all
 ``n_routed_experts`` in float32; this process adds the part of the experts it
@@ -32,10 +35,15 @@ from typing import Any, Callable, ClassVar, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import optax
 
 # what an expert layer sows a step; the packed round returns their sums
 COUNTERS = ("moe.assignments_local", "moe.assignments_total", "moe.expert_load_max",
             "moe.expert_load_mean", "moe.assignments_dropped")
+
+# what a prediction module sows a step beside them, and what the engine's loss
+# fills in for a module that names it (``ml/engine/train.py:build_loss_fn``)
+MTP_COUNTERS = ("lm.loss_main", "mtp.loss", "mtp.positions")
 
 # what a block's recomputation keeps from its first forward: the results of the
 # token mixers' Pallas kernels, by the names their ``fwd`` rules place
@@ -270,11 +278,87 @@ class ExpertShare(nn.Module):
         return out.reshape(h.shape)
 
 
+class PredictionModule(nn.Module):
+    """One multi-token-prediction module in the form of the DeepSeek-V3 report
+    (arXiv:2412.19437, section 2.2), trained for the token after next.  With
+    ``x`` the last main block's output (NOT the final norm's), ``Emb`` the main
+    embedding and ``W_head`` the main head, both handed in and so shared:
+
+        u_i = [RMSNorm_h(x_i) ; RMSNorm_e(Emb(t_{i+1}))] W_eh      (``lm.mtp.merge``)
+        y   = one whole block of the model's own kind on u          (its own weights)
+        logits'_i = RMSNorm_m(y_i) W_head  predicts  t_{i+2}        (``lm.mtp.head``)
+
+    ``t_{i+1}`` is the row's own next token, the last position's its label.  The
+    module runs over all L positions (causal, so the last reaches no other) and
+    the last one, which has no ``t_{i+2}``, is masked out of the loss:
+    ``L_mtp`` = mean cross-entropy over the L - 1 positions of the rows in the
+    batch's mask.  The head and its loss are one ``jax.checkpoint``: the module's
+    float32 logits live inside its forward and again inside its backward, never
+    beside the main model's.
+
+    Sows ``mtp_loss_weight`` x ``L_mtp`` into the collection ``losses`` (the
+    engine's loss adds what a module sows there) and ``mtp.loss`` (unweighted),
+    ``mtp.positions`` into ``counters``."""
+    cfg: Any
+    block_cls: Any  # as ``DecoderLM`` wraps it: under the per-layer remat where ``cfg.remat``
+
+    @nn.compact
+    def __call__(self, x, tokens, embed, head, targets=None, train: bool = False):
+        from ..core import obs
+
+        cfg = self.cfg
+        d, dt, eps = cfg.hidden_size, cfg.dtype, cfg.rms_norm_eps
+        obs.gauge_set("mtp.modules", cfg.num_nextn_predict_layers)
+        obs.gauge_set("mtp.loss_weight", cfg.mtp_loss_weight)
+
+        def scale(name):
+            return self.param(name, nn.initializers.ones, (d,), jnp.float32)
+
+        labels, row_mask = targets if targets is not None else (tokens, None)
+        with jax.named_scope("lm.mtp.merge"):
+            nxt = jnp.concatenate([tokens[:, 1:], labels[:, -1:]], axis=1)
+            w_eh = self.param("w_eh", _normal(2 * d), (2 * d, d), jnp.float32).astype(dt)
+            u = jnp.concatenate([rms_norm(x, scale("h_norm"), eps),
+                                 rms_norm(embed.astype(dt)[nxt], scale("e_norm"), eps)], -1) @ w_eh
+        y = self.block_cls(cfg, cfg.num_hidden_layers, name="block")(u, train)
+        out_scale = scale("norm")
+        if targets is None:  # ``init``: the parameters exist, nothing is trained
+            return
+        # position i is trained on t_{i+2} = labels[i + 1]; the last has none
+        after_next = jnp.concatenate([labels[:, 1:], labels[:, -1:]], axis=1)
+        has_target = (jnp.arange(labels.shape[1]) < labels.shape[1] - 1).astype(jnp.float32)
+        mask = row_mask.astype(jnp.float32)[:, None] * has_target
+
+        @jax.checkpoint
+        def head_loss(y, out_scale, head, after_next, mask):
+            logits = rms_norm(y, out_scale, eps) @ head.astype(dt)
+            per = optax.softmax_cross_entropy_with_integer_labels(
+                logits.astype(jnp.float32), after_next)
+            return jnp.sum(per * mask), jnp.sum(mask)
+
+        with jax.named_scope("lm.mtp.head"):
+            total, positions = head_loss(y, out_scale, head, after_next, mask)
+            loss = total / jnp.maximum(positions, 1.0)
+        zero = lambda: jnp.zeros((), jnp.float32)  # noqa: E731
+        self.sow("losses", "mtp", cfg.mtp_loss_weight * loss, reduce_fn=jnp.add, init_fn=zero)
+        self.sow("counters", "mtp.loss", loss, reduce_fn=jnp.add, init_fn=zero)
+        self.sow("counters", "mtp.positions", positions, reduce_fn=jnp.add, init_fn=zero)
+
+
 class DecoderLM(nn.Module):
     """The LM shell: token embedding, ``cfg.num_hidden_layers`` blocks
     (``block_cls(cfg, index, name="layer<i>")(x, train)``, each recomputed in
     the backward pass where ``cfg.remat``, all but what ``KEPT`` names), final
-    RMSNorm, untied head.  A model subclasses it and names its block."""
+    RMSNorm, untied head.  A model subclasses it and names its block.
+
+    Where ``cfg.num_nextn_predict_layers`` is 1 a :class:`PredictionModule`
+    (``mtp``: one more block of the same kind under the same remat and policy)
+    reads the last block's output when ``train``; the model still returns the
+    main logits alone, and the module's weighted loss reaches the step through
+    the ``losses`` collection.  Such a model ``takes_targets``: a training step
+    hands it ``targets=(labels, row mask)``, which the engine's loss does
+    (``ml/engine/train.py:build_loss_fn``).  With ``train=False`` the module
+    is not run."""
     cfg: Any
     # the packed round asks for these sums beside the loss (ml/engine/packed.py)
     round_counters: Tuple[str, ...] = COUNTERS
@@ -285,8 +369,12 @@ class DecoderLM(nn.Module):
     # pass for shapes alone, so ``init`` looks at this many tokens
     init_length: ClassVar[int] = 64
 
+    @property
+    def takes_targets(self) -> bool:
+        return bool(getattr(self.cfg, "num_nextn_predict_layers", 0))
+
     @nn.compact
-    def __call__(self, tokens, train: bool = False):
+    def __call__(self, tokens, train: bool = False, targets=None):
         cfg = self.cfg
         if self.is_initializing():
             tokens = tokens[:, :self.init_length]
@@ -301,4 +389,12 @@ class DecoderLM(nn.Module):
         scale = self.param("final_norm", nn.initializers.ones, (cfg.hidden_size,), jnp.float32)
         head = self.param("head", _normal(cfg.hidden_size),
                           (cfg.hidden_size, cfg.vocab_size), jnp.float32)
+        if self.takes_targets and (train or self.is_initializing()):
+            if train and targets is None:
+                raise ValueError(
+                    f"{type(self).__name__} trains a multi-token-prediction module: a training "
+                    "step hands it targets=(labels, row mask), as ml/engine/train.py's "
+                    "build_loss_fn does")
+            with jax.named_scope("lm.mtp"):
+                PredictionModule(cfg, block_cls, name="mtp")(x, tokens, embed, head, targets, train)
         return rms_norm(x, scale, cfg.rms_norm_eps) @ head.astype(cfg.dtype)

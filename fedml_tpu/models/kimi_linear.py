@@ -12,10 +12,11 @@ FFN(RMSNorm(x))``; final RMSNorm; untied output head.
   per-channel log-gate ``g = -exp(A_log) softplus(W_f_up W_f_down x + dt_bias)``,
   ``beta = sigmoid(W_beta x)``, the gated delta rule (``ops/kda.py``, chunkwise),
   then ``W_o(RMSNorm_head(o) * sigmoid(W_g_up W_g_down x))``.
-* MLA mixer (:class:`MLAMixer`), ``mla_use_nope``: keys and values up-projected
-  from a normalised latent of ``kv_lora_rank``, a shared ``qk_rope_head_dim``
-  key part that is NOT rotated, full-rank queries; q and k of width
-  ``qk_nope_head_dim + qk_rope_head_dim`` and v of ``v_head_dim`` go through
+* MLA mixer (``latent_attention.MLAMixer``, shared with ``glm4_moe_lite.py``,
+  here in its ``mla_use_nope`` form): keys and values up-projected from a
+  normalised latent of ``kv_lora_rank``, a shared ``qk_rope_head_dim`` key part
+  that is NOT rotated, full-rank queries; q and k of width ``qk_nope_head_dim +
+  qk_rope_head_dim`` and v of ``v_head_dim`` go through
   ``ops.flash_attention.attention``.
 * Expert layer (``expert_lm.ExpertShare``): sigmoid scores over all
   ``n_routed_experts`` in float32, the top ``num_experts_per_token`` of score +
@@ -46,6 +47,7 @@ from ..ops import kda as kda_ops
 # ``smallthinker.py``
 from .expert_lm import (DecoderLM, DenseMLP, ExpertShare, _normal, compute_dtype, held_range,
                         rms_norm)
+from .latent_attention import MLAMixer
 
 @dataclasses.dataclass(frozen=True)
 class KimiLinearConfig:
@@ -169,33 +171,6 @@ class KDAMixer(nn.Module):
         o_norm = self.param("o_norm", nn.initializers.ones, (D,), jnp.float32)
         o = rms_norm(o, o_norm, cfg.rms_norm_eps) * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
         return proj("wo", (H, D, d), H * D, o, "blhk,hkd->bld")
-
-
-class MLAMixer(nn.Module):
-    cfg: KimiLinearConfig
-
-    @nn.compact
-    def __call__(self, h):
-        from ..ops.flash_attention import attention
-
-        cfg = self.cfg
-        d, H, dt = cfg.hidden_size, cfg.num_attention_heads, cfg.dtype
-        nope, pe, dv, rank = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
-                              cfg.kv_lora_rank)
-
-        def param(name, shape, fan_in):
-            return self.param(name, _normal(fan_in), shape, jnp.float32).astype(dt)
-
-        q = jnp.einsum("bld,dhk->blhk", h, param("wq", (d, H, nope + pe), d))
-        kv = jnp.einsum("bld,dr->blr", h, param("w_kv_down", (d, rank + pe), d))
-        kv_norm = self.param("kv_norm", nn.initializers.ones, (rank,), jnp.float32)
-        c = rms_norm(kv[..., :rank], kv_norm, cfg.rms_norm_eps)
-        up = jnp.einsum("blr,rhk->blhk", c, param("w_kv_up", (rank, H, nope + dv), rank))
-        # the shared key part is broadcast over the heads and, NoPE, not rotated
-        k_pe = jnp.broadcast_to(kv[..., None, rank:], kv.shape[:2] + (H, pe))
-        k = jnp.concatenate([up[..., :nope], k_pe], -1)
-        o = attention(q, k, up[..., nope:], causal=True)
-        return jnp.einsum("blhk,hkd->bld", o, param("wo", (H, dv, d), H * dv))
 
 
 class Block(nn.Module):

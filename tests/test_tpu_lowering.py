@@ -75,8 +75,13 @@ def test_pallas_entry_points_compile_under_mosaic(v5e, shape):
 
 
 # latent attention: q and k of 192 (128 + 64, no lane multiple), v of 128; the
-# second is the benchmark's own shape (kimi-linear-48b-a3b-sim, one sequence)
-MLA_SHAPES = [(2, 1000, 4, 192, 128, jnp.bfloat16), (1, 8192, 32, 192, 128, jnp.bfloat16)]
+# second is the benchmark's own shape (kimi-linear-48b-a3b-sim, one sequence).  And
+# rotated latent attention with v as wide as q and k, 256 / 256 at 20 heads: the
+# benchmark's own shape (glm-4.7-flash-sim, one sequence of 8,192: the forward's step is
+# reckoned at the 16 MiB VMEM budget to the byte, dQ's q block steps down to 512) and a
+# ragged length in float32 (the tiles step down further)
+MLA_SHAPES = [(2, 1000, 4, 192, 128, jnp.bfloat16), (1, 8192, 32, 192, 128, jnp.bfloat16),
+              (1, 8192, 20, 256, 256, jnp.bfloat16), (2, 1000, 5, 256, 256, jnp.float32)]
 
 
 def _mla_args(B, L, H, D, Dv, dtype, sharding=None):
