@@ -154,6 +154,64 @@ def expert_blocks(total: int, held: int, routed: int) -> Tuple[int, Tuple[int, .
     return base, tuple(sorted({1, min(2, n_blocks), n_blocks})), base > floor
 
 
+# An expert layer whose block is at least this share of a step's assignments moves its
+# rows by gathers alone (:func:`grouped_experts`, "Two forms").  Readings on the v5e
+# (PR 34; the parent's traced rounds, then the layer alone in both forms).  A row
+# scattered: 0.108 us (``smallthinker``: 73,728 rows of 2,560 bfloat16 a call, 8.0 ms),
+# 0.092 us (``glm47-flash``: 12,288 rows of 2,048, 1.13 ms), 0.164 us (``kimi-linear``:
+# 8,192 of 2,304).  A row gathered by ``h[token]``, whose source XLA keeps in VMEM:
+# 0.0079 us (0.58 ms) and 0.0056-0.011 us.  The gather form moves T k rows whatever
+# the block, and reads them from the block's result: 0.0063 us a row where that fits
+# in VMEM (``glm47-flash``'s 50 MB), 0.043 us from HBM (``smallthinker``'s 377 MB), and
+# 0.008 us a row for the sum.  Scatter form -> gather form, the expert layers of a
+# traced round a layer-step: block / assignments 0.75 (16 of 64) 68.6 -> 61.0 ms, 0.375
+# (8 of 64) 14.7 -> 13.0 ms; the layer alone at 0.125 (8 of 256) 4.90 -> 5.22 ms: the
+# forms cross between an eighth and three eighths
+GATHERED_SHARE = 0.25
+
+
+def _over_rows(mask, like):
+    return mask.reshape(mask.shape + (1,) * (like.ndim - 1))
+
+
+@jax.custom_vjp
+def spread_rows(h, token, live, idx, valid):
+    """[T, ...] -> [R, ...]: row s is ``h[token[s]]`` where ``live[s]``, zeros
+    elsewhere.  ``idx``, ``valid`` ([k, T]) say the same thing from the tokens'
+    side — ``valid[j, t]``: the j-th of token t's rows is live row ``idx[j, t]`` —
+    and serve the way back, which is :func:`unpermute_sum` of the cotangent: a
+    gather where XLA's transpose of ``h[token]`` is a scatter-add."""
+    return jnp.where(_over_rows(live, h), h[token], 0)
+
+
+@jax.custom_vjp
+def unpermute_sum(rows, token, live, idx, valid):
+    """[R, ...] -> [T, ...]: ``out[t]`` is the sum over j of ``rows[idx[j, t]]``
+    where ``valid[j, t]``, formed in float32.  The transpose of
+    :func:`spread_rows` under the same four index arrays, and the other way
+    round.  k slabs of T rows and not T groups of k: a sum over the leading
+    axis adds whole tiles."""
+    picked = jnp.where(_over_rows(valid, rows), rows[idx], 0)
+    return jnp.sum(picked, axis=0, dtype=jnp.float32).astype(rows.dtype)
+
+
+def _transposes(one, other):
+    one.defvjp(lambda x, *index: (one(x, *index), index),
+               lambda index, g: (other(g, *index),) + (None,) * len(index))
+
+
+_transposes(spread_rows, unpermute_sum)
+_transposes(unpermute_sum, spread_rows)
+
+
+def _rows_of(pos, start, rows: int, n_local):
+    """(``idx``, ``valid``) of :func:`spread_rows` for the sorted rows
+    [start, start + rows) of which those before ``n_local`` are live; ``pos``:
+    [k, T], each assignment's place among the sorted rows."""
+    valid = (pos >= start) & (pos < jnp.minimum(start + rows, n_local))
+    return jnp.clip(pos - start, 0, rows - 1), valid
+
+
 def grouped_experts(h, chosen, weights, held: Tuple[int, int], w_gate, w_up, w_down,
                     n_routed: int, activation: Callable = jax.nn.silu):
     """Sum over the chosen experts that are held here of weight x gated expert
@@ -163,8 +221,8 @@ def grouped_experts(h, chosen, weights, held: Tuple[int, int], w_gate, w_up, w_d
 
     The T*k assignments are sorted by expert, those of absent experts last.
     The local ones are then worked off in blocks (gather the tokens, three
-    grouped products over the block's rows of each expert, weight, scatter
-    back), each block recomputed on the way back; as many blocks run as hold
+    grouped products over the block's rows of each expert, weight, add up each
+    token's rows), each block recomputed on the way back; as many blocks run as hold
     every local assignment, in the tiers :func:`expert_blocks` gives for the
     share held (``lax.switch``: a loop with a traced trip count has no reverse
     mode, and a ``lax.cond`` a block inside one ``lax.scan`` kept 2.5 GiB more
@@ -174,19 +232,43 @@ def grouped_experts(h, chosen, weights, held: Tuple[int, int], w_gate, w_up, w_d
     layer more than 6.25 % of its assignments here ran two blocks there and
     their rounds took 1.6 % longer than the others' (v5e, PR 27); and a block
     costs about 6 ms on the way back whatever its rows (the scatter-add that
-    transposes its gather; v5e, PR 31)."""
+    transposes its gather; v5e, PR 31).
+
+    Two forms, chosen from (T k, held, routed) alone.  A row costs ten times as
+    much to scatter-add as to gather (``GATHERED_SHARE``), the scatter form moves
+    a block's rows and the gather form T k.  Where a block is ``GATHERED_SHARE`` of
+    the assignments or more (16 of 64, 8 of 64), the inverse of the sort says
+    where each token's k rows sit among the sorted ones, the combine is a gather
+    of them and a float32 sum over k (:func:`unpermute_sum`), and the way back
+    of the tokens' gather and of the weights' lookup is the same gather of the
+    cotangent (:func:`spread_rows`); the experts' rows are counted by a compare
+    and a sum: no scatter-add is left in the layer.  Below it (8 of 256: one
+    block is an eighth) the combine is ``zeros.at[token].add``, XLA transposes
+    the gathers and ``bincount`` counts, as before."""
+    from ..core import obs
+
     T, k = chosen.shape
     d = h.shape[-1]
     lo, hi = held
     n_held, total = hi - lo, T * k
     base, tiers, padded = expert_blocks(total, n_held, n_routed)
     n_blocks = tiers[-1]
+    gathered = base >= GATHERED_SHARE * total
+    obs.gauge_set("moe.combine_gathered", int(gathered))
     flat = chosen.reshape(-1)
     with jax.named_scope("lm.moe.dispatch"):
         local = (flat >= lo) & (flat < hi)
         key = jnp.where(local, flat - lo, n_held)
-        order = jnp.pad(jnp.argsort(key, stable=True), (0, n_blocks * base - total))
-        sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
+        by_expert = jnp.argsort(key, stable=True)
+        order = jnp.pad(by_expert, (0, n_blocks * base - total))
+        if gathered:
+            # the inverse of the sort: where assignment (t, j) sits among the sorted rows
+            place = jnp.argsort(by_expert)
+            pos = place.reshape(T, k).T
+            # what ``bincount`` counts, without its scatter-add of T k integers
+            sizes = jnp.sum(key[:, None] == jnp.arange(n_held), axis=0, dtype=jnp.int32)
+        else:
+            sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
         ends = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
         n_local = ends[-1]
         tier = jnp.sum(n_local > base * jnp.asarray(tiers[:-1], jnp.int32))
@@ -208,19 +290,30 @@ def grouped_experts(h, chosen, weights, held: Tuple[int, int], w_gate, w_up, w_d
             # rows past the local assignments belong to no expert: what a grouped
             # product leaves in rows outside its groups is undefined, forward and
             # backward, so they are cut off on the way in as on the way out
-            x = jnp.where(live[:, None], h[token], 0)
+            if gathered:
+                x = spread_rows(h, token, live, *_rows_of(pos, start, base, n_local))
+            else:
+                x = jnp.where(live[:, None], h[token], 0)
         with jax.named_scope("lm.moe.experts"):
             gate = jax.lax.ragged_dot(x, w_gate, block_sizes)
             up = jax.lax.ragged_dot(x, w_up, block_sizes)
             y = jax.lax.ragged_dot(activation(gate) * up, w_down, block_sizes)
         with jax.named_scope("lm.moe.combine"):
-            w = jnp.where(live, flat_weights[rows], 0.0).astype(y.dtype)
+            if gathered:
+                w = spread_rows(flat_weights, rows, live,
+                                *_rows_of(place[None], start, base, n_local)).astype(y.dtype)
+            else:
+                w = jnp.where(live, flat_weights[rows], 0.0).astype(y.dtype)
             return token, jnp.where(live[:, None], y * w[:, None], 0.0)
 
     def run(blocks):
         def branch():
             token, y = jax.lax.map(one_block, jnp.arange(blocks))
             with jax.named_scope("lm.moe.combine"):
+                if gathered:
+                    rows = blocks * base
+                    return unpermute_sum(y.reshape(-1, d), token.reshape(-1),
+                                         jnp.arange(rows) < n_local, *_rows_of(pos, 0, rows, n_local))
                 return jnp.zeros_like(h).at[token.reshape(-1)].add(y.reshape(-1, d))
         return branch
 

@@ -203,3 +203,38 @@ def test_kimi_linear_ops_compile_for_v5e(v5e):
                 s((8, 2304, 1024), jnp.bfloat16), s((8, 1024, 2304), jnp.bfloat16))
     compiled = jax.jit(jax.grad(experts, argnums=(0, 3, 4, 5))).lower(*moe_args).compile()
     assert "ragged" in compiled.as_text()
+
+
+def test_a_large_tiers_expert_layer_moves_its_rows_by_gathers(v5e):
+    """``sim.fedavg.smallthinker.1chip``'s expert layer, forward and backward at the
+    cell's shape (16 of 64 experts, 6 a token: a block of three quarters of the
+    assignments), compiled for the described v5e: the combine and the dispatch's way
+    back are gathers and the experts' rows are counted without ``bincount``, so the
+    layer holds no scatter.  ``kimi-linear``'s shape, a block of an eighth, keeps its
+    row-sized scatter-adds."""
+    import re
+
+    from fedml_tpu.models import expert_lm
+
+    sharding = jax.sharding.SingleDeviceSharding(v5e)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def scatters(T, k, held, routed, d, f):
+        def experts(h, chosen, weights, w_gate, w_up, w_down):
+            out, counters = expert_lm.grouped_experts(h, chosen, weights, (0, held), w_gate,
+                                                      w_up, w_down, routed, jax.nn.relu)
+            return out.astype(jnp.float32).sum() + counters["moe.assignments_dropped"]
+
+        args = (s((T, d), jnp.bfloat16), s((T, k), jnp.int32), s((T, k), jnp.float32),
+                s((held, d, f), jnp.bfloat16), s((held, d, f), jnp.bfloat16),
+                s((held, f, d), jnp.bfloat16))
+        text = jax.jit(jax.value_and_grad(experts, argnums=(0, 2, 3, 4, 5))).lower(
+            *args).compile().as_text()
+        assert "ragged" in text
+        return re.findall(r"= (\w+)\[([\d,]*)\]\S* scatter\(", text)
+
+    assert scatters(16384, 6, 16, 64, 2560, 768) == []
+    kept = scatters(8192, 8, 8, 256, 2304, 1024)
+    assert any(dtype in ("bf16", "f32") and shape.startswith("8192,") for dtype, shape in kept)
