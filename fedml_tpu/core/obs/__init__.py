@@ -41,6 +41,7 @@ from .health import (
     Watchdog,
 )
 from .metrics import DEFAULT_TIME_BUCKETS, MetricsRegistry
+from .scopes import program_scopes, scope_seconds
 from .telemetry import (
     DEFAULT_FLUSH_S,
     DEFAULT_RING_CAPACITY,
@@ -70,6 +71,7 @@ __all__ = [
     "flight_recorder", "flight_dump", "exporter",
     "sample_resource_gauges", "compile_seconds_total",
     "trace_seconds_total", "compiles_total",
+    "program_scopes", "scope_seconds",
     "ClientTelemetry", "TelemetryMerger", "TOPIC_TELEMETRY",
     "telemetry_enabled", "telemetry_flush_s",
     "make_client_telemetry", "make_telemetry_merger",

@@ -199,12 +199,13 @@ def build_packed_device_fn(
                     )
                     extra = algo.engine_extra(cex_i, server_state)
                 grads = grad_hook(grads, params, params0, extra)
-            updates, new_opt = tx.update(grads, opt_state, params)
+            with jax.named_scope("fed.sgd"):
+                updates, new_opt = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             # every step the loop runs holds a real row (pack_round gives an
             # epoch ceil(n_i/B) steps and the loop stops at n_steps), so
             # optimizer state and mutable collections advance unconditionally
-            return (optax.apply_updates(params, updates), updated or other,
-                    new_opt, lval, bmask, counts)
+            return (params, updated or other, new_opt, lval, bmask, counts)
 
         def client_step(carry):
             (step, params, other, opt_state, c_steps, c_loss, c_cnt, ctr, _) = carry

@@ -210,10 +210,11 @@ def build_loss_fn(module, has_dropout: bool = True, loss: str = "ce",
         else:
             logits = module.apply(variables, bx, train=True, rngs=rngs)
             updated = {}
-        loss_val = main = loss_kind(logits, by, bmask)[0]
         updated = dict(updated)
-        if takes_targets:
-            loss_val = main + sum(jax.tree_util.tree_leaves(updated.pop("losses", {})))
+        with jax.named_scope("fed.loss"):
+            loss_val = main = loss_kind(logits, by, bmask)[0]
+            if takes_targets:
+                loss_val = main + sum(jax.tree_util.tree_leaves(updated.pop("losses", {})))
         if not counters:
             return loss_val, updated
         sown = jax.tree_util.tree_flatten_with_path(updated.pop("counters", {}))[0]
@@ -294,8 +295,9 @@ def build_local_train(
                     grads = grad_hook(grads, params, anchor, extra)
                 # Zero the step entirely if the batch is all padding.
                 any_valid = jnp.sum(bmask) > 0
-                updates, new_opt = tx.update(grads, opt_state, params)
-                new_params = optax.apply_updates(params, updates)
+                with jax.named_scope("fed.sgd"):
+                    updates, new_opt = tx.update(grads, opt_state, params)
+                    new_params = optax.apply_updates(params, updates)
                 params = jax.tree_util.tree_map(
                     lambda new, old: jnp.where(any_valid, new, old), new_params, params
                 )

@@ -473,7 +473,8 @@ class DecoderLM(nn.Module):
             tokens = tokens[:, :self.init_length]
         embed = self.param("embed", _normal(cfg.hidden_size),
                            (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        x = embed.astype(cfg.dtype)[tokens]
+        with jax.named_scope("lm.embed"):
+            x = embed.astype(cfg.dtype)[tokens]
         block_cls = (nn.remat(self.block_cls, static_argnums=(2,),
                               policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
                      if cfg.remat else self.block_cls)
@@ -490,4 +491,5 @@ class DecoderLM(nn.Module):
                     "build_loss_fn does")
             with jax.named_scope("lm.mtp"):
                 PredictionModule(cfg, block_cls, name="mtp")(x, tokens, embed, head, targets, train)
-        return rms_norm(x, scale, cfg.rms_norm_eps) @ head.astype(cfg.dtype)
+        with jax.named_scope("lm.head"):
+            return rms_norm(x, scale, cfg.rms_norm_eps) @ head.astype(cfg.dtype)

@@ -165,8 +165,11 @@ class Block(nn.Module):
         with jax.named_scope("lm.mla"):
             x = x + MLAMixer(cfg, cfg.q_lora_rank, cfg.rope_theta, name="mla")(norm("mixer_norm"))
         if self.index < cfg.first_k_dense_replace:
-            return x + DenseMLP(cfg, cfg.intermediate_size, name="mlp")(norm("ffn_norm"))
-        return x + ExpertShare(cfg, name="moe")(norm("ffn_norm"), train)
+            with jax.named_scope("lm.mlp"):
+                return x + DenseMLP(cfg, cfg.intermediate_size, name="mlp")(norm("ffn_norm"))
+        with jax.named_scope("lm.norm"):
+            h = norm("ffn_norm")
+        return x + ExpertShare(cfg, name="moe")(h, train)
 
 
 class Glm4MoeLiteLM(DecoderLM):

@@ -194,8 +194,11 @@ class Block(nn.Module):
         else:
             raise ValueError(f"layer {self.index + 1} is in neither kda_layers nor full_attn_layers")
         if self.index < cfg.first_k_dense_replace:
-            return x + DenseMLP(cfg, cfg.intermediate_size, name="mlp")(norm("ffn_norm"))
-        return x + ExpertShare(cfg, name="moe")(norm("ffn_norm"), train)
+            with jax.named_scope("lm.mlp"):
+                return x + DenseMLP(cfg, cfg.intermediate_size, name="mlp")(norm("ffn_norm"))
+        with jax.named_scope("lm.norm"):
+            h = norm("ffn_norm")
+        return x + ExpertShare(cfg, name="moe")(h, train)
 
 
 class KimiLinearLM(DecoderLM):

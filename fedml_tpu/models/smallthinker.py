@@ -156,7 +156,8 @@ class Block(nn.Module):
             scale = self.param(name, nn.initializers.ones, (d,), jnp.float32)
             return rms_norm(x, scale, cfg.rms_norm_eps)
 
-        a = norm("attn_norm")
+        with jax.named_scope("lm.norm"):  # router and attention share it
+            a = norm("attn_norm")
         with jax.named_scope("lm.moe.route"):
             w_r = self.param("router", _normal(d), (d, cfg.n_routed_experts), jnp.float32)
             logits = jnp.matmul(a.reshape(-1, d).astype(jnp.float32), w_r,
@@ -166,7 +167,9 @@ class Block(nn.Module):
         with jax.named_scope("lm.attn.window" if windowed else "lm.attn.global"):
             x = x + GQAMixer(cfg, cfg.sliding_window_size if windowed else None,
                              bool(cfg.rope_layout[self.index]), name="attn")(a)
-        return x + ExpertShare(cfg, jax.nn.relu, name="moe")(norm("ffn_norm"), train, routing)
+        with jax.named_scope("lm.norm"):
+            h = norm("ffn_norm")
+        return x + ExpertShare(cfg, jax.nn.relu, name="moe")(h, train, routing)
 
 
 class SmallThinkerLM(DecoderLM):

@@ -69,24 +69,28 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions, train: bool = False):
         cfg = self.cfg
-        h = nn.RMSNorm(dtype=cfg.dtype, name="attn_norm")(x)
-        d_head = cfg.d_model // cfg.n_heads
-        qkv = nn.DenseGeneral((3, cfg.n_heads, d_head), axis=-1, use_bias=False,
-                              dtype=cfg.dtype, name="qkv")(h)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        q = rope(q, positions)
-        k = rope(k, positions)
-        attn = self.attention_fn(q, k, v)
-        attn = nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False,
-                               dtype=cfg.dtype, name="out_proj")(attn)
-        x = x + attn
-        h = nn.RMSNorm(dtype=cfg.dtype, name="mlp_norm")(x)
-        gate = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype, name="wi_gate")(h)
-        up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype, name="wi_up")(h)
-        h = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype, name="wo")(
-            nn.silu(gate) * up
-        )
-        return x + h
+        # the scopes are names on the device trace's time (core/obs/scopes.py):
+        # each holds its norm, its matmuls and its residual add
+        with jax.named_scope("lm.attn"):
+            h = nn.RMSNorm(dtype=cfg.dtype, name="attn_norm")(x)
+            d_head = cfg.d_model // cfg.n_heads
+            qkv = nn.DenseGeneral((3, cfg.n_heads, d_head), axis=-1, use_bias=False,
+                                  dtype=cfg.dtype, name="qkv")(h)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            q = rope(q, positions)
+            k = rope(k, positions)
+            attn = self.attention_fn(q, k, v)
+            attn = nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False,
+                                   dtype=cfg.dtype, name="out_proj")(attn)
+            x = x + attn
+        with jax.named_scope("lm.mlp"):
+            h = nn.RMSNorm(dtype=cfg.dtype, name="mlp_norm")(x)
+            gate = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype, name="wi_gate")(h)
+            up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype, name="wi_up")(h)
+            h = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype, name="wo")(
+                nn.silu(gate) * up
+            )
+            return x + h
 
 
 class TransformerLM(nn.Module):
@@ -100,11 +104,13 @@ class TransformerLM(nn.Module):
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1]), tokens.shape
             )
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="embed")(tokens)
+        with jax.named_scope("lm.embed"):
+            x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="embed")(tokens)
         block_cls = Block
         if cfg.remat:
             block_cls = nn.remat(Block, static_argnums=(3,))
         for i in range(cfg.n_layers):
             x = block_cls(cfg, self.attention_fn, name=f"layer{i}")(x, positions, train)
-        x = nn.RMSNorm(dtype=cfg.dtype, name="final_norm")(x)
-        return nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype, name="lm_head")(x)
+        with jax.named_scope("lm.head"):
+            x = nn.RMSNorm(dtype=cfg.dtype, name="final_norm")(x)
+            return nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype, name="lm_head")(x)

@@ -37,11 +37,12 @@ psum (reference ``simulation/mpi/*`` parity, SURVEY.md §2.5).
 
 from __future__ import annotations
 
+import json
 import logging
 import os
 import time
 import warnings
-from typing import Any, Dict, List, NamedTuple, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...core import obs
 from ...core.dp.fedml_differential_privacy import FedMLDifferentialPrivacy
+from ...core.obs.scopes import table_json
 from ...core.schedule import RuntimeEstimator, SeqTrainScheduler
 from ...core.security.fedml_attacker import FedMLAttacker
 from ...core.security.fedml_defender import FedMLDefender
@@ -65,6 +67,25 @@ logger = logging.getLogger(__name__)
 # enable_profiler traces the second to fourth rounds a train() call runs
 # (rounds 1-3 of a fresh run; the first round compiles) and stops
 PROFILED_ROUNDS = (1, 3)
+
+
+def _abstract(x) -> jax.ShapeDtypeStruct:
+    """A round input's shape, dtype and, where it is committed to one, sharding."""
+    sharding = x.sharding if getattr(x, "committed", False) else None
+    return jax.ShapeDtypeStruct(np.shape(x), jnp.result_type(x), sharding=sharding)
+
+
+def _compile_anew(lowered):
+    """Compile ``lowered`` past both of jax's caches, for the metadata of THIS program:
+    an inert option keeps the executable jax holds in memory out of it, and with the
+    metadata in its key the persistent cache holds no entry that another program wrote."""
+    key = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, key)
+    jax.config.update(key, True)
+    try:
+        return lowered.compile(compiler_options={"xla_dump_max_hlo_modules": 1})
+    finally:
+        jax.config.update(key, before)
 
 
 class _Phase:
@@ -406,6 +427,9 @@ class XLASimulator:
             int(getattr(self.args, "epochs", 1)),
         )
         self._seen_buckets = set()  # stream buckets a round has compiled
+        # the abstract call of the newest bucket's first round, and the table
+        # round_scopes() makes of it on request
+        self._round_signature = self._round_scopes = None
         stacked = self.needs_stack
         sharded = self.sharded_state
         device_fn = build_packed_device_fn(
@@ -708,11 +732,11 @@ class XLASimulator:
                 if evaluated is not None:
                     last = evaluated
                 if profiling and round_idx == prof_last:
-                    jax.profiler.stop_trace()
+                    self._stop_profiler(prof_dir)
                     profiling = False
         finally:
             if profiling:  # the run ended, or raised, inside the traced rounds
-                jax.profiler.stop_trace()
+                self._stop_profiler(prof_dir)
         if prof_dir is not None and comm_round <= prof_first:
             logger.warning(
                 "enable_profiler traces rounds %d-%d of a run and this one ended "
@@ -806,6 +830,10 @@ class XLASimulator:
         takes the round from where it ends — none (the server step is inside
         it), the model-sharded server tail, or the security program.  Returns
         (mean loss, per-slot algorithm outs, the module's round counters)."""
+        if self._bucket_compiling:
+            # a bucket's first round: keep what round_scopes() lowers the program from
+            self._round_signature = jax.tree_util.tree_map(_abstract, round_inputs)
+            self._round_scopes = None
         if self.needs_stack:
             # the round returns the sharded per-client update stack
             mean_loss, outs, ext, *counters = self._round_fn(*round_inputs)
@@ -822,6 +850,43 @@ class XLASimulator:
             self.variables, self.server_state, mean_loss, outs, *counters = self._round_fn(
                 *round_inputs)
         return mean_loss, outs, (counters[0] if counters else {})
+
+    def round_scopes(self) -> Optional[Dict[str, str]]:
+        """{instruction name: op_name} of the compiled round program (the newest
+        stream bucket's): what joins a device trace's op line, which names an
+        event by its HLO instruction, to the program's ``jax.named_scope``s
+        (``core/obs/scopes.py``).  Made on the first request, by lowering and
+        compiling the round once more from its first call's signature (a hit of
+        the compilation cache), and kept; a run that reads no trace never asks.
+        None before the first round and where the lowering fails."""
+        if self._round_scopes is None and self._round_signature is not None:
+            try:
+                lowered = self._round_fn.lower(*self._round_signature)
+                table = obs.program_scopes(lowered.compile().as_text())
+                if not any("fed.sgd" in op_name for op_name in table.values()):
+                    # every packed step opens fed.sgd: this executable came out of a compile
+                    # cache (its key leaves metadata out) that a program without the scope filled
+                    logger.warning("round_scopes: the compilation cache handed the round an "
+                                   "executable compiled under other scopes; compiling it anew")
+                    table = obs.program_scopes(_compile_anew(lowered).as_text())
+                self._round_scopes = table
+            except Exception:  # noqa: BLE001 - telemetry: never raised into a round
+                logger.warning("round_scopes: the round program could not be lowered again",
+                               exc_info=True)
+                self._round_signature = None  # logged once
+        return self._round_scopes
+
+    def _stop_profiler(self, prof_dir: str) -> None:
+        """Stop the trace and leave ``round_scopes.json`` beside the xplane it wrote."""
+        jax.profiler.stop_trace()
+        path = os.path.join(prof_dir, "round_scopes.json")
+        try:
+            with open(path, "w") as f:
+                json.dump(table_json(self.round_scopes()), f)
+            logger.info("the traced round's instruction-to-scope table -> %s", path)
+        except OSError:
+            logger.warning("round_scopes.json could not be written to %s", prof_dir,
+                           exc_info=True)
 
     def _apply_server_tail(self, acc, wsum, ext):
         """server_state=sharded: the algorithm's server step, by the GSPMD tail
