@@ -1,7 +1,7 @@
 """Pallas flash attention (TPU kernel) and its fused-XLA reference.
 
 The single-chip hot path of the transformer stack: blockwise attention with
-online softmax.  Grid is (batch·heads, L/block_q, L/block_k) — TPU executes
+online softmax.  Grid is (batch·heads, q blocks, k blocks) — TPU executes
 the innermost grid dimension sequentially per core, so the running
 (max, denom, out) accumulators live in VMEM scratch across k-steps and only
 [block_q, D] / [block_k, D] tiles are VMEM-resident (never the full K/V, so
@@ -20,9 +20,9 @@ forward rule names its output and the log-sum-exp (``flash_fwd.out``,
 ``flash_fwd.lse``: ``ops/kept.py``), so a caller that recomputes its layers
 can keep those two and find the kernel's second call dead.
 
-How blocks are chosen.  A grid step costs about 0.35 µs on a v5e whatever it
-computes, and a 128 x 128 tile's matmuls a tenth of that, so the tiling, not
-the MXU, sets the kernels' time.  ``block_q`` / ``block_k`` left ``None``
+How blocks are chosen.  A grid step costs 0.1-0.35 µs on a v5e whatever it
+computes (the more where it waits for a copy), a 128 x 128 tile's matmuls a
+tenth of that, so the tiling, not the MXU, sets the kernels' time.  ``block_q`` / ``block_k`` left ``None``
 are chosen per kernel by :func:`_choose_blocks`, a pure function of L, D and
 the input dtype: the largest multiples of 128 that divide the lane-rounded
 length, up to the pair the on-chip sweep read best for that kernel
@@ -30,29 +30,39 @@ length, up to the pair the on-chip sweep read best for that kernel
 (``_VMEM_BUDGET``, ``_vmem_bytes``).  Explicit blocks are taken as given
 (interpret-mode tests pass small ones); there is no option, environment
 variable or run-time autotune.  What was chosen is left as the gauges
-``flash.block_q``, ``flash.block_k`` and ``flash.live_step_share`` per kernel
-name (docs/OBSERVABILITY.md).
+``flash.block_q``, ``flash.block_k``, ``flash.grid_steps`` and
+``flash.live_step_share`` per kernel name (docs/OBSERVABILITY.md).
 
-Dead tiles cost a step and no copy.  Under the causal mask a tile whose keys
-all lie after its queries (and any tile of padding) is dead, and under a
-``window`` (query ``t`` sees the keys ``s`` with ``0 <= t - s < window``) so is
-one whose keys all lie before the window of all its queries: its step still
-runs — the grid is rectangular and no scalar-prefetched schedule is added —
-but computes nothing, and its BlockSpec index is clamped into the live tiles
-of the row (of the column in the keys-major pass), which names the block
-already in VMEM, or the first live one, which it prefetches, so the pipeline
-issues no copy for it.  A live tile that the diagonal, the window's far edge
-and the padded tail do not cross is all live and takes an unmasked path (no
-iotas, compare or select); only the others build a mask.
+The grid holds its live tiles and no rectangle of them.  Under the causal mask
+a tile whose keys all lie after its queries (and any tile of padding) is dead,
+and under a ``window`` (query ``t`` sees the keys ``s`` with ``0 <= t - s <
+window``) so is one whose keys all lie before the window of all its queries.
+The live tiles of a row (of a column in the keys-major pass) are one
+contiguous run, short at one end of the causal triangle and long at the other
+(under a window: no longer than the band), so one step of the outer block
+axis walks row ``i`` and then row ``n - 1 - i`` and the sequential inner axis
+is as long as the longest such pair: at the cells' shapes every step of a
+global call is live, and 7 of 8 of a windowed one's (the band's first rows
+are short).  Which row and tile a step is comes from its two program ids by a
+compare and a subtract (``_paired_walk``; no scalar-prefetched schedule, no
+operand added); the accumulators are zeroed at each row's first tile and the
+output written at its last, so an output block is visited in one contiguous
+run and its tiles are folded in ascending order, as in a rectangle.  A step
+past both walks names the block already in VMEM and computes nothing.  A call
+that would save no step (no causal mask, one block, every run equally long)
+keeps the rectangular grid ``(heads, L/block_q, L/block_k)``, whose dead
+steps run, copy nothing (their BlockSpec index is clamped into the row's live
+run) and compute nothing.  A live tile that the diagonal, the window's far
+edge and the padded tail do not cross is all live and takes an unmasked path
+(no iotas, compare or select); only the others build a mask.
 
 Grouped kv heads.  k and v may have fewer heads than q (``Hq % Hkv == 0``;
 query head ``h`` reads kv head ``h // (Hq // Hkv)``).  They are never repeated
 in HBM: the grids run over ``B*Hq`` query heads and the k/v BlockSpecs' index
 maps send a query head's index to its kv head's (``_kv_head``); the dK/dV
-pass runs over ``B*Hkv`` kv heads and its sequential inner axis walks the q
-blocks of each of the group's query heads in turn, so the group's sum forms in
-the VMEM accumulators and dK / dV leave at ``Hkv`` heads.  With equal head
-counts and no window every index map and kernel body is what it was.
+pass runs over ``B*Hkv`` kv heads and its sequential inner axis walks a
+column's q blocks for each of the group's query heads in turn, so the group's
+sum forms in the VMEM accumulators and dK / dV leave at ``Hkv`` heads.
 
 Layouts are the ones Mosaic accepts: per-row softmax statistics are
 ``[block_q, 1]`` columns inside a kernel (they broadcast along lanes against
@@ -74,6 +84,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -197,31 +208,61 @@ def _tile_mask(qi, kj, shape, q_dim, *, block_q, block_k, causal, valid_len,
     return live
 
 
-def _live_k_block(i, j, *, block_q, block_k, causal, valid_len, window=None):
-    """Index map of a K/V block in a queries-major grid: ``j`` clamped into
-    the live tiles of row ``i`` (from the tile that holds the first key of the
-    first query's window to the one the diagonal or the tail ends in), so a
-    dead step names a block that is resident, or the row's first live one,
-    which it prefetches, and the pipeline copies nothing else."""
+def _div(a, b):
+    """``a // b`` of non-negative integers: one ``div`` on a program id (no
+    sign fix), numpy's on a trace-time index grid."""
+    return jax.lax.div(a, b) if isinstance(a, jax.Array) else a // b
+
+
+def _xp(i):
+    """The tile arithmetic below runs on program ids (kernels, index maps) and,
+    at trace time, on numpy index grids (``_tiling``'s grid and gauges, tests)."""
+    return jnp if isinstance(i, jax.Array) else np
+
+
+def _live_k_run(i, *, block_q, block_k, causal, valid_len, window=None):
+    """(first, last) K/V block of row ``i`` of a queries-major grid: its live
+    tiles are that one contiguous run, from the tile that holds the first key
+    of the first query's window to the one the diagonal or the tail ends in.
+    (A row of padding alone has no live tile and still a run of one or more.)"""
+    xp = _xp(i)
     last = (valid_len - 1) // block_k
     if causal:
-        last = jnp.minimum(last, jax.lax.div((i + 1) * block_q - 1, block_k))
+        last = xp.minimum(last, _div((i + 1) * block_q - 1, block_k))
     if window is None:
-        return jnp.minimum(j, last)
-    first = jax.lax.div(jnp.maximum(i * block_q - window + 1, 0), block_k)
-    return jnp.clip(j, jnp.minimum(first, last), last)
+        return 0, last
+    first = _div(xp.maximum(i * block_q - window + 1, 0), block_k)
+    return xp.minimum(first, last), last
 
 
-def _live_q_block(i, j, *, block_q, block_k, causal, valid_len, window=None):
-    """Index map of a q-side block (q, dO, lse, delta) in the keys-major
-    grid: ``i`` clamped into the live tiles of column ``j``, which under the
-    causal mask start at the diagonal — the dead steps before it prefetch it —
-    and under a window end with the last query that sees the column's last key."""
+def _live_q_run(j, *, block_q, block_k, causal, valid_len, window=None):
+    """(first, last) q-side block (q, dO, lse, delta) of column ``j`` of the
+    keys-major grid: its live tiles start under the causal mask at the
+    diagonal and end under a window with the last query that sees the
+    column's last key."""
+    xp = _xp(j)
     last = (valid_len - 1) // block_q
     if window is not None:
-        last = jnp.minimum(last, jax.lax.div((j + 1) * block_k + window - 2, block_q))
-    first = jnp.minimum(jax.lax.div(j * block_k, block_q), last) if causal else 0
-    return jnp.clip(i, first, last)
+        last = xp.minimum(last, _div((j + 1) * block_k + window - 2, block_q))
+    first = xp.minimum(_div(j * block_k, block_q), last) if causal else 0
+    return first, last
+
+
+def _live_k_block(i, j, **tile):
+    """Index map of a K/V block in the rectangular queries-major grid: ``j``
+    clamped into the live run of row ``i``, so a dead step names a block that
+    is resident, or the row's first live one, which it prefetches, and the
+    pipeline copies nothing else."""
+    first, last = _live_k_run(i, **tile)
+    xp = _xp(j)
+    return xp.minimum(j, last) if tile.get("window") is None else xp.clip(j, first, last)
+
+
+def _live_q_block(i, j, **tile):
+    """Index map of a q-side block in the rectangular keys-major grid: ``i``
+    clamped into the live run of column ``j`` (the dead steps before the
+    diagonal prefetch it)."""
+    return _xp(i).clip(i, *_live_q_run(j, **tile))
 
 
 # VMEM a step may plan for: the 16 MiB that Mosaic gives a kernel by default on
@@ -321,15 +362,76 @@ def _geometry(L, D, dtype, block_q, block_k, Dv=None):
     return blocks, -(-L // m) * m
 
 
+def _rect_walk(n, inner, reps, clamp):
+    """The rectangular grid ``(n, reps * inner)``: step ``(o, s)`` is member
+    (row, or column in the keys-major pass) ``o`` at tile ``s``, for each of
+    ``reps`` query heads in turn (``s = g * inner + tile``).  A dead tile's
+    step runs and names the block ``clamp`` gives."""
+    def tile(s):
+        return s if reps == 1 else jax.lax.rem(s, inner)
+
+    def step(o, s):
+        return o, tile(s), s == 0, s == reps * inner - 1, None
+
+    def block(o, s):
+        return o, clamp(o, tile(s)), 0 if reps == 1 else jax.lax.div(s, inner)
+
+    return (n, reps * inner), step, block
+
+
+def _paired_walk(n, steps, reps, run):
+    """The grid ``(ceil(n / 2), steps)`` that holds live tiles alone: outer
+    step ``o`` walks member ``o``'s live run (``run(o)``: first, last), once
+    for each of ``reps`` query heads, and then member ``n - 1 - o``'s, a short
+    one of the causal triangle with a long one, so that every pair takes
+    (nearly) the same ``steps``.  Which member and tile a step is comes from
+    its two program ids by a compare and a subtract; a step past both walks
+    (a window's first rows are short) stays on the pair's last block and is
+    not ``inside``; an odd ``n``'s middle member walks alone."""
+    def locate(o, s):
+        xp = _xp(s)
+        a, b = o, n - 1 - o
+        (first_a, last_a), (first_b, last_b) = run(a), run(b)
+        len_a, len_b = last_a - first_a + 1, last_b - first_b + 1
+        in_a = s < len_a * reps
+        run_len = xp.where(in_a, len_a, len_b)
+        pos = xp.where(in_a, s, s - len_a * reps)
+        inside = pos < run_len * reps
+        if n % 2:
+            inside = inside & (in_a | (a != b))
+        at = xp.minimum(pos, run_len * reps - 1)
+        rep = 0 if reps == 1 else _div(at, run_len)
+        tile = xp.where(in_a, first_a, first_b) + at - rep * run_len
+        return xp.where(in_a, a, b), tile, rep, pos, run_len * reps, inside
+
+    def step(o, s):
+        member, tile, _, pos, length, inside = locate(o, s)
+        return member, tile, inside & (pos == 0), inside & (pos == length - 1), inside
+
+    def block(o, s):
+        return locate(o, s)[:3]
+
+    return (-(-n // 2), steps), step, block
+
+
 def _tiling(kernel, Lp, blocks, causal, valid_len, window=None, group=1):
-    """One call's tile parameters (the keywords of the ``_tile_*`` predicates)
-    and its grid extents (q blocks, k blocks).  Leaves what was chosen as
-    gauges per kernel name (trace time: Python, from shapes): the blocks, live
-    grid steps over grid steps, the window (0: none) and the query heads a kv
+    """One call's tile parameters (the keywords of the ``_tile_*`` predicates),
+    its grid's two block axes and the two functions of their program ids that
+    say where a step is: ``step(o, s)`` -> (member, tile, first, last, inside)
+    for the kernel (the member's accumulators start at ``first`` and leave at
+    ``last``; ``inside`` None: every step is) and ``block(o, s)`` -> (member,
+    tile, query head of the kv head's group) for the index maps.  A member is
+    a q block and a tile a K/V block, or the other way round in the keys-major
+    ``flash_bwd_dkv``, whose walk repeats for each of ``group`` query heads.
+
+    A causal call's grid is the paired walk of its live runs wherever that
+    takes fewer steps than the rectangle; any other call keeps the rectangle.
+
+    Leaves what was chosen as gauges per kernel name (trace time: Python, from
+    shapes): the blocks, the steps the grid runs for one head of its first
+    axis, live steps over those, the window (0: none) and the query heads a kv
     head serves.  A windowed call's gauges carry the label ``window`` beside
     ``kernel``, so that a model with both kinds of layer keeps both readings."""
-    import numpy as np
-
     from ..core import obs
 
     block_q, block_k = blocks
@@ -339,12 +441,30 @@ def _tiling(kernel, Lp, blocks, causal, valid_len, window=None, group=1):
         tile["window"] = labels["window"] = int(window)
     n_qb, n_kb = Lp // block_q, Lp // block_k
     live = _tile_live(np.arange(n_qb)[:, None], np.arange(n_kb)[None, :], **tile)
+    if kernel == "flash_bwd_dkv":
+        n, inner, reps = n_kb, n_qb, group
+        run = functools.partial(_live_q_run, **tile)
+        clamp = lambda j, i: _live_q_block(i, j, **tile)
+    else:
+        n, inner, reps = n_qb, n_kb, 1
+        run = functools.partial(_live_k_run, **tile)
+        clamp = functools.partial(_live_k_block, **tile)
+    first, last = run(np.arange(n))
+    length = np.broadcast_to(last - first + 1, (n,))
+    members = np.arange(n)  # an odd n's middle member is its own partner and walks once
+    pair = length + np.where(members == members[::-1], 0, length[::-1])
+    if causal and n > 1 and -(-n // 2) * pair.max() < n * inner:
+        grid, step, block = _paired_walk(n, int(pair.max()) * reps, reps, run)
+    else:
+        grid, step, block = _rect_walk(n, inner, reps, clamp)
+    grid_steps = grid[0] * grid[1]
     obs.gauge_set("flash.block_q", block_q, labels)
     obs.gauge_set("flash.block_k", block_k, labels)
-    obs.gauge_set("flash.live_step_share", float(np.mean(live)), labels)
+    obs.gauge_set("flash.grid_steps", grid_steps, labels)
+    obs.gauge_set("flash.live_step_share", float(np.sum(live)) * reps / grid_steps, labels)
     obs.gauge_set("flash.window", window or 0, labels)
     obs.gauge_set("flash.kv_group", group, labels)
-    return tile, n_qb, n_kb
+    return tile, grid, step, block
 
 
 def _kv_head(group):
@@ -388,23 +508,27 @@ def _scratch(block_q, D):
     ]
 
 
-def _when_live(qi, kj, tile, step):
+def _when_live(qi, kj, tile, step, inside=None):
     """Run ``step(masked)`` for a live tile: unmasked where the whole tile is
-    live, with the mask only where the diagonal or the padded tail crosses."""
+    live, with the mask only where the diagonal or the padded tail crosses.
+    ``inside``: the grid step is one of its walk's (None: every step is)."""
     live = _tile_live(qi, kj, **tile)
+    if inside is not None:
+        live = live & inside
     interior = _tile_interior(qi, kj, **tile)
     pl.when(live & interior)(functools.partial(step, False))
     pl.when(live & jnp.logical_not(interior))(functools.partial(step, True))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-                  n_kb, scale, tile):
-    """Grid cell (bh, qi, kj): fold K/V block kj into q block qi's online
-    softmax state (scratch persists across the sequential kj dimension)."""
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+                  walk, scale, tile):
+    """Grid cell (bh, o, s), which ``walk`` places at q block qi and K/V block
+    kj: fold the K/V block into the q block's online softmax state (scratch
+    persists across the sequential inner dimension, which walks the K/V blocks
+    of one q block and then, in the paired grid, those of a second)."""
+    qi, kj, first, last, inside = walk(pl.program_id(1), pl.program_id(2))
 
-    @pl.when(kj == 0)
+    @pl.when(first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -418,9 +542,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
             s = jnp.where(_tile_mask(qi, kj, s.shape, 0, **tile), s, -jnp.inf)
         _fold_block(s, v_ref[0], m_ref, l_ref, acc_ref)
 
-    _when_live(qi, kj, tile, _attend)
+    _when_live(qi, kj, tile, _attend, inside)
 
-    @pl.when(kj == n_kb - 1)
+    @pl.when(last)
     def _finish():
         l = l_ref[...]
         m = m_ref[...]
@@ -456,19 +580,19 @@ def _fwd_call(qb, kb, vb, blocks, causal, valid_len, interpret, scale=None,
     Dv = vb.shape[-1]
     block_q, block_k = blocks
     group = BH // kb.shape[0]
-    tile, n_qb, n_kb = _tiling("flash_fwd", Lp, blocks, causal, valid_len, window, group)
+    tile, grid, walk, block = _tiling("flash_fwd", Lp, blocks, causal, valid_len, window, group)
     kv_head = _kv_head(group)
     q_spec, k_spec, o_spec, v_spec = _specs(
-        D, Dv, block_q, block_k, lambda b, i, j: (b, i),
-        lambda b, i, j: (kv_head(b), _live_k_block(i, j, **tile)))
+        D, Dv, block_q, block_k, lambda b, o, s: (b, block(o, s)[0]),
+        lambda b, o, s: (kv_head(b), block(o, s)[1]))
     return pl.pallas_call(
-        functools.partial(_flash_kernel, n_kb=n_kb,
+        functools.partial(_flash_kernel, walk=walk,
                           scale=float(scale or 1.0 / (D**0.5)), tile=tile),
-        grid=(BH, n_qb, n_kb),
+        grid=(BH, *grid),
         in_specs=[q_spec, k_spec, v_spec],
         out_specs=[
             o_spec,
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, o, s: (b, 0, block(o, s)[0])),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Lp, Dv), qb.dtype),
@@ -529,13 +653,12 @@ def _block_grads(q, k, v, do, lse, delta, mask, *, scale, keys_major):
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, acc_ref, *, n_kb, scale, tile):
-    """Grid cell (bh, qi, kj): accumulate q block qi's gradient over k blocks
-    (sequential innermost kj; acc persists in VMEM scratch)."""
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+                         dq_ref, acc_ref, *, walk, scale, tile):
+    """Grid cell (bh, o, s), placed by ``walk`` like the forward's: accumulate
+    q block qi's gradient over its K/V blocks (acc persists in VMEM scratch)."""
+    qi, kj, first, last, inside = walk(pl.program_id(1), pl.program_id(2))
 
-    @pl.when(kj == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -551,27 +674,24 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         acc_ref[...] += jax.lax.dot_general(
             ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-    _when_live(qi, kj, tile, _accum)
+    _when_live(qi, kj, tile, _accum, inside)
 
-    @pl.when(kj == n_kb - 1)
+    @pl.when(last)
     def _finish():
         dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, n_qb, scale, tile,
-                          group=1):
-    """Grid cell (kv head, kj, t): accumulate k/v block kj's gradients over
-    the q blocks (sequential innermost axis) of every query head the kv head
-    serves, one head's ``n_qb`` blocks after the other's (``t = g * n_qb +
-    qi``; one head: ``t = qi``), so a group's sum is formed in the VMEM
+                          dk_ref, dv_ref, dk_acc, dv_acc, *, walk, scale, tile):
+    """Grid cell (kv head, o, s), which ``walk`` places at K/V block kj and q
+    block qi: accumulate the K/V block's gradients over its q blocks
+    (sequential innermost axis) of every query head the kv head serves, one
+    head's blocks after the other's, so a group's sum is formed in the VMEM
     accumulators and dK / dV leave at the kv heads' count.  p is zero wherever
     q_pos < k_pos, so the q blocks entirely above kj are dead tiles."""
-    kj = pl.program_id(1)
-    t = pl.program_id(2)
-    qi = t if group == 1 else jax.lax.rem(t, n_qb)
+    kj, qi, first, last, inside = walk(pl.program_id(1), pl.program_id(2))
 
-    @pl.when(t == 0)
+    @pl.when(first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -590,9 +710,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] += jax.lax.dot_general(
             ds_t.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32)
 
-    _when_live(qi, kj, tile, _accum)
+    _when_live(qi, kj, tile, _accum, inside)
 
-    @pl.when(t == group * n_qb - 1)
+    @pl.when(last)
     def _finish():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -607,16 +727,17 @@ def _dq_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret,
     Dv = vb.shape[-1]
     block_q, block_k = blocks
     group = BH // kb.shape[0]
-    tile, n_qb, n_kb = _tiling("flash_bwd_dq", Lp, blocks, causal, valid_len, window, group)
+    tile, grid, walk, block = _tiling("flash_bwd_dq", Lp, blocks, causal, valid_len, window,
+                                      group)
     kv_head = _kv_head(group)
     q_spec, k_spec, do_spec, v_spec = _specs(
-        D, Dv, block_q, block_k, lambda b, i, j: (b, i),
-        lambda b, i, j: (kv_head(b), _live_k_block(i, j, **tile)))
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
+        D, Dv, block_q, block_k, lambda b, o, s: (b, block(o, s)[0]),
+        lambda b, o, s: (kv_head(b), block(o, s)[1]))
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, o, s: (b, 0, block(o, s)[0]))
     return pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, n_kb=n_kb,
+        functools.partial(_flash_bwd_dq_kernel, walk=walk,
                           scale=float(scale or 1.0 / (D**0.5)), tile=tile),
-        grid=(BH, n_qb, n_kb),
+        grid=(BH, *grid),
         in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype),
@@ -630,31 +751,32 @@ def _dq_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret,
 def _dkv_call(qb, kb, vb, dob, lse, delta, blocks, causal, valid_len, interpret,
               scale=None, window=None):
     """``flash_bwd_dkv`` over the same operands: (dK, dV) at the kv heads'
-    count, keys-major — the grid is (kv head, kj, t) and the q-side blocks
-    follow the inner axis, which walks the q blocks of each query head of the
-    kv head's group in turn (``_flash_bwd_dkv_kernel``)."""
+    count, keys-major — the grid's first axis is the kv heads and the q-side
+    blocks follow the inner axis, which walks the q blocks of each query head
+    of the kv head's group in turn (``_flash_bwd_dkv_kernel``)."""
     BH, Lp, D = kb.shape
     Dv = vb.shape[-1]
     block_q, block_k = blocks
     group = qb.shape[0] // BH
-    tile, n_qb, n_kb = _tiling("flash_bwd_dkv", Lp, blocks, causal, valid_len, window, group)
-    if group == 1:
-        q_at = lambda b, j, t: (b, _live_q_block(t, j, **tile))
-    else:
-        q_at = lambda b, j, t: (b * group + jax.lax.div(t, n_qb),
-                                _live_q_block(jax.lax.rem(t, n_qb), j, **tile))
-    q_spec, k_spec, do_spec, v_spec = _specs(
-        D, Dv, block_q, block_k, q_at, lambda b, j, t: (b, j))
+    tile, grid, walk, block = _tiling("flash_bwd_dkv", Lp, blocks, causal, valid_len, window,
+                                      group)
 
-    def row_at(b, j, t):
-        head, block = q_at(b, j, t)
-        return head, 0, block
+    def q_at(b, o, s):
+        _, qi, g = block(o, s)
+        return (b if group == 1 else b * group + g), qi
+
+    q_spec, k_spec, do_spec, v_spec = _specs(
+        D, Dv, block_q, block_k, q_at, lambda b, o, s: (b, block(o, s)[0]))
+
+    def row_at(b, o, s):
+        head, qi = q_at(b, o, s)
+        return head, 0, qi
 
     row_spec = pl.BlockSpec((1, 1, block_q), row_at)
     return pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, n_qb=n_qb, group=group,
+        functools.partial(_flash_bwd_dkv_kernel, walk=walk,
                           scale=float(scale or 1.0 / (D**0.5)), tile=tile),
-        grid=(BH, n_kb, group * n_qb),
+        grid=(BH, *grid),
         in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=[k_spec, v_spec],
         out_shape=[

@@ -121,7 +121,7 @@ def test_no_window_and_equal_heads_keep_their_geometry_and_outputs():
     new = _value_and_grads(lambda *a: fa.flash_attention(*a, True, 32, 32, True, None), (q, k, v))
     for a, b in zip(jax.tree_util.tree_leaves(old), jax.tree_util.tree_leaves(new)):
         np.testing.assert_array_equal(a, b)
-    tile, n_qb, n_kb = fa._tiling("flash_fwd", 2048, (1024, 1024), True, 2048)
+    tile = fa._tiling("flash_fwd", 2048, (1024, 1024), True, 2048)[0]
     assert tile == dict(block_q=1024, block_k=1024, causal=True, valid_len=2048)
     assert fa._kv_head(1)(5) == 5
     assert fa._choose_blocks("flash_bwd_dkv", 16384, 128, jnp.bfloat16) == (512, 512)
@@ -131,8 +131,12 @@ def test_no_window_and_equal_heads_keep_their_geometry_and_outputs():
     fa._tiling("flash_fwd", 16384, (1024, 1024), True, 16384, None, 7)
     gauges = {(r["metric"], r["labels"].get("kernel"), r["labels"].get("window")): r["value"]
               for r in obs.registry().export() if r["metric"].startswith("flash.")}
-    assert gauges[("flash.live_step_share", "flash_fwd", "4096")] == 70 / 256
-    assert gauges[("flash.live_step_share", "flash_fwd", None)] == 136 / 256
+    # the grids hold their live tiles: 8 pairs of rows at 10 steps under the window (the
+    # band's first rows are short), 17 steps a pair of the whole triangle
+    assert gauges[("flash.live_step_share", "flash_fwd", "4096")] == 70 / 80
+    assert gauges[("flash.live_step_share", "flash_fwd", None)] == 1.0
+    assert gauges[("flash.grid_steps", "flash_fwd", "4096")] == 80
+    assert gauges[("flash.grid_steps", "flash_fwd", None)] == 136
     assert gauges[("flash.window", "flash_fwd", "4096")] == 4096
     assert gauges[("flash.window", "flash_fwd", None)] == 0
     assert gauges[("flash.kv_group", "flash_fwd", "4096")] == 7

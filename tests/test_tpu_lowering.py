@@ -4,6 +4,8 @@ in the tree was refused this way until PR 21, and ``interpret=True`` tests
 could not see it).  Where libtpu can describe a v5e without a chip attached
 the kernels are also compiled, which runs Mosaic itself."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -140,6 +142,38 @@ def test_window_and_grouped_kv_heads_compile_under_mosaic(v5e, shape):
         compiled = jax.jit(fn).lower(*args).compile()
         if name == "grad":  # dK and dV leave at the kv heads' count: k and v were never repeated
             assert [o.shape for o in compiled.out_info] == [a.shape for a in args]
+
+
+# the four configurations' own attention calls, one sequence (two for dsllm7b-sim):
+# (B, L, Hq, Hkv, D, Dv, window)
+CELL_SHAPES = [(2, 2048, 32, 32, 128, 128, None), (1, 8192, 32, 32, 192, 128, None),
+               (1, 16384, 28, 4, 128, 128, 4096), (1, 16384, 28, 4, 128, 128, None),
+               (1, 8192, 20, 20, 256, 256, None)]
+
+
+@pytest.mark.parametrize("shape", CELL_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_the_cells_calls_keep_their_names_and_arity(shape):
+    """What the benchmark's readers find the kernels by: one custom call of each name a
+    layer, 3 operands -> 2 results, 6 -> 1 and 6 -> 2 (no scalar-prefetch operand), and a
+    first result of the operands' own shape [B * heads, L, width]."""
+    B, L, Hq, Hkv, D, Dv, window = shape
+    q, k, v = (jax.ShapeDtypeStruct((B, L, H, W), jnp.bfloat16)
+               for H, W in ((Hq, D), (Hkv, D), (Hkv, Dv)))
+    grad = jax.grad(lambda *a: flash_attention(*a, causal=True, window=window)
+                    .astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    text = jax.jit(grad).trace(q, k, v).lower(lowering_platforms=("tpu",)).as_text()
+    calls = {}
+    for line in text.splitlines():
+        if "@tpu_custom_call" in line:
+            name = re.search(r'kernel_name = "(\w+)"', line).group(1)
+            operands, results = line.rsplit(" : (", 1)[1].split(") -> ")
+            assert name not in calls, f"two calls named {name}"
+            calls[name] = (operands.count("tensor<"), results.count("tensor<"),
+                           re.search(r"tensor<(\w+)>", results).group(1))
+    Dp = 256 if D == 192 else D  # q and k of 192 are zero-padded to whole lanes
+    assert calls == {"flash_fwd": (3, 2, f"{B * Hq}x{L}x{Dv}xbf16"),
+                     "flash_bwd_dq": (6, 1, f"{B * Hq}x{L}x{Dp}xbf16"),
+                     "flash_bwd_dkv": (6, 2, f"{B * Hkv}x{L}x{Dp}xbf16")}
 
 
 # the KDA kernels at the benchmark cell's shape (kimi-linear-48b-a3b-sim, one
