@@ -26,11 +26,12 @@ from typing import Dict, Iterable, Optional, Tuple
 
 # the vocabulary, most specific first: the prediction module's block counts as
 # the module's (its ``lm.mla`` / ``lm.moe.*`` nest inside ``lm.mtp``), a
-# windowed or global mixer before the plain ``lm.attn`` its name contains,
+# windowed, global or block-diffusion mixer before the plain ``lm.attn`` its
+# name contains,
 # every model scope before the engine's and the round's
 SCOPES: Tuple[str, ...] = (
     "lm.mtp", "lm.kda", "lm.mla", "lm.moe.", "lm.attn.window", "lm.attn.global",
-    "lm.attn", "lm.mlp", "lm.embed", "lm.head", "lm.norm",
+    "lm.attn.bd", "lm.attn", "lm.bd.noise", "lm.mlp", "lm.embed", "lm.head", "lm.norm",
     "fed.loss", "fed.sgd", "fed.gather", "fed.flush", "fed.exchange", "fed.server_step")
 # what the table's last rows are called
 STEP_ALONE = "unscoped (fed.local_step alone)"  # inside the step, under no scope of its own

@@ -25,6 +25,11 @@ import optax
 Pytree = Any
 
 
+# the rng stream ``noise`` of a module that owns its loss: the step's key folded
+# with this (build_loss_fn); its evaluation's one key is ``PRNGKey(0)`` folded alike
+NOISE_STREAM = 0xBD
+
+
 class LocalTrainResult(NamedTuple):
     variables: Pytree
     loss: jnp.ndarray  # mean masked loss over the run
@@ -193,27 +198,40 @@ def build_loss_fn(module, has_dropout: bool = True, loss: str = "ce",
     ``targets=(labels, row mask)``, sows each further weighted loss term into
     its ``losses`` collection, and the step trains ``loss_kind(logits) + their
     sum``, which is the ``loss_val`` returned.  Where ``counters`` names
-    ``lm.loss_main`` it is filled with ``loss_kind(logits)`` alone."""
+    ``lm.loss_main`` it is filled with ``loss_kind(logits)`` alone.
+
+    A module class that ``owns_loss`` (block-diffusion training,
+    ``models/sdar_moe.py``) returns its objective, given ``targets``: that is
+    the step's loss and the engine adds no term of its own.  Whatever
+    ``has_dropout`` says, it gets the rng stream ``noise``, whose key is
+    ``jax.random.fold_in(rng, NOISE_STREAM)`` of the step's ``rng`` (the packed
+    round's ``fold_in(device key, stream step)``, ``ml/engine/packed.py``)."""
     loss_kind = LOSS_FNS[loss]
+    owns_loss = bool(getattr(module, "owns_loss", False))
     takes_targets = bool(getattr(module, "takes_targets", False))
 
     def loss_fn(params, other_vars, bx, by, bmask, rng):
         variables = dict(other_vars, params=params)
         mutable = ([k for k in other_vars.keys()] + (["counters"] if counters else [])
-                   + (["losses"] if takes_targets else []))
+                   + (["losses"] if takes_targets and not owns_loss else []))
         rngs = {"dropout": rng} if has_dropout else None
+        if owns_loss:
+            rngs = dict(rngs or {}, noise=jax.random.fold_in(rng, NOISE_STREAM))
         targets = {"targets": (by, bmask)} if takes_targets else {}
         if mutable:
             logits, updated = module.apply(
                 variables, bx, train=True, rngs=rngs, mutable=mutable, **targets
             )
         else:
-            logits = module.apply(variables, bx, train=True, rngs=rngs)
+            logits = module.apply(variables, bx, train=True, rngs=rngs, **targets)
             updated = {}
         updated = dict(updated)
         with jax.named_scope("fed.loss"):
-            loss_val = main = loss_kind(logits, by, bmask)[0]
-            if takes_targets:
+            if owns_loss:  # the module's own objective, computed under this scope there
+                loss_val = main = logits
+            else:
+                loss_val = main = loss_kind(logits, by, bmask)[0]
+            if takes_targets and not owns_loss:
                 loss_val = main + sum(jax.tree_util.tree_leaves(updated.pop("losses", {})))
         if not counters:
             return loss_val, updated
@@ -332,7 +350,25 @@ def build_local_train(
 
 
 def make_eval_fn(module) -> Callable:
-    """Jitted masked eval: ``(variables, x, y, mask) -> (loss_sum, correct, count)``."""
+    """Jitted masked eval: ``(variables, x, y, mask) -> (loss_sum, correct, count)``.
+
+    For a module that ``owns_loss``: its own loss under one noise key for every
+    batch (``fold_in(PRNGKey(0), NOISE_STREAM)``), ``loss_sum`` that loss times the batch's rows, and
+    ``correct`` the share of the masked positions whose prediction is the
+    token, times the rows (so ``correct / count`` is that accuracy)."""
+    if getattr(module, "owns_loss", False):
+        @jax.jit
+        def evaluate_own(variables, x, y, mask):
+            rows = jnp.sum(mask.astype(jnp.float32))
+            loss, sown = module.apply(variables, x, train=False, targets=(y, mask),
+                                      rngs={"noise": jax.random.fold_in(
+                                          jax.random.PRNGKey(0), NOISE_STREAM)},
+                                      mutable=["counters"])
+            counted = sown["counters"]
+            share = counted["bd.correct"] / jnp.maximum(counted["bd.masked"], 1.0)
+            return loss * rows, share * rows, rows
+
+        return evaluate_own
 
     @jax.jit
     def evaluate(variables, x, y, mask):

@@ -1,9 +1,10 @@
 """What the config-driven sparse decoders share (``kimi_linear.py``,
-``smallthinker.py``): the expert layer — routing, the grouped products over the
-experts held here, the round's counters — RMSNorm, the dense gated MLP, the
-initialiser and the LM shell (embedding -> blocks under per-layer ``remat`` ->
-final RMSNorm -> untied head, and after the blocks the multi-token-prediction
-module of a model that has one: :class:`PredictionModule`).
+``smallthinker.py``, ``sdar_moe.py``): the grouped-query mixer, the expert layer —
+routing, the grouped products over the experts held here, the round's counters —
+RMSNorm, the dense gated MLP, the initialiser and the LM shell (embedding ->
+blocks under per-layer ``remat`` -> final RMSNorm -> untied head, and after the
+blocks the multi-token-prediction module of a model that has one:
+:class:`PredictionModule`).
 
 A model's config dataclass gives the shared parts these fields:
 ``hidden_size``, ``num_hidden_layers``, ``vocab_size``, ``rms_norm_eps``,
@@ -30,7 +31,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Any, Callable, ClassVar, Tuple
+from typing import Any, Callable, ClassVar, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -49,7 +50,8 @@ MTP_COUNTERS = ("lm.loss_main", "mtp.loss", "mtp.positions")
 # token mixers' Pallas kernels, by the names their ``fwd`` rules place
 # (``ops/kept.py``).  A kernel's output is cheap to keep and dear to rebuild; its
 # inputs (norm, projections, rotation, convolutions, gates) are rebuilt
-KEPT = ("flash_fwd.out", "flash_fwd.lse", "kda_fwd.o", "kda_fwd.states")
+KEPT = ("flash_fwd.out", "flash_fwd.lse", "kda_fwd.o", "kda_fwd.states", "bd_flash_fwd.out",
+        "bd_flash_fwd.lse")
 
 
 def load_config(model_config) -> dict:
@@ -101,6 +103,55 @@ class DenseMLP(nn.Module):
             ("w_gate", (d, f), d), ("w_up", (d, f), d), ("w_down", (f, d), f))}
         return swiglu(h, w["w_gate"], w["w_up"], w["w_down"])
 
+
+
+class GQAMixer(nn.Module):
+    """Grouped-query attention on ``a`` [B, L, d] (``smallthinker``, ``sdar_moe``):
+    ``cfg.num_attention_heads`` query heads over ``cfg.num_key_value_heads`` key/value
+    heads of ``cfg.head_dim`` (``cfg`` any config with those, ``hidden_size``,
+    ``rope_theta``, ``rms_norm_eps`` and ``dtype``); ``window`` keys a query sees
+    (None: every earlier one), ``rotate``: rotary positions on q and k.  ``qk_norm``:
+    q and k pass a per-head RMSNorm (a float32 scale of ``head_dim``,
+    ``rms_norm_eps``) before the rotation.  ``block_diffusion``: ``a`` is
+    ``[a_noised ; a_clean]`` (2L positions, each half at positions 0..L-1) under the
+    block-diffusion mask of that block length (``ops.flash_attention.attention``)."""
+    cfg: Any
+    window: Optional[int]
+    rotate: bool
+    block_diffusion: Optional[int] = None
+    qk_norm: bool = False
+
+    @nn.compact
+    def __call__(self, a):
+        from ..ops.flash_attention import attention
+        from .transformer import rope
+
+        cfg = self.cfg
+        d, D, dt = cfg.hidden_size, cfg.head_dim, cfg.dtype
+        Hq, Hkv = cfg.num_attention_heads, cfg.num_key_value_heads
+
+        def param(name, shape, fan_in):
+            return self.param(name, _normal(fan_in), shape, jnp.float32).astype(dt)
+
+        q = jnp.einsum("bld,dhk->blhk", a, param("wq", (d, Hq, D), d))
+        k = jnp.einsum("bld,dhk->blhk", a, param("wk", (d, Hkv, D), d))
+        v = jnp.einsum("bld,dhk->blhk", a, param("wv", (d, Hkv, D), d))
+        if self.qk_norm:
+            q, k = (rms_norm(x, self.param(name, nn.initializers.ones, (D,), jnp.float32),
+                             cfg.rms_norm_eps) for x, name in ((q, "q_norm"), (k, "k_norm")))
+        if self.rotate:
+            if self.block_diffusion is None:
+                positions = jnp.broadcast_to(jnp.arange(a.shape[1]), a.shape[:2])
+            else:  # both halves at 0..L-1
+                half = jnp.arange(a.shape[1] // 2)
+                positions = jnp.broadcast_to(jnp.concatenate([half, half]), a.shape[:2])
+            q, k = (rope(x.astype(jnp.float32), positions, cfg.rope_theta).astype(dt)
+                    for x in (q, k))
+        if self.block_diffusion is None:
+            o = attention(q, k, v, causal=True, window=self.window)
+        else:
+            o = attention(q, k, v, block_diffusion=self.block_diffusion)
+        return jnp.einsum("blhk,hkd->bld", o, param("wo", (Hq, D, d), Hq * D))
 
 def route(scores, bias, top_k: int, scaling: float = 1.0, renormalize: bool = False,
           softmax_chosen: bool = False):

@@ -31,15 +31,14 @@ is applied in float32.  Counters as ``expert_lm`` sows them.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .expert_lm import (DecoderLM, ExpertShare, _normal, compute_dtype, held_range, rms_norm,
-                        route)
-from .transformer import rope
+from .expert_lm import (DecoderLM, ExpertShare, GQAMixer, _normal, compute_dtype, held_range,
+                        rms_norm, route)
 
 # every ``moe_*`` key of the published config.json; another one (the family's
 # secondary experts, say) names a mechanism this module does not write
@@ -112,35 +111,6 @@ class SmallThinkerConfig:
             moe_intermediate_size=int(cfg["moe_ffn_hidden_size"]),
             n_routed_experts=total, experts_held=held, num_experts_per_token=top_k,
             dtype=compute_dtype(cfg), remat=bool(cfg.get("remat", False)))
-
-
-class GQAMixer(nn.Module):
-    """Grouped-query attention on ``a`` [B, L, d]: ``window`` keys a query sees
-    (None: every earlier one), ``rotate``: rotary positions on q and k."""
-    cfg: SmallThinkerConfig
-    window: Optional[int]
-    rotate: bool
-
-    @nn.compact
-    def __call__(self, a):
-        from ..ops.flash_attention import attention
-
-        cfg = self.cfg
-        d, D, dt = cfg.hidden_size, cfg.head_dim, cfg.dtype
-        Hq, Hkv = cfg.num_attention_heads, cfg.num_key_value_heads
-
-        def param(name, shape, fan_in):
-            return self.param(name, _normal(fan_in), shape, jnp.float32).astype(dt)
-
-        q = jnp.einsum("bld,dhk->blhk", a, param("wq", (d, Hq, D), d))
-        k = jnp.einsum("bld,dhk->blhk", a, param("wk", (d, Hkv, D), d))
-        v = jnp.einsum("bld,dhk->blhk", a, param("wv", (d, Hkv, D), d))
-        if self.rotate:
-            positions = jnp.broadcast_to(jnp.arange(a.shape[1]), a.shape[:2])
-            q, k = (rope(x.astype(jnp.float32), positions, cfg.rope_theta).astype(dt)
-                    for x in (q, k))
-        o = attention(q, k, v, causal=True, window=self.window)
-        return jnp.einsum("blhk,hkd->bld", o, param("wo", (Hq, D, d), Hq * D))
 
 
 class Block(nn.Module):

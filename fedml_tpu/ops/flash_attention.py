@@ -96,13 +96,16 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
-def reference_attention(q, k, v, causal: bool = True, window: int | None = None):
+def reference_attention(q, k, v, causal: bool = True, window: int | None = None,
+                        block_diffusion: int | None = None):
     """Fused-XLA attention, [B, L, H, D] layout (fallback, test oracle, and
     the single fused-attention definition — models/transformer.py delegates
     here).  v may have another width than q and k.  k and v may have fewer
     heads than q (grouped-query attention: query head ``h`` reads kv head
     ``h // (Hq // Hkv)``; the repeat is written out here).  ``window``: query
-    ``t`` sees the keys ``s`` with ``0 <= t - s < window`` (causal only)."""
+    ``t`` sees the keys ``s`` with ``0 <= t - s < window`` (causal only).
+    ``block_diffusion``: the mask of :func:`block_diffusion_mask` over the
+    2L positions, written out."""
     d = q.shape[-1]
     group = _kv_group(q, k, causal, window)
     if group > 1:
@@ -110,7 +113,10 @@ def reference_attention(q, k, v, causal: bool = True, window: int | None = None)
     scores = jnp.einsum("blhd,bmhd->bhlm", q, k).astype(jnp.float32) / jnp.sqrt(
         jnp.float32(d)
     )
-    if causal:
+    if block_diffusion is not None:
+        mask = block_diffusion_mask(q.shape[1] // 2, block_diffusion)
+        scores = jnp.where(mask[None, None], scores, -jnp.inf)
+    elif causal:
         L, M = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((L, M), dtype=bool))
         if window is not None:
@@ -118,6 +124,20 @@ def reference_attention(q, k, v, causal: bool = True, window: int | None = None)
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhlm,bmhd->blhd", probs, v)
+
+
+def block_diffusion_mask(L: int, block_len: int):
+    """[2L, 2L] bool, queries by keys, of block-diffusion training over
+    ``[x_noised ; x_clean]`` (BD3-LM, arXiv:2503.09573): position ``t`` of
+    either half is in block ``t // block_len``; a clean query sees the clean
+    keys of its own and earlier blocks, a noised query the noised keys of its
+    own block and the clean keys of the blocks strictly before it.  The
+    oracle's form; the kernels never build it."""
+    blk = jnp.arange(L) // block_len
+    q_blk, k_blk = blk[:, None], blk[None, :]
+    noised_rows = jnp.concatenate([q_blk == k_blk, q_blk > k_blk], axis=1)
+    clean_rows = jnp.concatenate([jnp.zeros((L, L), bool), q_blk >= k_blk], axis=1)
+    return jnp.concatenate([noised_rows, clean_rows], axis=0)
 
 
 def _kv_group(q, k, causal, window):
@@ -137,13 +157,14 @@ def _col_to_row(x):
     return jnp.broadcast_to(x, (x.shape[0], _LANES)).T[:1]
 
 
-def _fold_block(s, v, m_ref, l_ref, acc_ref):
+def _fold_block(s, v, m_ref, l_ref, acc_ref, rows=...):
     """Fold one score block ``s`` [bq, bk] (dead entries ``-inf``) and its
     values ``v`` [bk, D] into the running online-softmax state held in VMEM
     scratch: ``m``/``l`` [bq, 1] f32 columns, ``acc`` [bq, D] f32
-    (unnormalized).  Shared by the forward and the ring shard-update kernel
+    (unnormalized); ``rows``: the slice of that state ``s`` stands for (all of
+    it by default).  Shared by the forward and the ring shard-update kernel
     so the two cannot drift."""
-    m = m_ref[...]
+    m = m_ref[rows]
     new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     # rows with every position masked keep a -inf max; shift by a finite one,
     # so that a dead entry (and a first block's -inf history) is exp(-inf) = 0
@@ -151,11 +172,11 @@ def _fold_block(s, v, m_ref, l_ref, acc_ref):
     safe_m = jnp.where(new_m > -jnp.inf, new_m, 0.0)
     p = jnp.exp(s - safe_m)
     corr = jnp.exp(m - safe_m)
-    m_ref[...] = new_m
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[rows] = new_m
+    l_ref[rows] = l_ref[rows] * corr + jnp.sum(p, axis=-1, keepdims=True)
     # matmuls stay in the input dtype (bf16 rides the MXU at full rate)
     # with f32 accumulation; softmax state is f32 throughout
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+    acc_ref[rows] = acc_ref[rows] * corr + jax.lax.dot_general(
         p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
 
 
@@ -277,6 +298,11 @@ _FOOTPRINT = {
     "flash_fwd": ((1, 1), (1, 1), (0, 1), (0, 0)),      # q, out | k, v | acc
     "flash_bwd_dq": ((2, 1), (1, 1), (1, 0), (0, 0)),   # q, dq, dO | k, v | acc
     "flash_bwd_dkv": ((1, 1), (2, 2), (0, 0), (1, 1)),  # q, dO | k, dk, v, dv | dk, dv
+    # block diffusion: the noised keys and values of a q block (queries-major) or
+    # of the column (keys-major, with their gradients and accumulators) beside
+    "bd_flash_fwd": ((2, 2), (1, 1), (0, 1), (0, 0)),
+    "bd_flash_bwd_dq": ((3, 2), (1, 1), (1, 0), (0, 0)),
+    "bd_flash_bwd_dkv": ((1, 1), (4, 4), (0, 0), (2, 2)),
 }
 # f32 [block_q, block_k] intermediates a step is reckoned to hold at once
 # (scores -> probs in place, one more, a bf16 copy for the MXU).  Calibrated
@@ -328,7 +354,7 @@ def _choose_blocks(kernel, L, D, dtype, Dv=None):
     fits = [n * _LANES for n in range(1, lanes + 1) if lanes % n == 0]
     itemsize = jnp.dtype(dtype).itemsize
     block_q, block_k = (max(b for b in fits if b <= target)
-                        for target in _BLOCK_TARGET[kernel])
+                        for target in _BLOCK_TARGET[kernel.removeprefix("bd_")])
     while (_vmem_bytes(kernel, block_q, block_k, D, itemsize, Dv) > _VMEM_BUDGET
            and max(block_q, block_k) > _LANES):
         if block_q >= block_k:
@@ -441,7 +467,7 @@ def _tiling(kernel, Lp, blocks, causal, valid_len, window=None, group=1):
         tile["window"] = labels["window"] = int(window)
     n_qb, n_kb = Lp // block_q, Lp // block_k
     live = _tile_live(np.arange(n_qb)[:, None], np.arange(n_kb)[None, :], **tile)
-    if kernel == "flash_bwd_dkv":
+    if kernel.endswith("flash_bwd_dkv"):
         n, inner, reps = n_kb, n_qb, group
         run = functools.partial(_live_q_run, **tile)
         clamp = lambda j, i: _live_q_block(i, j, **tile)
@@ -1054,10 +1080,418 @@ def flash_shard_update(q, k, v, q_pos, k_pos, m, l, o, causal: bool = True,
                                    block_q, block_k, interpret)
 
 
-def attention(q, k, v, causal: bool = True, window: int | None = None):
+# -- block diffusion ------------------------------------------------------------
+# Training a block-diffusion LM (BD3-LM, arXiv:2503.09573) runs the model over
+# ``[x_noised ; x_clean]``: 2L positions, the halves' positions both 0..L-1, cut
+# into blocks of ``block_len``.  A clean query sees the clean keys of its own
+# and earlier blocks (block-causal); a noised query the clean keys of the
+# blocks STRICTLY before its own and the noised keys of its own block
+# (:func:`block_diffusion_mask`).  One flat call over the 2L concatenation would
+# give a noised row two runs of live tiles; instead the noised queries are
+# further query heads of each kv head's group (``2 * group`` heads a kv head,
+# the noised ones first), every head's clean keys are the run of the causal
+# walk (block-causal, or strictly earlier blocks, is a mask on the diagonal
+# tile alone, since ``block_len`` divides the tiles), and the ``block_len``
+# in-block keys of a noised query come from a second (k, v) pair, the noised
+# half's, read on the diagonal tile only and folded into the same online
+# softmax in sub-blocks of 128 rows: a 128 x 128 product each, whose mask is
+# the same block diagonal in every sub-block.  The grids, the paired walk,
+# ``_kv_head`` and the dead-tile skipping are the causal calls'; the blocks are
+# square (q block i and K/V block i hold the same positions).  k and v are
+# never repeated or concatenated in HBM; dK / dV of the clean keys sum the
+# clean and the noised query heads of a group in VMEM, and those of the noised
+# keys come out of the same keys-major pass, from the diagonal tile of its
+# noised heads.  The kernels are named ``bd_flash_fwd`` / ``bd_flash_bwd_dq`` /
+# ``bd_flash_bwd_dkv`` and leave the causal calls' gauges under those names
+# (``flash.kv_group`` counts the ``2 * group`` heads).
+
+_BD_KERNELS = tuple("bd_" + k for k in _BLOCK_TARGET)
+
+
+def _bd_check(q, k, v, block_len):
+    """(L, group) of a block-diffusion call; checks what the mode asks."""
+    L2, Hq, D = q.shape[1:]
+    if L2 % 2 or k.shape[1] != L2 or v.shape[-1] != D:
+        raise ValueError(f"block diffusion takes [x_noised ; x_clean] of equal halves and "
+                         f"equal q/v widths: q {q.shape}, k {k.shape}, v {v.shape}")
+    L = L2 // 2
+    if block_len < 1 or _LANES % block_len or L % block_len:
+        raise ValueError(f"block length {block_len} must divide {_LANES} and the length {L}")
+    return L, _kv_group(q, k, True, None)
+
+
+def _bd_geometry(L, D, dtype, block, block_len):
+    """``{kernel: (b, b)}`` and the padded length of a block-diffusion call:
+    square blocks, the smaller of each kernel's chosen pair (or ``block`` where
+    given), a multiple of ``block_len`` and of its 128-row sub-blocks."""
+    blocks = {}
+    for kernel in _BD_KERNELS:
+        b = _fit_block(block, L) if block else min(_choose_blocks(kernel, L, D, dtype))
+        if b % min(_LANES, b) or min(_LANES, b) % block_len:
+            raise ValueError(f"block {b} does not hold whole sub-blocks of blocks of {block_len}")
+        blocks[kernel] = (b, b)
+    m = math.lcm(*(b for b, _ in blocks.values()))
+    return blocks, -(-L // m) * m
+
+
+def _bd_q_to_bh(x, B, L, Hkv, group, Lp):
+    """[B, 2L, Hkv*group, D] -> [B*Hkv*2*group, Lp, D]: a kv head's noised query
+    heads, then its clean ones."""
+    D = x.shape[-1]
+    x = x.reshape(B, 2, L, Hkv, group, D).transpose(0, 3, 1, 4, 2, 5)
+    x = x.reshape(B * Hkv * 2 * group, L, D)
+    return jnp.pad(x, ((0, 0), (0, Lp - L), (0, 0))) if Lp != L else x
+
+
+def _bd_q_from_bh(x, B, L, Hkv, group):
+    D = x.shape[-1]
+    x = x[:, :L].reshape(B, Hkv, 2, group, L, D).transpose(0, 2, 4, 1, 3, 5)
+    return x.reshape(B, 2 * L, Hkv * group, D)
+
+
+def _bd_halves(x, B, L, Lp):
+    """[B, 2L, Hkv, D] -> (noised, clean), each [B*Hkv, Lp, D]."""
+    H, D = x.shape[2:]
+    return _to_bh(x[:, :L], B, L, H, D, Lp), _to_bh(x[:, L:], B, L, H, D, Lp)
+
+
+def _bd_tile_mask(qi, kj, shape, q_dim, noised, *, block_len, block_q, block_k, causal,
+                  valid_len, window=None):
+    """Live clean keys of a tile the diagonal or the padded tail crosses: key
+    block <= query block for a clean head, < for a noised one (``noised``: 0
+    or 1); ``shape`` has queries along ``q_dim``."""
+    k_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+    q_idx = jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    live = k_idx < valid_len - kj * block_k
+    shift = block_len.bit_length() - 1
+    if shift:
+        k_idx, q_idx = k_idx >> shift, q_idx >> shift
+    ahead = qi * (block_q // block_len) - kj * (block_k // block_len)
+    return live & (k_idx - q_idx <= ahead - noised)
+
+
+def _in_block_mask(n, block_len):
+    """[n, n] of a 128-row sub-block of the diagonal: query and key in one block."""
+    shift = block_len.bit_length() - 1
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) >> shift
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1) >> shift
+    return r == c
+
+
+def _sub_blocks(block):
+    """The 128-row slices (``pl.ds``) of a block the in-block keys are read in."""
+    sub = min(_LANES, block)
+    return [pl.ds(r * sub, sub) for r in range(block // sub)]
+
+
+def _bd_diagonal(qi, kj, noised, inside):
+    """The step at which a noised head folds its in-block keys: its diagonal tile."""
+    at = (noised == 1) & (qi == kj)
+    return at if inside is None else at & inside
+
+
+def _bd_flash_kernel(q_ref, k_ref, v_ref, kn_ref, vn_ref, o_ref, lse_ref, m_ref, l_ref,
+                     acc_ref, *, walk, scale, tile, block_len, noised_of):
+    """``_flash_kernel`` over the clean keys with the block-diffusion mask, and
+    for a noised head, on its diagonal tile, the in-block noised keys folded
+    into the same state before the row is finished."""
+    qi, kj, first, last, inside = walk(pl.program_id(1), pl.program_id(2))
+    noised = noised_of(pl.program_id(0))
+
+    @pl.when(first)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def _attend(masked):
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], _NT, preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = jnp.where(_bd_tile_mask(qi, kj, s.shape, 0, noised, block_len=block_len, **tile),
+                          s, -jnp.inf)
+        _fold_block(s, v_ref[0], m_ref, l_ref, acc_ref)
+
+    _when_live(qi, kj, tile, _attend, inside)
+
+    @pl.when(_bd_diagonal(qi, kj, noised, inside))
+    def _in_block():
+        for rows in _sub_blocks(tile["block_q"]):
+            s = jax.lax.dot_general(q_ref[0, rows, :], kn_ref[0, rows, :], _NT,
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(_in_block_mask(s.shape[0], block_len), s, -jnp.inf)
+            _fold_block(s, vn_ref[0, rows, :], m_ref, l_ref, acc_ref, rows)
+
+    @pl.when(last)
+    def _finish():
+        l = l_ref[...]
+        m = m_ref[...]
+        o_ref[0] = (acc_ref[...] * (1.0 / jnp.maximum(l, 1e-20))).astype(o_ref.dtype)
+        lse = jnp.where(
+            l > 0, jnp.where(m > -jnp.inf, m, 0.0) + jnp.log(jnp.maximum(l, 1e-38)),
+            -jnp.inf,
+        )
+        lse_ref[0] = _col_to_row(lse)
+
+
+def _bd_flash_bwd_dq_kernel(q_ref, k_ref, v_ref, kn_ref, vn_ref, do_ref, lse_ref, delta_ref,
+                            dq_ref, acc_ref, *, walk, scale, tile, block_len, noised_of):
+    """``_flash_bwd_dq_kernel`` under the block-diffusion mask, plus a noised
+    head's in-block keys on its diagonal tile."""
+    qi, kj, first, last, inside = walk(pl.program_id(1), pl.program_id(2))
+    noised = noised_of(pl.program_id(0))
+
+    @pl.when(first)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def _accum(masked):
+        k = k_ref[0]
+        mask = (_bd_tile_mask(qi, kj, (tile["block_q"], tile["block_k"]), 0, noised,
+                              block_len=block_len, **tile) if masked else None)
+        _, ds = _block_grads(
+            q_ref[0], k, v_ref[0], do_ref[0],
+            lse_ref[0, 0][:, None], delta_ref[0, 0][:, None], mask,
+            scale=scale, keys_major=False,
+        )
+        acc_ref[...] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
+
+    _when_live(qi, kj, tile, _accum, inside)
+
+    @pl.when(_bd_diagonal(qi, kj, noised, inside))
+    def _in_block():
+        for rows in _sub_blocks(tile["block_q"]):
+            kn = kn_ref[0, rows, :]
+            _, ds = _block_grads(
+                q_ref[0, rows, :], kn, vn_ref[0, rows, :], do_ref[0, rows, :],
+                lse_ref[0, 0, rows][:, None], delta_ref[0, 0, rows][:, None],
+                _in_block_mask(kn.shape[0], block_len), scale=scale, keys_major=False)
+            acc_ref[rows, :] += jax.lax.dot_general(
+                ds.astype(kn.dtype), kn, _NN, preferred_element_type=jnp.float32)
+
+    @pl.when(last)
+    def _finish():
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+
+
+def _bd_flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, kn_ref, vn_ref, do_ref, lse_ref, delta_ref,
+                             dk_ref, dv_ref, dkn_ref, dvn_ref, dk_acc, dv_acc, dkn_acc, dvn_acc,
+                             *, walk, head, scale, tile, block_len, group):
+    """``_flash_bwd_dkv_kernel`` over the ``2 * group`` query heads of a kv head
+    (the noised ones first) under the block-diffusion mask, and on a noised
+    head's diagonal tile the gradients of the column's noised keys and values,
+    summed over the group in their own VMEM accumulators."""
+    kj, qi, first, last, inside = walk(pl.program_id(1), pl.program_id(2))
+    noised = (head(pl.program_id(1), pl.program_id(2)) < group).astype(jnp.int32)
+
+    @pl.when(first)
+    def _init():
+        for acc in (dk_acc, dv_acc, dkn_acc, dvn_acc):
+            acc[...] = jnp.zeros_like(acc)
+
+    def _accum(masked):
+        q = q_ref[0]
+        do = do_ref[0]
+        mask = (_bd_tile_mask(qi, kj, (tile["block_k"], tile["block_q"]), 1, noised,
+                              block_len=block_len, **tile) if masked else None)
+        p_t, ds_t = _block_grads(
+            q, k_ref[0], v_ref[0], do, lse_ref[0], delta_ref[0], mask,
+            scale=scale, keys_major=True,
+        )
+        dv_acc[...] += jax.lax.dot_general(
+            p_t.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
+        dk_acc[...] += jax.lax.dot_general(
+            ds_t.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32)
+
+    _when_live(qi, kj, tile, _accum, inside)
+
+    @pl.when(_bd_diagonal(qi, kj, noised, inside))
+    def _in_block():
+        for rows in _sub_blocks(tile["block_k"]):
+            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            p_t, ds_t = _block_grads(
+                q, kn_ref[0, rows, :], vn_ref[0, rows, :], do, lse_ref[0, :, rows],
+                delta_ref[0, :, rows], _in_block_mask(q.shape[0], block_len),
+                scale=scale, keys_major=True)
+            dvn_acc[rows, :] += jax.lax.dot_general(
+                p_t.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
+            dkn_acc[rows, :] += jax.lax.dot_general(
+                ds_t.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32)
+
+    @pl.when(last)
+    def _finish():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        dkn_ref[0] = (dkn_acc[...] * scale).astype(dkn_ref.dtype)
+        dvn_ref[0] = dvn_acc[...].astype(dvn_ref.dtype)
+
+
+def _noised_head(group):
+    """Grid index of a query head ([B*Hkv*2*group]) -> 1 where it is a noised
+    head (the first ``group`` of its kv head's ``2 * group``), else 0."""
+    return lambda b: (jax.lax.rem(jax.lax.div(b, group), 2) == 0).astype(jnp.int32)
+
+
+def _bd_queries_major(kernel, qb, kb, knb, blocks, valid_len, group):
+    """What the forward and dQ calls share: tiling, the q-side and k-side
+    BlockSpecs and the in-block keys' spec (a noised head's q block; a clean
+    head, which never reads them, stays on block 0)."""
+    block_q, block_k = blocks
+    D = qb.shape[-1]
+    tile, grid, walk, block = _tiling(kernel, qb.shape[1], blocks, True, valid_len, None,
+                                      2 * group)
+    kv_head, noised_of = _kv_head(2 * group), _noised_head(group)
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, o, s: (b, block(o, s)[0], 0))
+    k_spec = pl.BlockSpec((1, block_k, D), lambda b, o, s: (kv_head(b), block(o, s)[1], 0))
+    kn_spec = pl.BlockSpec((1, block_q, D), lambda b, o, s: (
+        kv_head(b), block(o, s)[0] * noised_of(b), 0))
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, o, s: (b, 0, block(o, s)[0]))
+    return tile, grid, walk, noised_of, (q_spec, k_spec, kn_spec, row_spec)
+
+
+def _bd_fwd_call(qb, kb, vb, knb, vnb, blocks, valid_len, block_len, group, scale, interpret):
+    """``bd_flash_fwd`` over ``[B*Hkv*2*group, Lp, D]`` q and ``[B*Hkv, Lp, D]``
+    clean and noised k and v: (out, lse) at q's heads."""
+    BH, Lp, D = qb.shape
+    tile, grid, walk, noised_of, (q_spec, k_spec, kn_spec, row_spec) = _bd_queries_major(
+        "bd_flash_fwd", qb, kb, knb, blocks, valid_len, group)
+    return pl.pallas_call(
+        functools.partial(_bd_flash_kernel, walk=walk, scale=scale, tile=tile,
+                          block_len=block_len, noised_of=noised_of),
+        grid=(BH, *grid),
+        in_specs=[q_spec, k_spec, k_spec, kn_spec, kn_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype),
+                   jax.ShapeDtypeStruct((BH, 1, Lp), jnp.float32)],
+        scratch_shapes=_scratch(blocks[0], D),
+        compiler_params=_GRID_SEMANTICS,
+        interpret=interpret,
+        name="bd_flash_fwd",
+    )(qb, kb, vb, knb, vnb)
+
+
+def _bd_dq_call(qb, kb, vb, knb, vnb, dob, lse, delta, blocks, valid_len, block_len, group,
+                scale, interpret):
+    BH, Lp, D = qb.shape
+    tile, grid, walk, noised_of, (q_spec, k_spec, kn_spec, row_spec) = _bd_queries_major(
+        "bd_flash_bwd_dq", qb, kb, knb, blocks, valid_len, group)
+    return pl.pallas_call(
+        functools.partial(_bd_flash_bwd_dq_kernel, walk=walk, scale=scale, tile=tile,
+                          block_len=block_len, noised_of=noised_of),
+        grid=(BH, *grid),
+        in_specs=[q_spec, k_spec, k_spec, kn_spec, kn_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype),
+        scratch_shapes=[pltpu.VMEM((blocks[0], D), jnp.float32)],
+        compiler_params=_GRID_SEMANTICS,
+        interpret=interpret,
+        name="bd_flash_bwd_dq",
+    )(qb, kb, vb, knb, vnb, dob, lse, delta)
+
+
+def _bd_dkv_call(qb, kb, vb, knb, vnb, dob, lse, delta, blocks, valid_len, block_len, group,
+                 scale, interpret):
+    """``bd_flash_bwd_dkv``: (dK, dV, dK_noised, dV_noised) at the kv heads'
+    count, keys-major; the inner axis walks each of the ``2 * group`` query
+    heads' q blocks in turn."""
+    BH, Lp, D = kb.shape
+    block_q, block_k = blocks
+    reps = 2 * group
+    tile, grid, walk, block = _tiling("bd_flash_bwd_dkv", Lp, blocks, True, valid_len, None,
+                                      reps)
+
+    def q_at(b, o, s):
+        _, qi, g = block(o, s)
+        return b * reps + g, qi
+
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, o, s: (*q_at(b, o, s), 0))
+    k_spec = pl.BlockSpec((1, block_k, D), lambda b, o, s: (b, block(o, s)[0], 0))
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, o, s: (q_at(b, o, s)[0], 0,
+                                                            q_at(b, o, s)[1]))
+    return pl.pallas_call(
+        functools.partial(_bd_flash_bwd_dkv_kernel, walk=walk,
+                          head=lambda o, s: block(o, s)[2], scale=scale, tile=tile,
+                          block_len=block_len, group=group),
+        grid=(BH, *grid),
+        in_specs=[q_spec, k_spec, k_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[k_spec] * 4,
+        out_shape=[jax.ShapeDtypeStruct((BH, Lp, D), qb.dtype)] * 4,
+        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32)] * 4,
+        compiler_params=_GRID_SEMANTICS,
+        interpret=interpret,
+        name="bd_flash_bwd_dkv",
+    )(qb, kb, vb, knb, vnb, dob, lse, delta)
+
+
+def _bd_operands(q, k, v, block_len, block, extra=()):
+    """The layouts and geometry a block-diffusion call's kernels share."""
+    B, _, H, D = q.shape
+    L, group = _bd_check(q, k, v, block_len)
+    blocks, Lp = _bd_geometry(L, D, q.dtype, block, block_len)
+    Hkv = H // group
+    (kn, kc), (vn, vc) = _bd_halves(k, B, L, Lp), _bd_halves(v, B, L, Lp)
+    qs = tuple(_bd_q_to_bh(x, B, L, Hkv, group, Lp) for x in (q,) + tuple(extra))
+    return (B, L, Hkv, group), blocks, qs, (kc, vc, kn, vn)
+
+
+def _bd_forward(q, k, v, block_len, block, interpret):
+    (B, L, Hkv, group), blocks, (qb,), kv = _bd_operands(q, k, v, block_len, block)
+    out, lse = _bd_fwd_call(qb, *kv, blocks["bd_flash_fwd"], L, block_len, group,
+                            1.0 / (q.shape[-1] ** 0.5), interpret)
+    return _bd_q_from_bh(out, B, L, Hkv, group), lse
+
+
+def _bd_backward(q, k, v, out, lse, g, block_len, block, interpret):
+    (B, L, Hkv, group), blocks, (qb, dob, ob), kv = _bd_operands(
+        q, k, v, block_len, block, (g.astype(q.dtype), out))
+    delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32), axis=-1)[:, None, :]
+    operands = (qb, *kv, dob, lse, delta)
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    dq = _bd_dq_call(*operands, blocks["bd_flash_bwd_dq"], L, block_len, group, scale,
+                     interpret)
+    dk, dv, dkn, dvn = _bd_dkv_call(*operands, blocks["bd_flash_bwd_dkv"], L, block_len,
+                                    group, scale, interpret)
+    H, D = k.shape[2:]
+    halves = lambda n, c: jnp.concatenate(  # noqa: E731
+        [_from_bh(n, B, L, H, D), _from_bh(c, B, L, H, D)], axis=1)
+    return _bd_q_from_bh(dq, B, L, Hkv, group), halves(dkn, dk), halves(dvn, dv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def bd_flash_attention(q, k, v, block_len: int, block: int | None = None,
+                       interpret: bool = False):
+    """Block-diffusion attention (the mask of :func:`block_diffusion_mask`) by
+    the Pallas kernels.  q: [B, 2L, Hq, D], k, v: [B, 2L, Hkv, D], the noised
+    half first -> [B, 2L, Hq, D].  ``block``: the square tile of every kernel
+    (None: chosen per kernel from the shape)."""
+    return _bd_forward(q, k, v, block_len, block, interpret)[0]
+
+
+def _bd_fwd_rule(q, k, v, block_len, block, interpret):
+    out, lse = _bd_forward(q, k, v, block_len, block, interpret)
+    out, lse = kept.tag("bd_flash_fwd", out=out, lse=lse)
+    return out, (q, k, v, out, lse)
+
+
+def _bd_bwd_rule(block_len, block, interpret, res, g):
+    return _bd_backward(*res, g, block_len, block, interpret)
+
+
+bd_flash_attention.defvjp(_bd_fwd_rule, _bd_bwd_rule)
+
+
+def attention(q, k, v, causal: bool = True, window: int | None = None,
+              block_diffusion: int | None = None):
     """Dispatch on the default backend and nothing else: the pallas kernel on
     ``tpu`` (a kernel that does not compile raises — it never quietly becomes
-    the reference), the fused-XLA reference on every other backend."""
+    the reference), the fused-XLA reference on every other backend.
+    ``block_diffusion``: the block length of a block-diffusion call over
+    ``[x_noised ; x_clean]`` (:func:`bd_flash_attention`)."""
+    if block_diffusion is not None:
+        if jax.default_backend() == "tpu":
+            return bd_flash_attention(q, k, v, block_diffusion)
+        return reference_attention(q, k, v, block_diffusion=block_diffusion)
     if jax.default_backend() == "tpu":
         return flash_attention(q, k, v, causal=causal, window=window)
     return reference_attention(q, k, v, causal=causal, window=window)
