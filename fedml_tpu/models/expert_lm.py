@@ -114,7 +114,10 @@ class GQAMixer(nn.Module):
     q and k pass a per-head RMSNorm (a float32 scale of ``head_dim``,
     ``rms_norm_eps``) before the rotation.  ``block_diffusion``: ``a`` is
     ``[a_noised ; a_clean]`` (2L positions, each half at positions 0..L-1) under the
-    block-diffusion mask of that block length (``ops.flash_attention.attention``)."""
+    block-diffusion mask of that block length (``ops.flash_attention.attention``);
+    called with ``noised_only``, the queries are the noised half's alone (k and v
+    still over both halves) and the output is [B, L, d]: a last layer's, whose clean
+    half nothing reads but its keys and values."""
     cfg: Any
     window: Optional[int]
     rotate: bool
@@ -122,7 +125,7 @@ class GQAMixer(nn.Module):
     qk_norm: bool = False
 
     @nn.compact
-    def __call__(self, a):
+    def __call__(self, a, noised_only: bool = False):
         from ..ops.flash_attention import attention
         from .transformer import rope
 
@@ -133,7 +136,8 @@ class GQAMixer(nn.Module):
         def param(name, shape, fan_in):
             return self.param(name, _normal(fan_in), shape, jnp.float32).astype(dt)
 
-        q = jnp.einsum("bld,dhk->blhk", a, param("wq", (d, Hq, D), d))
+        q = jnp.einsum("bld,dhk->blhk", a[:, :a.shape[1] // 2] if noised_only else a,
+                       param("wq", (d, Hq, D), d))
         k = jnp.einsum("bld,dhk->blhk", a, param("wk", (d, Hkv, D), d))
         v = jnp.einsum("bld,dhk->blhk", a, param("wv", (d, Hkv, D), d))
         if self.qk_norm:
@@ -145,8 +149,8 @@ class GQAMixer(nn.Module):
             else:  # both halves at 0..L-1
                 half = jnp.arange(a.shape[1] // 2)
                 positions = jnp.broadcast_to(jnp.concatenate([half, half]), a.shape[:2])
-            q, k = (rope(x.astype(jnp.float32), positions, cfg.rope_theta).astype(dt)
-                    for x in (q, k))
+            q, k = (rope(x.astype(jnp.float32), positions[:, :x.shape[1]] if noised_only
+                         else positions, cfg.rope_theta).astype(dt) for x in (q, k))
         if self.block_diffusion is None:
             o = attention(q, k, v, causal=True, window=self.window)
         else:

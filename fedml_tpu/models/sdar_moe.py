@@ -33,8 +33,12 @@ rng stream ``noise`` (the step's key: ``ml/engine/train.py:build_loss_fn``), wit
 (``noise_t_range`` = [lo, hi]; [1e-3, 1] is the linear schedule
 ``t = eps + (1 - eps) u``; the mask id is the embedding's last row, ``V - 1``,
 which the data never holds).  The blocks run over ``[x_noisy ; x]``, 2L positions with
-RoPE positions 0..L-1 in both halves; the final norm and the head see the noised half
-alone, and the step's loss, under ``fed.loss``, is
+RoPE positions 0..L-1 in both halves, except the last: nothing after it reads its clean
+half but that half's keys and values, so it computes k and v over 2L and its queries,
+output projection, residual, norm, router and experts over the noised half alone, and
+returns that half (``ops.flash_attention`` reads q over L against k over 2L as the
+noised queries alone).  The final norm and the head see the noised half, and the step's
+loss, under ``fed.loss``, is
 
     L = mean over the batch's rows of  (1 / L) sum_b (1 / t_b) sum_{i in b, masked} -log p(x_i)
 
@@ -163,12 +167,21 @@ def draw_noise(key, rows: int, length: int, block_length: int, t_range):
 
 
 class Block(nn.Module):
+    """One layer over ``[x_noised ; x_clean]`` (2L positions) -> the same, except the
+    last layer's: its clean half is needed only for its keys and values, so it takes
+    2L positions and returns the noised half's L (queries, output projection,
+    residual, ``ffn_norm``, router and experts over L); ``init`` runs it whole."""
     cfg: SdarMoeConfig
     index: int  # 0-based
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         cfg = self.cfg
+        # ``init`` runs the last block whole: the parameters are the same either way, and an
+        # eager init then reuses the other blocks' small programs (a last block of its own
+        # shapes compiled 253 of them where 179 do at the tiny preset, about 6 s more of the
+        # ``sdar`` cell's set-up on a v5e)
+        kv_only = self.index == cfg.num_hidden_layers - 1 and not self.is_initializing()
 
         def norm(name):
             scale = self.param(name, nn.initializers.ones, (cfg.hidden_size,), jnp.float32)
@@ -177,7 +190,8 @@ class Block(nn.Module):
         with jax.named_scope("lm.norm"):
             a = norm("attn_norm")
         with jax.named_scope("lm.attn.bd"):
-            x = x + GQAMixer(cfg, None, True, cfg.block_length, cfg.qk_norm, name="attn")(a)
+            y = GQAMixer(cfg, None, True, cfg.block_length, cfg.qk_norm, name="attn")(a, kv_only)
+            x = (x[:, :y.shape[1]] if kv_only else x) + y
         with jax.named_scope("lm.norm"):
             h = norm("ffn_norm")
         with jax.named_scope("lm.moe.route"):
@@ -191,9 +205,11 @@ class Block(nn.Module):
 
 class SdarMoeLM(nn.Module):
     """The LM shell of block-diffusion training (module docstring): noise, embedding
-    of both copies, the blocks over 2L positions (each recomputed in the backward
-    pass where ``cfg.remat``, all but what ``KEPT`` names), the final norm and the
-    head over the noised half, and the loss."""
+    of both copies, the blocks over 2L positions, the last of which returns the
+    noised half alone (each recomputed in the backward pass where ``cfg.remat``, all
+    but what ``KEPT`` names), the final norm and the head over that half, and the
+    loss.  Gauge ``bd.kv_only_layers``: the blocks whose clean half ran only through
+    k and v (1), set when the model is traced."""
     cfg: SdarMoeConfig
     # the packed round asks for these sums beside the loss (ml/engine/packed.py)
     round_counters: Tuple[str, ...] = COUNTERS + BD_COUNTERS
@@ -232,11 +248,13 @@ class SdarMoeLM(nn.Module):
                      if cfg.remat else Block)
         for i in range(cfg.num_hidden_layers):
             x = block_cls(cfg, i, name=f"layer{i}")(x, train)
+        # the last block returned the noised half alone: it read its clean half for k and v only
+        obs.gauge_set("bd.kv_only_layers", int(x.shape[1] == length))
         scale = self.param("final_norm", nn.initializers.ones, (cfg.hidden_size,), jnp.float32)
         head = self.param("head", _normal(cfg.hidden_size),
                           (cfg.hidden_size, cfg.vocab_size), jnp.float32)
         with jax.named_scope("lm.head"):
-            logits = rms_norm(x[:, :length], scale, cfg.rms_norm_eps) @ head.astype(cfg.dtype)
+            logits = rms_norm(x, scale, cfg.rms_norm_eps) @ head.astype(cfg.dtype)
         if targets is None:
             return logits
         _, row_mask = targets

@@ -105,7 +105,8 @@ def reference_attention(q, k, v, causal: bool = True, window: int | None = None,
     ``h // (Hq // Hkv)``; the repeat is written out here).  ``window``: query
     ``t`` sees the keys ``s`` with ``0 <= t - s < window`` (causal only).
     ``block_diffusion``: the mask of :func:`block_diffusion_mask` over the
-    2L positions, written out."""
+    2L positions of k and v, written out; its first L rows where q holds the
+    noised half's queries alone."""
     d = q.shape[-1]
     group = _kv_group(q, k, causal, window)
     if group > 1:
@@ -114,7 +115,9 @@ def reference_attention(q, k, v, causal: bool = True, window: int | None = None,
         jnp.float32(d)
     )
     if block_diffusion is not None:
-        mask = block_diffusion_mask(q.shape[1] // 2, block_diffusion)
+        mask = block_diffusion_mask(k.shape[1] // 2, block_diffusion)
+        if q.shape[1] != k.shape[1]:
+            mask = mask[:q.shape[1]]
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     elif causal:
         L, M = q.shape[1], k.shape[1]
@@ -440,7 +443,7 @@ def _paired_walk(n, steps, reps, run):
     return (-(-n // 2), steps), step, block
 
 
-def _tiling(kernel, Lp, blocks, causal, valid_len, window=None, group=1):
+def _tiling(kernel, Lp, blocks, causal, valid_len, window=None, group=1, queries=None):
     """One call's tile parameters (the keywords of the ``_tile_*`` predicates),
     its grid's two block axes and the two functions of their program ids that
     say where a step is: ``step(o, s)`` -> (member, tile, first, last, inside)
@@ -457,7 +460,9 @@ def _tiling(kernel, Lp, blocks, causal, valid_len, window=None, group=1):
     shapes): the blocks, the steps the grid runs for one head of its first
     axis, live steps over those, the window (0: none) and the query heads a kv
     head serves.  A windowed call's gauges carry the label ``window`` beside
-    ``kernel``, so that a model with both kinds of layer keeps both readings."""
+    ``kernel``, so that a model with both kinds of layer keeps both readings;
+    a block-diffusion call of one query set (``queries``: ``"noised"``) carries
+    ``queries`` likewise."""
     from ..core import obs
 
     block_q, block_k = blocks
@@ -465,6 +470,8 @@ def _tiling(kernel, Lp, blocks, causal, valid_len, window=None, group=1):
     labels = {"kernel": kernel}
     if window is not None:
         tile["window"] = labels["window"] = int(window)
+    if queries is not None:
+        labels["queries"] = queries
     n_qb, n_kb = Lp // block_q, Lp // block_k
     live = _tile_live(np.arange(n_qb)[:, None], np.arange(n_kb)[None, :], **tile)
     if kernel.endswith("flash_bwd_dkv"):
@@ -1104,16 +1111,25 @@ def flash_shard_update(q, k, v, q_pos, k_pos, m, l, o, causal: bool = True,
 # noised heads.  The kernels are named ``bd_flash_fwd`` / ``bd_flash_bwd_dq`` /
 # ``bd_flash_bwd_dkv`` and leave the causal calls' gauges under those names
 # (``flash.kv_group`` counts the ``2 * group`` heads).
+#
+# The query set is one half or both, read off the shapes: q over L rows against
+# k and v over 2L is the noised queries alone (a model's last layer, whose clean
+# half nothing reads but its keys and values).  A kv head then has ``group``
+# query heads, all noised; the kernels, their names and arity are the same,
+# and dK / dV of the clean keys sum over the noised heads alone.  Such a call's
+# gauges carry the label ``queries: noised``.
 
 _BD_KERNELS = tuple("bd_" + k for k in _BLOCK_TARGET)
 
 
 def _bd_check(q, k, v, block_len):
-    """(L, group) of a block-diffusion call; checks what the mode asks."""
-    L2, Hq, D = q.shape[1:]
-    if L2 % 2 or k.shape[1] != L2 or v.shape[-1] != D:
-        raise ValueError(f"block diffusion takes [x_noised ; x_clean] of equal halves and "
-                         f"equal q/v widths: q {q.shape}, k {k.shape}, v {v.shape}")
+    """(L, group) of a block-diffusion call; checks what the mode asks: k and v
+    over 2L, q over both halves (2L) or the noised one (L)."""
+    L2, (Lq, Hq, D) = k.shape[1], q.shape[1:]
+    if L2 % 2 or Lq not in (L2, L2 // 2) or v.shape[-1] != D:
+        raise ValueError(f"block diffusion takes [x_noised ; x_clean] of equal halves, q over "
+                         f"both or the first, and equal q/v widths: q {q.shape}, k {k.shape}, "
+                         f"v {v.shape}")
     L = L2 // 2
     if block_len < 1 or _LANES % block_len or L % block_len:
         raise ValueError(f"block length {block_len} must divide {_LANES} and the length {L}")
@@ -1135,18 +1151,19 @@ def _bd_geometry(L, D, dtype, block, block_len):
 
 
 def _bd_q_to_bh(x, B, L, Hkv, group, Lp):
-    """[B, 2L, Hkv*group, D] -> [B*Hkv*2*group, Lp, D]: a kv head's noised query
-    heads, then its clean ones."""
-    D = x.shape[-1]
-    x = x.reshape(B, 2, L, Hkv, group, D).transpose(0, 3, 1, 4, 2, 5)
-    x = x.reshape(B * Hkv * 2 * group, L, D)
+    """[B, h*L, Hkv*group, D] -> [B*Hkv*h*group, Lp, D] for a query set of h
+    halves (2, or 1 for the noised alone): a kv head's noised query heads, then
+    its clean ones."""
+    halves, D = x.shape[1] // L, x.shape[-1]
+    x = x.reshape(B, halves, L, Hkv, group, D).transpose(0, 3, 1, 4, 2, 5)
+    x = x.reshape(B * Hkv * halves * group, L, D)
     return jnp.pad(x, ((0, 0), (0, Lp - L), (0, 0))) if Lp != L else x
 
 
 def _bd_q_from_bh(x, B, L, Hkv, group):
-    D = x.shape[-1]
-    x = x[:, :L].reshape(B, Hkv, 2, group, L, D).transpose(0, 2, 4, 1, 3, 5)
-    return x.reshape(B, 2 * L, Hkv * group, D)
+    halves, D = x.shape[0] // (B * Hkv * group), x.shape[-1]
+    x = x[:, :L].reshape(B, Hkv, halves, group, L, D).transpose(0, 2, 4, 1, 3, 5)
+    return x.reshape(B, halves * L, Hkv * group, D)
 
 
 def _bd_halves(x, B, L, Lp):
@@ -1279,7 +1296,8 @@ def _bd_flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, kn_ref, vn_ref, do_ref, lse_re
                              dk_ref, dv_ref, dkn_ref, dvn_ref, dk_acc, dv_acc, dkn_acc, dvn_acc,
                              *, walk, head, scale, tile, block_len, group):
     """``_flash_bwd_dkv_kernel`` over the ``2 * group`` query heads of a kv head
-    (the noised ones first) under the block-diffusion mask, and on a noised
+    (the noised ones first; the ``group`` noised ones alone in a call of one
+    query set) under the block-diffusion mask, and on a noised
     head's diagonal tile the gradients of the column's noised keys and values,
     summed over the group in their own VMEM accumulators."""
     kj, qi, first, last, inside = walk(pl.program_id(1), pl.program_id(2))
@@ -1327,10 +1345,18 @@ def _bd_flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, kn_ref, vn_ref, do_ref, lse_re
         dvn_ref[0] = dvn_acc[...].astype(dvn_ref.dtype)
 
 
-def _noised_head(group):
-    """Grid index of a query head ([B*Hkv*2*group]) -> 1 where it is a noised
-    head (the first ``group`` of its kv head's ``2 * group``), else 0."""
+def _noised_head(group, halves):
+    """Grid index of a query head ([B*Hkv*halves*group]) -> 1 where it is a
+    noised head (the first ``group`` of its kv head's ``2 * group``; every head
+    of a call of one query set), else 0."""
+    if halves == 1:
+        return lambda b: 1
     return lambda b: (jax.lax.rem(jax.lax.div(b, group), 2) == 0).astype(jnp.int32)
+
+
+def _bd_queries(halves):
+    """The gauges' ``queries`` label of a call: ``noised`` for one query set."""
+    return "noised" if halves == 1 else None
 
 
 def _bd_queries_major(kernel, qb, kb, knb, blocks, valid_len, group):
@@ -1339,9 +1365,10 @@ def _bd_queries_major(kernel, qb, kb, knb, blocks, valid_len, group):
     head, which never reads them, stays on block 0)."""
     block_q, block_k = blocks
     D = qb.shape[-1]
+    halves = qb.shape[0] // (kb.shape[0] * group)
     tile, grid, walk, block = _tiling(kernel, qb.shape[1], blocks, True, valid_len, None,
-                                      2 * group)
-    kv_head, noised_of = _kv_head(2 * group), _noised_head(group)
+                                      halves * group, _bd_queries(halves))
+    kv_head, noised_of = _kv_head(halves * group), _noised_head(group, halves)
     q_spec = pl.BlockSpec((1, block_q, D), lambda b, o, s: (b, block(o, s)[0], 0))
     k_spec = pl.BlockSpec((1, block_k, D), lambda b, o, s: (kv_head(b), block(o, s)[1], 0))
     kn_spec = pl.BlockSpec((1, block_q, D), lambda b, o, s: (
@@ -1351,8 +1378,8 @@ def _bd_queries_major(kernel, qb, kb, knb, blocks, valid_len, group):
 
 
 def _bd_fwd_call(qb, kb, vb, knb, vnb, blocks, valid_len, block_len, group, scale, interpret):
-    """``bd_flash_fwd`` over ``[B*Hkv*2*group, Lp, D]`` q and ``[B*Hkv, Lp, D]``
-    clean and noised k and v: (out, lse) at q's heads."""
+    """``bd_flash_fwd`` over ``[B*Hkv*h*group, Lp, D]`` q (h halves) and
+    ``[B*Hkv, Lp, D]`` clean and noised k and v: (out, lse) at q's heads."""
     BH, Lp, D = qb.shape
     tile, grid, walk, noised_of, (q_spec, k_spec, kn_spec, row_spec) = _bd_queries_major(
         "bd_flash_fwd", qb, kb, knb, blocks, valid_len, group)
@@ -1393,13 +1420,13 @@ def _bd_dq_call(qb, kb, vb, knb, vnb, dob, lse, delta, blocks, valid_len, block_
 def _bd_dkv_call(qb, kb, vb, knb, vnb, dob, lse, delta, blocks, valid_len, block_len, group,
                  scale, interpret):
     """``bd_flash_bwd_dkv``: (dK, dV, dK_noised, dV_noised) at the kv heads'
-    count, keys-major; the inner axis walks each of the ``2 * group`` query
-    heads' q blocks in turn."""
+    count, keys-major; the inner axis walks each of a kv head's ``2 * group``
+    query heads' q blocks in turn (``group`` in a call of one query set)."""
     BH, Lp, D = kb.shape
     block_q, block_k = blocks
-    reps = 2 * group
+    reps = qb.shape[0] // BH
     tile, grid, walk, block = _tiling("bd_flash_bwd_dkv", Lp, blocks, True, valid_len, None,
-                                      reps)
+                                      reps, _bd_queries(reps // group))
 
     def q_at(b, o, s):
         _, qi, g = block(o, s)
@@ -1409,10 +1436,11 @@ def _bd_dkv_call(qb, kb, vb, knb, vnb, dob, lse, delta, blocks, valid_len, block
     k_spec = pl.BlockSpec((1, block_k, D), lambda b, o, s: (b, block(o, s)[0], 0))
     row_spec = pl.BlockSpec((1, 1, block_q), lambda b, o, s: (q_at(b, o, s)[0], 0,
                                                             q_at(b, o, s)[1]))
+    # a walk of one query head a column names no head (0): the kernel compares a scalar
+    head = (lambda o, s: block(o, s)[2]) if reps > 1 else (lambda o, s: jnp.int32(0))
     return pl.pallas_call(
-        functools.partial(_bd_flash_bwd_dkv_kernel, walk=walk,
-                          head=lambda o, s: block(o, s)[2], scale=scale, tile=tile,
-                          block_len=block_len, group=group),
+        functools.partial(_bd_flash_bwd_dkv_kernel, walk=walk, head=head, scale=scale,
+                          tile=tile, block_len=block_len, group=group),
         grid=(BH, *grid),
         in_specs=[q_spec, k_spec, k_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=[k_spec] * 4,
@@ -1462,9 +1490,10 @@ def _bd_backward(q, k, v, out, lse, g, block_len, block, interpret):
 def bd_flash_attention(q, k, v, block_len: int, block: int | None = None,
                        interpret: bool = False):
     """Block-diffusion attention (the mask of :func:`block_diffusion_mask`) by
-    the Pallas kernels.  q: [B, 2L, Hq, D], k, v: [B, 2L, Hkv, D], the noised
-    half first -> [B, 2L, Hq, D].  ``block``: the square tile of every kernel
-    (None: chosen per kernel from the shape)."""
+    the Pallas kernels.  k, v: [B, 2L, Hkv, D], the noised half first; q:
+    [B, 2L, Hq, D], or [B, L, Hq, D] for the noised half's queries alone (the
+    mask's first L rows) -> q's shape.  ``block``: the square tile of every
+    kernel (None: chosen per kernel from the shape)."""
     return _bd_forward(q, k, v, block_len, block, interpret)[0]
 
 
@@ -1487,7 +1516,8 @@ def attention(q, k, v, causal: bool = True, window: int | None = None,
     ``tpu`` (a kernel that does not compile raises — it never quietly becomes
     the reference), the fused-XLA reference on every other backend.
     ``block_diffusion``: the block length of a block-diffusion call over
-    ``[x_noised ; x_clean]`` (:func:`bd_flash_attention`)."""
+    ``[x_noised ; x_clean]``, q over both halves or the noised one
+    (:func:`bd_flash_attention`)."""
     if block_diffusion is not None:
         if jax.default_backend() == "tpu":
             return bd_flash_attention(q, k, v, block_diffusion)

@@ -53,24 +53,37 @@ def _bd_operands(L, Hq, Hkv, D=8, seed=0):
 
 @pytest.mark.parametrize("group", [1, 8])
 @pytest.mark.parametrize("block_len", [1, 4, 16])
-@pytest.mark.parametrize("L,block", [(256, 128), (640, 256)], ids=["L256", "L640_padded_tail"])
-def test_bd_kernels_are_the_mask_written_out(L, block, block_len, group):
+@pytest.mark.parametrize("L,block,queries", [(256, 128, "both"), (640, 256, "both"),
+                                             (256, 128, "noised"), (640, 256, "noised")],
+                         ids=["L256", "L640_padded_tail", "L256_noised", "L640_padded_tail_noised"])
+def test_bd_kernels_are_the_mask_written_out(L, block, queries, block_len, group):
     """Forward, dQ, dK and dV of the three ``bd_flash_*`` kernels against
     ``reference_attention`` with the [2L, 2L] mask written out (640 under blocks of 256
-    pads its tail to 768).  Tolerance 2e-6 of a leaf's largest entry: float32 in interpret
-    mode, the online softmax's summation order against the reference's one softmax."""
-    q, k, v, w = _bd_operands(L, group, 1)
+    pads its tail to 768).  ``noised``: q over the noised half alone (L rows against 2L
+    keys: the mask's first L rows), and the same call against the full call's noised half
+    under a cotangent that is zero on the clean half (dK / dV over both halves).
+    Tolerance 2e-6 of a leaf's largest entry: float32 in interpret mode, the online
+    softmax's summation order against the reference's one softmax."""
+    q_full, k, v, w_full = _bd_operands(L, group, 1)
+    rows = L if queries == "noised" else 2 * L
+    q, w = q_full[:, :rows], w_full[:, :rows]
 
-    def out_and_grads(fn):
+    def out_and_grads(fn, q, w):
         out, vjp = jax.vjp(fn, q, k, v)
         return out, vjp(w)
 
+    def kernels(*a):
+        return fa.bd_flash_attention(*a, block_len, block, True)
+
     with jax.default_matmul_precision("highest"):
         want = jax.jit(lambda: out_and_grads(
-            lambda *a: fa.reference_attention(*a, block_diffusion=block_len)))()
-    got = jax.jit(lambda: out_and_grads(
-        lambda *a: fa.bd_flash_attention(*a, block_len, block, True)))()
+            lambda *a: fa.reference_attention(*a, block_diffusion=block_len), q, w))()
+    got = jax.jit(lambda: out_and_grads(kernels, q, w))()
     _assert_close(want, got, 2e-6)
+    if queries == "noised":
+        out, (dq, dk, dv) = jax.jit(lambda: out_and_grads(
+            kernels, q_full, w_full.at[:, L:].set(0.0)))()
+        _assert_close((out[:, :L], (dq[:, :L], dk, dv)), got, 2e-6)
 
 
 def test_block_length_one_is_the_causal_call_on_the_clean_half():
@@ -90,19 +103,25 @@ def test_block_length_one_is_the_causal_call_on_the_clean_half():
 def test_bd_grids_walk_live_steps_and_leave_their_gauges():
     """The cell's shape: every row's run is causal-shaped, so the paired walk holds live
     steps only (the issue asks >= 0.95); the gauges carry the bd names, the causal calls'
-    theirs."""
+    theirs, and the last layer's call of the noised queries alone a second label
+    ``queries: noised``, so the full calls' readings stay."""
     from fedml_tpu.core import obs
 
     S = jax.ShapeDtypeStruct
-    q, kv = S((1, 2 * 8192, 32, 128), jnp.bfloat16), S((1, 2 * 8192, 4, 128), jnp.bfloat16)
-    jax.make_jaxpr(jax.grad(lambda *a: fa.bd_flash_attention(*a, 4).astype(jnp.float32).sum(),
-                            (0, 1, 2)))(q, kv, kv)
-    gauges = {(r["metric"], r["labels"].get("kernel")): r["value"] for r in obs.registry().export()
+    kv = S((1, 2 * 8192, 4, 128), jnp.bfloat16)
+    for rows in (2 * 8192, 8192):
+        jax.make_jaxpr(jax.grad(
+            lambda *a: fa.bd_flash_attention(*a, 4).astype(jnp.float32).sum(), (0, 1, 2)))(
+                S((1, rows, 32, 128), jnp.bfloat16), kv, kv)
+    gauges = {(r["metric"], r["labels"].get("kernel"), r["labels"].get("queries")): r["value"]
+              for r in obs.registry().export()
               if r["kind"] == "gauge" and r["labels"].get("kernel", "").startswith("bd_")}
     for kernel in fa._BD_KERNELS:
-        assert gauges[("flash.live_step_share", kernel)] >= 0.95, kernel
-        assert gauges[("flash.kv_group", kernel)] == 16  # the noised copy's heads beside the 8
-        assert gauges[("flash.block_q", kernel)] == gauges[("flash.block_k", kernel)]
+        for queries, group in ((None, 16), ("noised", 8)):  # the noised copy's heads beside the 8
+            assert gauges[("flash.live_step_share", kernel, queries)] >= 0.95, kernel
+            assert gauges[("flash.kv_group", kernel, queries)] == group
+            assert (gauges[("flash.block_q", kernel, queries)]
+                    == gauges[("flash.block_k", kernel, queries)])
 
 
 @pytest.mark.parametrize("block_len,L,error", [(3, 96, "divide"), (4, 30, "divide"),
@@ -240,8 +259,52 @@ def test_loss_and_every_gradient_are_the_references(built, model, mask):
     assert float(sums["bd.positions"]) == 2 * 32 * float(mask.sum())
     assert float(sums["bd.masked"]) == ref.masked_count(tokens, mask, noise, model)
     assert float(sums["moe.assignments_dropped"]) == 0.0
-    # two layers route every position of both copies of both rows
-    assert float(sums["moe.assignments_total"]) == 2 * 2 * 2 * 32 * model["num_experts_per_tok"]
+    # layer 0 routes every position of both copies of both rows, the last layer the noised
+    # copy's alone (its clean half runs only through k and v)
+    assert float(sums["moe.assignments_total"]) == (2 + 1) * 2 * 32 * model["num_experts_per_tok"]
+
+
+def _bd_calls(jaxpr, found=None):
+    """(kernel name, leading dim of its q operand) of every ``bd_flash_*`` call in the order
+    the jaxpr holds them, sub-jaxprs included."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and eqn.params["name"].startswith("bd_"):
+            found.append((eqn.params["name"], eqn.invars[0].aval.shape[0]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _bd_calls(sub, found)
+    return found
+
+
+def test_the_last_block_runs_its_clean_half_only_through_k_and_v(built, model, monkeypatch):
+    """One training step of the tiny preset (two layers): layer 0's expert layer routes both
+    copies of both rows, the last layer's the noised copy alone (rows x L x k assignments);
+    traced with the bd kernels in, layer 0 calls each of them with q over both halves (2 x 8
+    query heads a row), the last layer with q over the noised half (8); ``bd.kv_only_layers``
+    reads 1."""
+    from fedml_tpu.core import obs
+
+    module, weights, tokens = built
+    program, mask, key = sim_kimi_linear.to_program(weights), jnp.ones(2), jax.random.PRNGKey(12)
+    rows, length, k = tokens.shape + (model["num_experts_per_tok"],)
+    _, sown = jax.jit(lambda v: module.apply(
+        v, tokens, train=True, rngs={"noise": key}, mutable=["counters"],
+        targets=(tokens, mask)))(program)
+    assignments = [float(sown["counters"][f"layer{i}"]["moe"]["moe.assignments_total"])
+                   for i in range(model["num_hidden_layers"])]
+    assert assignments == [2 * rows * length * k, rows * length * k]
+
+    monkeypatch.setattr(fa, "attention", lambda q, k, v, block_diffusion=None: (
+        fa.bd_flash_attention(q, k, v, block_diffusion, None, True)))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda v: _program_loss(module, v, tokens, mask, key)[0]))(program).jaxpr
+    heads = rows * model["num_attention_heads"]
+    calls = _bd_calls(jaxpr)
+    assert [n for name, n in calls if name == "bd_flash_fwd"] == [2 * heads, heads]
+    for kernel in ("bd_flash_bwd_dq", "bd_flash_bwd_dkv"):  # the backward walks the layers back
+        assert [n for name, n in calls if name == kernel] == [heads, 2 * heads], kernel
+    gauge = [r["value"] for r in obs.registry().export() if r["metric"] == "bd.kv_only_layers"]
+    assert gauge == [1]
 
 
 def test_the_engine_hands_the_step_key_on_as_the_noise_stream(built, model):

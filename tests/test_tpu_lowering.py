@@ -276,28 +276,35 @@ def test_a_large_tiers_expert_layer_moves_its_rows_by_gathers(v5e):
 
 # block diffusion over [x_noised ; x_clean]: the benchmark's own call (sdar-30b-a3b-sim: 32 / 4
 # heads of 128, one sequence of 8,192 data tokens, blocks of 4) and a ragged length in
-# float32 (B, L, Hq, Hkv, D, block_length, dtype)
-BD_SHAPES = [(1, 8192, 32, 4, 128, 4, jnp.bfloat16), (2, 1000, 8, 2, 128, 8, jnp.float32)]
+# float32, with q over both halves (2) and over the noised half alone (1: a last layer's
+# call) (B, L, Hq, Hkv, D, block_length, dtype, query halves)
+BD_SHAPES = [(1, 8192, 32, 4, 128, 4, jnp.bfloat16, 2), (2, 1000, 8, 2, 128, 8, jnp.float32, 2),
+             (1, 8192, 32, 4, 128, 4, jnp.bfloat16, 1), (2, 1000, 8, 2, 128, 8, jnp.float32, 1)]
 
 
-def _bd_case(B, L, Hq, Hkv, D, block_len, dtype, sharding=None):
+def _bd_id(shape):
+    return "x".join(map(str, shape[:6])) + ("_noised" if shape[7] == 1 else "")
+
+
+def _bd_case(B, L, Hq, Hkv, D, block_len, dtype, halves, sharding=None):
     from fedml_tpu.ops.flash_attention import bd_flash_attention
 
     kw = {} if sharding is None else {"sharding": sharding}
-    q = jax.ShapeDtypeStruct((B, 2 * L, Hq, D), dtype, **kw)
+    q = jax.ShapeDtypeStruct((B, halves * L, Hq, D), dtype, **kw)
     kv = jax.ShapeDtypeStruct((B, 2 * L, Hkv, D), dtype, **kw)
     grad = jax.grad(lambda *a: bd_flash_attention(*a, block_len).astype(jnp.float32).sum(),
                     argnums=(0, 1, 2))
     return grad, (q, kv, kv)
 
 
-@pytest.mark.parametrize("shape", BD_SHAPES, ids=lambda s: "x".join(map(str, s[:6])))
+@pytest.mark.parametrize("shape", BD_SHAPES, ids=_bd_id)
 def test_block_diffusion_calls_are_named_apart(shape):
     """The mode's three calls, one of each name a layer: 5 operands -> 2 results (q, the
     clean and the noised k and v), 8 -> 1 and 8 -> 4 (the noised keys' dK and dV beside the
-    clean ones'), q at 2 x the query heads: the accepted readers, which find the causal
-    calls by name and by (3 in, 2 out), cannot take them for those."""
-    B, L, Hq, Hkv, D, _, dtype = shape
+    clean ones'), q at 2 x the query heads (1 x for the noised queries alone): the accepted
+    readers, which find the causal calls by name and by (3 in, 2 out), cannot take them for
+    those."""
+    B, L, Hq, Hkv, D, _, dtype, halves = shape
     grad, args = _bd_case(*shape)
     text = jax.jit(grad).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
     calls = {}
@@ -308,12 +315,12 @@ def test_block_diffusion_calls_are_named_apart(shape):
             calls[name] = (operands.count("tensor<"), results.count("tensor<"),
                            re.search(r"tensor<(\w+)x\w+>", results).group(1))
     Lp = -(-L // 128) * 128
-    assert calls == {"bd_flash_fwd": (5, 2, f"{2 * B * Hq}x{Lp}x{D}"),
-                     "bd_flash_bwd_dq": (8, 1, f"{2 * B * Hq}x{Lp}x{D}"),
+    assert calls == {"bd_flash_fwd": (5, 2, f"{halves * B * Hq}x{Lp}x{D}"),
+                     "bd_flash_bwd_dq": (8, 1, f"{halves * B * Hq}x{Lp}x{D}"),
                      "bd_flash_bwd_dkv": (8, 4, f"{B * Hkv}x{Lp}x{D}")}
 
 
-@pytest.mark.parametrize("shape", BD_SHAPES, ids=lambda s: "x".join(map(str, s[:6])))
+@pytest.mark.parametrize("shape", BD_SHAPES, ids=_bd_id)
 def test_block_diffusion_kernels_compile_under_mosaic(v5e, shape):
     grad, args = _bd_case(*shape, sharding=jax.sharding.SingleDeviceSharding(v5e))
     compiled = jax.jit(grad).lower(*args).compile()
