@@ -30,7 +30,7 @@ from typing import Dict, Iterable, Optional, Tuple
 # name contains,
 # every model scope before the engine's and the round's
 SCOPES: Tuple[str, ...] = (
-    "lm.mtp", "lm.kda", "lm.mla", "lm.moe.", "lm.attn.window", "lm.attn.global",
+    "lm.mtp", "lm.kda", "lm.ssm", "lm.mla", "lm.moe.", "lm.attn.window", "lm.attn.global",
     "lm.attn.bd", "lm.attn", "lm.bd.noise", "lm.mlp", "lm.embed", "lm.head", "lm.norm",
     "fed.loss", "fed.sgd", "fed.gather", "fed.flush", "fed.exchange", "fed.server_step")
 # what the table's last rows are called

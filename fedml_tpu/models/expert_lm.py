@@ -1,18 +1,22 @@
-"""What the config-driven sparse decoders share (``kimi_linear.py``,
-``smallthinker.py``, ``sdar_moe.py``): the grouped-query mixer, the expert layer —
-routing, the grouped products over the experts held here, the round's counters —
-RMSNorm, the dense gated MLP, the initialiser and the LM shell (embedding ->
-blocks under per-layer ``remat`` -> final RMSNorm -> untied head, and after the
-blocks the multi-token-prediction module of a model that has one:
-:class:`PredictionModule`).
+"""What the five config-driven decoders share (``kimi_linear.py``,
+``smallthinker.py``, ``glm4_moe_lite.py``, ``sdar_moe.py``, ``nemotron_h.py``): the
+grouped-query mixer, the expert layer — routing, the grouped products over the
+experts held here, gated or not, the round's counters — RMSNorm, the dense MLP,
+the causal depthwise convolution of the linear mixers (KDA's and Mamba-2's), the
+initialiser and the LM shell (embedding -> blocks under per-layer ``remat`` ->
+final RMSNorm -> untied head, and after the blocks the multi-token-prediction
+module of a model that has one: :class:`PredictionModule`).
 
 A model's config dataclass gives the shared parts these fields:
 ``hidden_size``, ``num_hidden_layers``, ``vocab_size``, ``rms_norm_eps``,
 ``moe_intermediate_size``, ``n_routed_experts``, ``experts_held`` ([lo, hi): the
 experts this process holds), ``num_experts_per_token``, ``num_shared_experts``,
 ``dtype``, ``remat``; a model whose expert layer routes on its own input
-(``kimi_linear``: sigmoid scores + correction bias) also gives
-``routed_scaling_factor`` and ``moe_renormalize``; a model with a
+(``kimi_linear``, ``nemotron_h``: sigmoid scores + correction bias) also gives
+``routed_scaling_factor`` and ``moe_renormalize``; a model whose experts are not
+gated (``nemotron_h``: ``activation(x W_up) W_down``) gives ``moe_gated`` False, and
+one whose shared expert has a width of its own ``shared_expert_intermediate_size``;
+a model with a
 multi-token-prediction module gives ``num_nextn_predict_layers`` (1) and
 ``mtp_loss_weight``.
 
@@ -51,7 +55,7 @@ MTP_COUNTERS = ("lm.loss_main", "mtp.loss", "mtp.positions")
 # (``ops/kept.py``).  A kernel's output is cheap to keep and dear to rebuild; its
 # inputs (norm, projections, rotation, convolutions, gates) are rebuilt
 KEPT = ("flash_fwd.out", "flash_fwd.lse", "kda_fwd.o", "kda_fwd.states", "bd_flash_fwd.out",
-        "bd_flash_fwd.lse")
+        "bd_flash_fwd.lse", "ssd_fwd.y", "ssd_fwd.states")
 
 
 def load_config(model_config) -> dict:
@@ -92,21 +96,43 @@ def swiglu(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+def relu2(x):
+    """Squared ReLU (``mlp_hidden_act: relu2``)."""
+    return jnp.square(jax.nn.relu(x))
+
+
+def causal_conv(x, w, bias=None):
+    """Depthwise causal convolution over time.  x: [B, L, ...]; w: [K, ...]:
+    ``y_t = sum_i w[i] x_{t-(K-1)+i}`` (zeros before the sequence), plus ``bias``
+    [...] where given."""
+    K, L = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (K - 1, 0)) + ((0, 0),) * (x.ndim - 2))
+    y = sum(padded[:, i:i + L] * w[i].astype(x.dtype) for i in range(K))
+    return y if bias is None else y + bias.astype(x.dtype)
+
+
 class DenseMLP(nn.Module):
+    """``activation(h W_gate) * (h W_up)) W_down``, or where not ``gated``
+    ``activation(h W_up) W_down``."""
     cfg: Any
     width: int
+    activation: Callable = jax.nn.silu
+    gated: bool = True
 
     @nn.compact
     def __call__(self, h):
         d, f, dt = self.cfg.hidden_size, self.width, self.cfg.dtype
+        shapes = (("w_gate", (d, f), d),) if self.gated else ()
         w = {n: self.param(n, _normal(fi), s, jnp.float32).astype(dt) for n, s, fi in (
-            ("w_gate", (d, f), d), ("w_up", (d, f), d), ("w_down", (f, d), f))}
-        return swiglu(h, w["w_gate"], w["w_up"], w["w_down"])
+            shapes + (("w_up", (d, f), d), ("w_down", (f, d), f)))}
+        if not self.gated:
+            return self.activation(h @ w["w_up"]) @ w["w_down"]
+        return (self.activation(h @ w["w_gate"]) * (h @ w["w_up"])) @ w["w_down"]
 
 
 
 class GQAMixer(nn.Module):
-    """Grouped-query attention on ``a`` [B, L, d] (``smallthinker``, ``sdar_moe``):
+    """Grouped-query attention on ``a`` [B, L, d] (``smallthinker``, ``sdar_moe``, ``nemotron_h``):
     ``cfg.num_attention_heads`` query heads over ``cfg.num_key_value_heads`` key/value
     heads of ``cfg.head_dim`` (``cfg`` any config with those, ``hidden_size``,
     ``rope_theta``, ``rms_norm_eps`` and ``dtype``); ``window`` keys a query sees
@@ -270,9 +296,10 @@ def _rows_of(pos, start, rows: int, n_local):
 def grouped_experts(h, chosen, weights, held: Tuple[int, int], w_gate, w_up, w_down,
                     n_routed: int, activation: Callable = jax.nn.silu):
     """Sum over the chosen experts that are held here of weight x gated expert
-    (``activation(x W_gate) * (x W_up)) W_down``).  h: [T, d]; chosen, weights:
-    [T, k]; w_*: [E_held, ...]; ``n_routed``: the router's width.  Returns
-    ([T, d], counters).
+    (``activation(x W_gate) * (x W_up)) W_down``), or, with ``w_gate`` None, of
+    weight x expert ``activation(x W_up) W_down`` (two grouped products, not three).
+    h: [T, d]; chosen, weights: [T, k]; w_*: [E_held, ...]; ``n_routed``: the
+    router's width.  Returns ([T, d], counters).
 
     The T*k assignments are sorted by expert, those of absent experts last.
     The local ones are then worked off in blocks (gather the tokens, three
@@ -350,9 +377,13 @@ def grouped_experts(h, chosen, weights, held: Tuple[int, int], w_gate, w_up, w_d
             else:
                 x = jnp.where(live[:, None], h[token], 0)
         with jax.named_scope("lm.moe.experts"):
-            gate = jax.lax.ragged_dot(x, w_gate, block_sizes)
-            up = jax.lax.ragged_dot(x, w_up, block_sizes)
-            y = jax.lax.ragged_dot(activation(gate) * up, w_down, block_sizes)
+            if w_gate is None:
+                hidden = activation(jax.lax.ragged_dot(x, w_up, block_sizes))
+            else:
+                gate = jax.lax.ragged_dot(x, w_gate, block_sizes)
+                up = jax.lax.ragged_dot(x, w_up, block_sizes)
+                hidden = activation(gate) * up
+            y = jax.lax.ragged_dot(hidden, w_down, block_sizes)
         with jax.named_scope("lm.moe.combine"):
             if gathered:
                 w = spread_rows(flat_weights, rows, live,
@@ -386,7 +417,9 @@ class ExpertShare(nn.Module):
     shared expert, which every process computes alike).  ``routing``: the
     (chosen, weights) a block decided elsewhere (``smallthinker``: before the
     attention, on the attention's input); left ``None`` the layer routes on
-    the tensor it transforms, by sigmoid scores and a correction bias."""
+    the tensor it transforms, by sigmoid scores and a correction bias.  The
+    experts and the shared expert are gated unless ``cfg.moe_gated`` is False
+    (``activation(x W_up) W_down``; gauge ``moe.gated``, 1 / 0)."""
     cfg: Any
     activation: Callable = jax.nn.silu
 
@@ -410,19 +443,22 @@ class ExpertShare(nn.Module):
                 routing = route(scores, bias, cfg.num_experts_per_token,
                                 cfg.routed_scaling_factor, cfg.moe_renormalize)
         chosen, weights = routing
+        gated = getattr(cfg, "moe_gated", True)
+        obs.gauge_set("moe.gated", int(gated))
+        shapes = (("e_gate", (d, f), d),) if gated else ()
         experts = {n: self.param(n, _normal(fi), (hi - lo,) + s, jnp.float32).astype(dt)
-                   for n, s, fi in (("e_gate", (d, f), d), ("e_up", (d, f), d),
-                                    ("e_down", (f, d), f))}
+                   for n, s, fi in shapes + (("e_up", (d, f), d), ("e_down", (f, d), f))}
         out, counters = grouped_experts(
-            flat, chosen, weights, (lo, hi), experts["e_gate"], experts["e_up"],
+            flat, chosen, weights, (lo, hi), experts.get("e_gate"), experts["e_up"],
             experts["e_down"], cfg.n_routed_experts, self.activation)
         if train:
             for name, value in counters.items():
                 self.sow("counters", name, value, reduce_fn=jnp.add,
                          init_fn=lambda: jnp.zeros((), jnp.float32))
         if cfg.num_shared_experts:
+            width = getattr(cfg, "shared_expert_intermediate_size", 0) or f * cfg.num_shared_experts
             with jax.named_scope("lm.moe.shared"):
-                out = out + DenseMLP(cfg, f * cfg.num_shared_experts, name="shared")(flat)
+                out = out + DenseMLP(cfg, width, self.activation, gated, name="shared")(flat)
         return out.reshape(h.shape)
 
 

@@ -21,7 +21,8 @@ _BF16_MODELS = {"resnet20", "resnet56", "resnet18", "resnet18_gn"}
 # states its own compute dtype.  The module ``models/<name>.py`` has the
 # classes ``<prefix>Config`` (with ``from_dict``) and ``<prefix>LM``
 _CONFIG_MODELS = {"kimi_linear": "KimiLinear", "smallthinker": "SmallThinker",
-                  "glm4_moe_lite": "Glm4MoeLite", "sdar_moe": "SdarMoe"}
+                  "glm4_moe_lite": "Glm4MoeLite", "sdar_moe": "SdarMoe",
+                  "nemotron_h": "NemotronH"}
 
 
 def create(args: Any, output_dim: int) -> nn.Module:

@@ -43,10 +43,10 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import kda as kda_ops
-# the expert layer, the norm, the dense MLP and the LM shell are shared with
-# ``smallthinker.py``
-from .expert_lm import (DecoderLM, DenseMLP, ExpertShare, _normal, compute_dtype, held_range,
-                        rms_norm)
+# the expert layer, the norm, the dense MLP, the short convolution and the LM shell
+# are shared with the other config-driven decoders
+from .expert_lm import (DecoderLM, DenseMLP, ExpertShare, _normal, causal_conv, compute_dtype,
+                        held_range, rms_norm)
 from .latent_attention import MLAMixer
 
 @dataclasses.dataclass(frozen=True)
@@ -123,14 +123,6 @@ class KimiLinearConfig:
             routed_scaling_factor=float(cfg["routed_scaling_factor"]),
             moe_renormalize=bool(cfg["moe_renormalize"]),
             dtype=dtype, remat=bool(cfg.get("remat", False)))
-
-
-def causal_conv(x, w):
-    """Depthwise causal convolution over time.  x: [B, L, ...]; w: [K, ...]:
-    ``y_t = sum_i w[i] x_{t-(K-1)+i}`` (zeros before the sequence)."""
-    K, L = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (K - 1, 0)) + ((0, 0),) * (x.ndim - 2))
-    return sum(padded[:, i:i + L] * w[i].astype(x.dtype) for i in range(K))
 
 
 class KDAMixer(nn.Module):

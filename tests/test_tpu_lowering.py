@@ -325,3 +325,81 @@ def test_block_diffusion_kernels_compile_under_mosaic(v5e, shape):
     grad, args = _bd_case(*shape, sharding=jax.sharding.SingleDeviceSharding(v5e))
     compiled = jax.jit(grad).lower(*args).compile()
     assert [o.shape for o in compiled.out_info] == [a.shape for a in args]
+
+
+# the SSD kernels at the benchmark cell's shape (nemotron-3-nano-30b-a3b-sim, one
+# sequence: 64 heads of 64 over 8 groups of 128, four runs of 16 chunks) and at a
+# ragged length under float32 inputs (two groups of two heads, one run of 8 chunks)
+# (B, L, heads, head_dim, groups, state, dtype)
+SSD_SHAPES = [(1, 8192, 64, 64, 8, 128, jnp.bfloat16), (2, 1000, 4, 64, 2, 128, jnp.float32)]
+
+
+def _ssd_case(B, L, H, P, G, N, dtype, sharding=None):
+    from fedml_tpu.ops import ssd
+
+    kw = {} if sharding is None else {"sharding": sharding}
+    args = (jax.ShapeDtypeStruct((B, L, H, P), dtype, **kw),
+            jax.ShapeDtypeStruct((B, L, H), jnp.float32, **kw),
+            jax.ShapeDtypeStruct((H,), jnp.float32, **kw),
+            jax.ShapeDtypeStruct((B, L, G, N), dtype, **kw),
+            jax.ShapeDtypeStruct((B, L, G, N), dtype, **kw))
+    grad = jax.grad(lambda *a: ssd.ssd_pallas(*a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2, 3, 4))
+    return grad, args
+
+
+@pytest.mark.parametrize("shape", SSD_SHAPES, ids=lambda s: "x".join(map(str, s[:6])))
+def test_ssd_kernels_lower_for_tpu_and_keep_their_names_and_arity(shape):
+    """What ``ssd_fwd_roofline`` / ``ssd_bwd_roofline`` find the kernels by: one call of
+    each name, x, dt, the running sums, B and C in -> y and the runs' states out (5 -> 2),
+    those five, the states and dy in -> the five gradients out (7 -> 5), y and dx at the
+    rows' shape [B, Lp, heads x head_dim]."""
+    B, L, H, P, G, N, dtype = shape
+    grad, args = _ssd_case(*shape)
+    text = jax.jit(grad).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    calls = {}
+    for line in text.splitlines():
+        if "@tpu_custom_call" in line:
+            name = re.search(r'kernel_name = "(\w+)"', line).group(1)
+            operands, results = line.rsplit(" : (", 1)[1].split(") -> ")
+            assert name not in calls, f"two calls named {name}"
+            calls[name] = (operands.count("tensor<"), results.count("tensor<"),
+                           re.search(r"tensor<(\w+)x\w+>", results).group(1))
+    chunks = -(-L // 128)
+    run = chunks if chunks <= 16 else 8 if (-(-chunks // 8) * 8) < (-(-chunks // 16) * 16) else 16
+    Lp = -(-chunks // run) * run * 128
+    rows = f"{B}x{Lp}x{H * P}"
+    assert calls == {"ssd_fwd": (5, 2, rows), "ssd_bwd": (7, 5, rows)}
+
+
+@pytest.mark.parametrize("shape", SSD_SHAPES, ids=lambda s: "x".join(map(str, s[:6])))
+def test_ssd_kernels_compile_under_mosaic(v5e, shape):
+    """The off-chip guard of the kernels' VMEM and layouts."""
+    grad, args = _ssd_case(*shape, sharding=jax.sharding.SingleDeviceSharding(v5e))
+    compiled = jax.jit(grad).lower(*args).compile()
+    assert [o.shape for o in compiled.out_info] == [a.shape for a in args]
+    text = compiled.as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+
+
+def test_a_non_gated_expert_layer_compiles_for_v5e(v5e):
+    """``sim.fedavg.nemotron-nano.1chip``'s expert layer, forward and backward at the cell's
+    shape (8 of 128 experts, 6 a token, squared ReLU): two grouped products a block, not
+    three."""
+    from fedml_tpu.models import expert_lm
+
+    sharding = jax.sharding.SingleDeviceSharding(v5e)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def experts(h, chosen, weights, w_up, w_down):
+        out, counters = expert_lm.grouped_experts(h, chosen, weights, (0, 8), None, w_up,
+                                                  w_down, 128, expert_lm.relu2)
+        return out.astype(jnp.float32).sum() + counters["moe.assignments_dropped"]
+
+    args = (s((8192, 2688), jnp.bfloat16), s((8192, 6), jnp.int32), s((8192, 6), jnp.float32),
+            s((8, 2688, 1856), jnp.bfloat16), s((8, 1856, 2688), jnp.bfloat16))
+    lowered = jax.jit(jax.grad(experts, argnums=(0, 3, 4))).lower(*args)
+    assert lowered.as_text().count("ragged_dot") > 0
+    assert "ragged" in lowered.compile().as_text()
