@@ -33,6 +33,7 @@ sums them.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 from typing import Any, Callable, ClassVar, Optional, Tuple
@@ -41,6 +42,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import optax
+from jax.experimental.xla_metadata import set_xla_metadata
 
 # what an expert layer sows a step; the packed round returns their sums
 COUNTERS = ("moe.assignments_local", "moe.assignments_total", "moe.expert_load_max",
@@ -250,6 +252,83 @@ def expert_blocks(total: int, held: int, routed: int) -> Tuple[int, Tuple[int, .
 # forms cross between an eighth and three eighths
 GATHERED_SHARE = 0.25
 
+# The TPU compiler lowers each grouped product (``jax.lax.ragged_dot``) to a Mosaic kernel of its
+# own, ``ragged-dot-none``, and takes its tiles (rows, k, n) from the widths: rows 512, a k or n
+# tile the largest of 512, 256 and 128 that divides the width (``_compiler_tile``).  The
+# instruction's frontend attribute ``ragged_dot_tiling`` records them, and a tiling set there with
+# ``set_xla_metadata`` is taken as asked; the kernel masks a tile that runs past the width.
+# Readings on the v5e (jax 0.9.0, libtpu 0.0.34): each product alone at ``nemotron-3-nano``'s first
+# tier (9,216 rows in 8 groups, the last holding the tier's padding; 92 GFLOP a call), ms a call,
+# the compiler's 512,128,128 -> the best of 25 (k, n) tile pairs at rows 512 (``PRODUCT_TILES``
+# squared):
+#   up, down's cotangent ([9216, 2688] x [8, 2688, 1856])  3.86, 3.86 -> 896,640: 1.04, 1.05
+#   down, up's cotangent ([9216, 1856] x [8, 1856, 2688])  3.23, 3.23 -> 640,896: 0.85, 0.85
+#   the weights' ([8, 2688, 1856], [8, 1856, 2688])        5.13, 3.78 -> 896,640 1.09, 640,896 0.99
+# All 204 tilings tried gave the compiler's tiling's values bit for bit.  A width's tile is the
+# one that pads it least, the larger of two that pad it alike (``_tile``).  Over the six kernels,
+# the other width at its tile: 2,688 at 896 (3 tiles) 0.85-1.09 ms, at 384 (7) 0.96-1.25, at 512
+# (6, 384 columns padded) 1.03-1.30; 1,856 at 640 (3, 64 padded) 0.85-1.09, at 384 (5)
+# 0.92-1.16, at 512 (4) 0.93-1.17, at 896 1.12-1.38.  Rows 256 read -5 to +13 % against 512,
+# rows 1,024 +10 to +21 %.  The layer alone at the cell (T 8,192, 6 of 128 a token, 8 held),
+# forward and backward: 36.8 -> 12.0 ms where one tiling a product, set around its forward,
+# reached its transposes (up 896,640, down 384,896).  The cotangent's product swaps k and n, so
+# no one tiling suits it and the forward: each kernel tiled from its own widths
+# (``_tiled_product``) saves ~1.1 ms more a layer-step.  In the round of
+# ``sim.fedavg.nemotron-nano.1chip``: the expert layers 2,808 -> 1,245 ms, the round 6.09 -> 4.52 s
+PRODUCT_TILES = (256, 384, 512, 640, 896)
+
+
+def _compiler_tile(width: int) -> int:
+    return next((t for t in (512, 256) if width % t == 0), 128)
+
+
+def _tile(width: int) -> int:
+    return min(PRODUCT_TILES, key=lambda t: (-(-width // t) * t, -t))
+
+
+def product_tiling(rows: int, k: int, n: int) -> Optional[str]:
+    """The ``ragged_dot_tiling`` a grouped product ``[rows, k] x [groups, k, n]`` (and the
+    weights' product of its backward, over the same k and n) is given, or None where the
+    compiler's own pick stands: where its k and n tiles are 256 or more, and where a width is
+    under 512.  Symmetric in k and n."""
+    if min(rows, k, n) < 512 or min(_compiler_tile(k), _compiler_tile(n)) >= 256:
+        return None
+    return f"512,{_tile(k)},{_tile(n)}"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tiled_product(x, w, sizes, tiling: str):
+    with set_xla_metadata(ragged_dot_tiling=tiling):
+        return jax.lax.ragged_dot(x, w, sizes)
+
+
+def _tiled_product_fwd(x, w, sizes, tiling):
+    return _tiled_product(x, w, sizes, tiling), (x, w, sizes)
+
+
+def _tiled_product_bwd(tiling, residuals, g):
+    """jax's own two transposes of the product, each under the tiling of its own widths: the
+    cotangent's ``[rows, n] x [groups, n, k]``, and the weights' (each group's rows
+    contracted), whose tiles are the forward's (k, n)."""
+    x, w, sizes = residuals
+    w_t = jnp.swapaxes(w, 1, 2)
+    with set_xla_metadata(ragged_dot_tiling=product_tiling(x.shape[0], *w_t.shape[1:])):
+        dx = jax.lax.ragged_dot(g, w_t, sizes)
+    with set_xla_metadata(ragged_dot_tiling=tiling):
+        dw = jax.lax.ragged_dot_general(x, g, sizes, jax.lax.RaggedDotDimensionNumbers(
+            (([0], [0]), ([], [])), lhs_ragged_dimensions=[0], rhs_group_dimensions=[]))
+    return dx.astype(x.dtype), dw.astype(w.dtype), None
+
+
+_tiled_product.defvjp(_tiled_product_fwd, _tiled_product_bwd)
+
+
+def _grouped_product(x, w, sizes):
+    tiling = product_tiling(x.shape[0], *w.shape[1:])
+    if tiling is None:
+        return jax.lax.ragged_dot(x, w, sizes)
+    return _tiled_product(x, w, sizes, tiling)
+
 
 def _over_rows(mask, like):
     return mask.reshape(mask.shape + (1,) * (like.ndim - 1))
@@ -326,7 +405,11 @@ def grouped_experts(h, chosen, weights, held: Tuple[int, int], w_gate, w_up, w_d
     cotangent (:func:`spread_rows`); the experts' rows are counted by a compare
     and a sum: no scatter-add is left in the layer.  Below it (8 of 256: one
     block is an eighth) the combine is ``zeros.at[token].add``, XLA transposes
-    the gathers and ``bincount`` counts, as before."""
+    the gathers and ``bincount`` counts, as before.
+
+    A grouped product whose widths would fall to the compiler's 128-wide tiles runs
+    under the tiling :func:`product_tiling` gives its shape, and so do its two
+    transposes (gauges ``moe.tiled_products``, ``moe.tile_k``, ``moe.tile_n``)."""
     from ..core import obs
 
     T, k = chosen.shape
@@ -337,6 +420,14 @@ def grouped_experts(h, chosen, weights, held: Tuple[int, int], w_gate, w_up, w_d
     n_blocks = tiers[-1]
     gathered = base >= GATHERED_SHARE * total
     obs.gauge_set("moe.combine_gathered", int(gathered))
+    f = w_up.shape[-1]
+    up_tiling = product_tiling(base, d, f)
+    tilings = [up_tiling] * (1 if w_gate is None else 2) + [product_tiling(base, f, d)]
+    # a tiled product's two transposes are tiled too: three kernels a product
+    obs.gauge_set("moe.tiled_products", 3 * sum(t is not None for t in tilings))
+    tile_k, tile_n = up_tiling.split(",")[1:] if up_tiling else map(_compiler_tile, (d, f))
+    obs.gauge_set("moe.tile_k", int(tile_k))
+    obs.gauge_set("moe.tile_n", int(tile_n))
     flat = chosen.reshape(-1)
     with jax.named_scope("lm.moe.dispatch"):
         local = (flat >= lo) & (flat < hi)
@@ -378,12 +469,12 @@ def grouped_experts(h, chosen, weights, held: Tuple[int, int], w_gate, w_up, w_d
                 x = jnp.where(live[:, None], h[token], 0)
         with jax.named_scope("lm.moe.experts"):
             if w_gate is None:
-                hidden = activation(jax.lax.ragged_dot(x, w_up, block_sizes))
+                hidden = activation(_grouped_product(x, w_up, block_sizes))
             else:
-                gate = jax.lax.ragged_dot(x, w_gate, block_sizes)
-                up = jax.lax.ragged_dot(x, w_up, block_sizes)
+                gate = _grouped_product(x, w_gate, block_sizes)
+                up = _grouped_product(x, w_up, block_sizes)
                 hidden = activation(gate) * up
-            y = jax.lax.ragged_dot(hidden, w_down, block_sizes)
+            y = _grouped_product(hidden, w_down, block_sizes)
         with jax.named_scope("lm.moe.combine"):
             if gathered:
                 w = spread_rows(flat_weights, rows, live,

@@ -206,3 +206,81 @@ def test_the_backward_gathers_sit_under_the_layers_scopes():
     assert {scope for _, scope in gathers} == {"lm.moe.dispatch", "lm.moe.combine"}
     assert {(f"{K}x{T}x{D}xf32", "lm.moe.dispatch"), (f"240x{D}xf32", "lm.moe.combine"),
             (f"{K}x{T}x{D}xf32", "lm.moe.combine"), (f"240x{D}xf32", "lm.moe.dispatch")} <= gathers
+
+
+# (d, f, tokens a step, experts a token, held, routed) of each configuration's expert layer at
+# its cell (``benchmark/configs``; ``sdar``'s layers see both halves of a row) and of the tiny
+# presets, and whether the grouped products take a tiling of their own: only
+# nemotron-3-nano's widths fall to the compiler's 128-wide tiles (2,688 = 21 x 128 and 1,856,
+# no multiple of 128)
+TILING_CASES = {
+    "nemotron-3-nano-30b-a3b-sim": (2688, 1856, 8192, 6, 8, 128, True),
+    "kimi-linear-48b-a3b-sim": (2304, 1024, 8192, 8, 8, 256, False),
+    "smallthinker-21b-a3b-sim": (2560, 768, 16384, 6, 16, 64, False),
+    "glm-4.7-flash-sim": (2048, 1536, 8192, 4, 8, 64, False),
+    "sdar-30b-a3b-sim": (2048, 768, 16384, 8, 16, 128, False),
+    "tiny-nemotron-h": (32, 8, 64, 6, 2, 8, False),
+    "tiny-kimi-linear": (36, 16, 64, 4, 4, 16, False),
+    "tiny-smallthinker": (40, 12, 64, 4, 4, 16, False),
+    "tiny-glm47-flash": (32, 24, 64, 4, 8, 8, False),
+    "tiny-sdar": (32, 12, 64, 2, 4, 4, False)}
+
+
+@pytest.mark.parametrize("preset", list(TILING_CASES))
+def test_which_grouped_products_take_a_tiling(preset):
+    """``product_tiling`` reads a product's shape alone: a tiling for nemotron-3-nano's two
+    products (k and n tiles of 256 or more), none for the other configurations' and the
+    tiny presets'.  The gauges say what a traced layer asked for."""
+    from fedml_tpu.core import obs
+
+    d, f, tokens, k, held, routed, tiled = TILING_CASES[preset]
+    base = expert_lm.expert_blocks(tokens * k, held, routed)[0]
+    asked = [expert_lm.product_tiling(base, d, f), expert_lm.product_tiling(base, f, d)]
+    if not tiled:
+        assert asked == [None, None]
+    else:
+        for tiling in asked:
+            rows, tile_k, tile_n = map(int, tiling.split(","))
+            assert min(tile_k, tile_n) >= 256 and rows <= base
+
+    gated = preset != "tiny-nemotron-h" and "nemotron" not in preset
+    s = jax.ShapeDtypeStruct
+    shapes = (s((tokens, d), jnp.bfloat16), s((tokens, k), jnp.int32), s((tokens, k), jnp.float32),
+              s((held, d, f), jnp.bfloat16), s((held, f, d), jnp.bfloat16),
+              s((held, d, f), jnp.bfloat16) if gated else None)
+
+    def layer(h, chosen, weights, w_up, w_down, w_gate):
+        return expert_lm.grouped_experts(h, chosen, weights, (0, held), w_gate, w_up, w_down,
+                                         routed, jax.nn.silu if gated else expert_lm.relu2)[0]
+
+    jax.make_jaxpr(layer)(*shapes)
+    gauges = {r["metric"]: r["value"] for r in obs.registry().export() if r["kind"] == "gauge"
+              and r["metric"] in ("moe.tiled_products", "moe.tile_k", "moe.tile_n")}
+    up = asked[0].split(",")[1:] if tiled else [expert_lm._compiler_tile(w) for w in (d, f)]
+    assert gauges == {"moe.tiled_products": 6 if tiled else 0,
+                      "moe.tile_k": int(up[0]), "moe.tile_n": int(up[1])}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_a_tiled_product_is_the_plain_one(dtype):
+    """Widths that fall to the compiler's 128-wide tiles (640 and 576: 5 and 4.5 x 128): the
+    product under its tilings — its own custom backward, the cotangent's product tiled from
+    its own widths — gives ``jax.lax.ragged_dot``'s value and gradients, bit for bit."""
+    rows, groups, k, n = 512, 4, 640, 576
+    assert expert_lm.product_tiling(rows, k, n) == "512,640,640"
+    assert expert_lm.product_tiling(rows, n, k) == "512,640,640"
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    x = jax.random.normal(ks[0], (rows, k), dtype)
+    w = (jax.random.normal(ks[1], (groups, k, n)) * k ** -0.5).astype(dtype)
+    wave = jax.random.normal(ks[2], (rows, n))
+    sizes = jnp.array([100, 0, 250, 162], jnp.int32)
+
+    def loss(product):
+        return lambda x, w: jnp.sum(product(x, w, sizes).astype(jnp.float32) * wave)
+
+    got = jax.jit(jax.value_and_grad(loss(expert_lm._grouped_product), (0, 1)))(x, w)
+    want = jax.jit(jax.value_and_grad(loss(jax.lax.ragged_dot), (0, 1)))(x, w)
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss(expert_lm._grouped_product)))(x, w))
+    assert "custom_vjp_call" in jaxpr
+    for g, r in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(r, np.float32))

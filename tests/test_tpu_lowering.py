@@ -239,6 +239,37 @@ def test_kimi_linear_ops_compile_for_v5e(v5e):
     assert "ragged" in compiled.as_text()
 
 
+_LAYER_TEXTS = {}
+
+
+def _expert_layer(v5e, T, k, held, routed, d, f, gated, activation):
+    """``grouped_experts`` forward and backward (the value and the gradients of ``h``, the
+    weights and the experts) lowered and compiled for the described v5e at one shape: (the
+    lowered text, the compiled text), kept for the module's other tests at that shape."""
+    from fedml_tpu.models import expert_lm
+
+    key = (T, k, held, routed, d, f, gated, activation)
+    if key not in _LAYER_TEXTS:
+        sharding = jax.sharding.SingleDeviceSharding(v5e)
+
+        def s(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        def experts(h, chosen, weights, w_up, w_down, w_gate=None):
+            out, counters = expert_lm.grouped_experts(h, chosen, weights, (0, held), w_gate,
+                                                      w_up, w_down, routed, activation)
+            return out.astype(jnp.float32).sum() + counters["moe.assignments_dropped"]
+
+        args = (s((T, d), jnp.bfloat16), s((T, k), jnp.int32), s((T, k), jnp.float32),
+                s((held, d, f), jnp.bfloat16), s((held, f, d), jnp.bfloat16))
+        if gated:
+            args += (s((held, d, f), jnp.bfloat16),)
+        lowered = jax.jit(jax.value_and_grad(experts, argnums=(0, 2) + tuple(
+            range(3, len(args))))).lower(*args)
+        _LAYER_TEXTS[key] = (lowered.as_text(), lowered.compile().as_text())
+    return _LAYER_TEXTS[key]
+
+
 def test_a_large_tiers_expert_layer_moves_its_rows_by_gathers(v5e):
     """``sim.fedavg.smallthinker.1chip``'s expert layer, forward and backward at the
     cell's shape (16 of 64 experts, 6 a token: a block of three quarters of the
@@ -246,32 +277,49 @@ def test_a_large_tiers_expert_layer_moves_its_rows_by_gathers(v5e):
     back are gathers and the experts' rows are counted without ``bincount``, so the
     layer holds no scatter.  ``kimi-linear``'s shape, a block of an eighth, keeps its
     row-sized scatter-adds."""
-    import re
-
-    from fedml_tpu.models import expert_lm
-
-    sharding = jax.sharding.SingleDeviceSharding(v5e)
-
-    def s(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
     def scatters(T, k, held, routed, d, f):
-        def experts(h, chosen, weights, w_gate, w_up, w_down):
-            out, counters = expert_lm.grouped_experts(h, chosen, weights, (0, held), w_gate,
-                                                      w_up, w_down, routed, jax.nn.relu)
-            return out.astype(jnp.float32).sum() + counters["moe.assignments_dropped"]
-
-        args = (s((T, d), jnp.bfloat16), s((T, k), jnp.int32), s((T, k), jnp.float32),
-                s((held, d, f), jnp.bfloat16), s((held, d, f), jnp.bfloat16),
-                s((held, f, d), jnp.bfloat16))
-        text = jax.jit(jax.value_and_grad(experts, argnums=(0, 2, 3, 4, 5))).lower(
-            *args).compile().as_text()
+        _, text = _expert_layer(v5e, T, k, held, routed, d, f, True, jax.nn.relu)
         assert "ragged" in text
         return re.findall(r"= (\w+)\[([\d,]*)\]\S* scatter\(", text)
 
     assert scatters(16384, 6, 16, 64, 2560, 768) == []
     kept = scatters(8192, 8, 8, 256, 2304, 1024)
     assert any(dtype in ("bf16", "f32") and shape.startswith("8192,") for dtype, shape in kept)
+
+
+# (tokens, experts a token, held, routed, d, f, gated) of three cells' expert layers: the
+# compiler's own tiles (rows, k, n) are the largest of 512, 256 and 128 that divide a width,
+# so nemotron-3-nano's 2,688 (21 x 128) and 1,856 (14.5 x 128) fall to 128-wide k and n
+# tiles and take a tiling of their own; kimi-linear's and smallthinker's keep the compiler's
+LAYERS = {"nemotron-nano": (8192, 6, 8, 128, 2688, 1856, False),
+          "kimi-linear": (8192, 8, 8, 256, 2304, 1024, True),
+          "smallthinker": (16384, 6, 16, 64, 2560, 768, True)}
+COMPILERS_OWN = {"kimi-linear": {"512,256,512", "512,512,256"},
+                 "smallthinker": {"512,512,256", "512,256,512"}}
+
+
+@pytest.mark.parametrize("cell", list(LAYERS))
+def test_the_grouped_products_take_a_tiling_from_their_widths(v5e, cell):
+    """Every ``ragged-dot-none`` kernel of the layer's forward and backward, compiled for
+    the described v5e, carries the tiling the compiler ran it at: above 128 in k and n at
+    nemotron-3-nano's widths (forward, the cotangent's and the weights' products of both
+    matrices, and the remat's forward), the compiler's own pick at the others'."""
+    from fedml_tpu.models import expert_lm
+
+    T, k, held, routed, d, f, gated = LAYERS[cell]
+    activation = jax.nn.relu if gated else expert_lm.relu2
+    _, text = _expert_layer(v5e, T, k, held, routed, d, f, gated, activation)
+    kernels = [line for line in text.splitlines()
+               if re.match(r"\s*(ROOT )?%ragged-dot-none\S* = ", line)]
+    tilings = [re.search(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', line) for line in kernels]
+    assert kernels and all(tilings), cell
+    found = {",".join(t.groups()) for t in tilings}
+    base = expert_lm.expert_blocks(T * k, held, routed)[0]
+    asked = {expert_lm.product_tiling(base, d, f), expert_lm.product_tiling(base, f, d)}
+    if cell in COMPILERS_OWN:
+        assert asked == {None} and found == COMPILERS_OWN[cell]
+    else:
+        assert found == asked and all(min(int(t.group(2)), int(t.group(3))) > 128 for t in tilings)
 
 
 # block diffusion over [x_noised ; x_clean]: the benchmark's own call (sdar-30b-a3b-sim: 32 / 4
@@ -388,18 +436,6 @@ def test_a_non_gated_expert_layer_compiles_for_v5e(v5e):
     three."""
     from fedml_tpu.models import expert_lm
 
-    sharding = jax.sharding.SingleDeviceSharding(v5e)
-
-    def s(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
-    def experts(h, chosen, weights, w_up, w_down):
-        out, counters = expert_lm.grouped_experts(h, chosen, weights, (0, 8), None, w_up,
-                                                  w_down, 128, expert_lm.relu2)
-        return out.astype(jnp.float32).sum() + counters["moe.assignments_dropped"]
-
-    args = (s((8192, 2688), jnp.bfloat16), s((8192, 6), jnp.int32), s((8192, 6), jnp.float32),
-            s((8, 2688, 1856), jnp.bfloat16), s((8, 1856, 2688), jnp.bfloat16))
-    lowered = jax.jit(jax.grad(experts, argnums=(0, 3, 4))).lower(*args)
-    assert lowered.as_text().count("ragged_dot") > 0
-    assert "ragged" in lowered.compile().as_text()
+    lowered, compiled = _expert_layer(v5e, 8192, 6, 8, 128, 2688, 1856, False, expert_lm.relu2)
+    assert lowered.count("ragged_dot") > 0
+    assert "ragged" in compiled
