@@ -712,7 +712,9 @@ class ServerRecoveryMixin:
         # chunk of a partial upload) route to the reassembler, never the
         # slot table — a complete-but-unacked stream re-dispatches when its
         # sender retransmits, and _journal_upload's sender dedup below keeps
-        # the finished upload exactly-once either way
+        # the finished upload exactly-once either way.  Both kinds count as
+        # replays: a kill before any stream completed leaves a journal of
+        # chunks alone, and its restore is a replay all the same.
         chunk_recs = [r for r in records if r.get("kind") == "chunk"]
         for rec in records:
             if rec.get("kind") == "chunk":
@@ -727,7 +729,7 @@ class ServerRecoveryMixin:
         if chunk_recs:
             chunking = getattr(self, "_chunking", None)
             if chunking is not None:
-                chunking.restore(chunk_recs)
+                replayed += chunking.restore(chunk_recs)
         # already-initialized: the ONLINE handshake must NOT restart round 0.
         # _client_epochs is deliberately NOT restored — every client's next
         # ONLINE therefore reads as a rejoin and flows through the existing

@@ -26,8 +26,36 @@ _mod = _ilu.module_from_spec(_spec)
 _spec.loader.exec_module(_mod)
 _mod.force_cpu_backend()
 
+import signal  # noqa: E402
+import threading  # noqa: E402
+
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+#: Seconds one test may run. Past it the test fails under its own name and the
+#: session goes on, instead of a hang cutting the whole run at its outer limit.
+TEST_TIME_LIMIT_S = 600
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    """Arm SIGALRM around the test's call. Signals reach the main thread alone,
+    which is where pytest (and each xdist worker) runs tests; elsewhere the
+    test runs unlimited. A test stuck inside one C call fails once it returns."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def expired(signum, frame):
+        raise TimeoutError(f"{item.nodeid} ran past its {TEST_TIME_LIMIT_S} s limit")
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.alarm(TEST_TIME_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, previous)
 
 
 @pytest.fixture(autouse=True)

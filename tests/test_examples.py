@@ -36,10 +36,22 @@ def test_examples_exist():
     assert len(_all_configs("cross_silo")) >= 4
 
 
-@pytest.mark.parametrize(
-    "cfg", _all_configs("simulation"), ids=lambda p: p.split(os.sep)[-2]
-)
-def test_simulation_example(cfg):
+def _family(cfg):
+    name = os.path.basename(os.path.dirname(cfg))
+    if not name.startswith("xla_"):
+        return "host"
+    return "split" if name.startswith(("xla_fedgkt_", "xla_split_nn_", "xla_vfl_")) else "in_mesh"
+
+
+def _simulation_configs(family):
+    """The simulation examples of one family: ``host`` (the sp and mpi backends,
+    run here), ``in_mesh`` (``xla_*``, run by ``tests/test_examples_xla.py``) or
+    ``split`` (the in-mesh ones that train a model split between the clients and
+    the server, run by ``tests/test_examples_xla_split.py``)."""
+    return [c for c in _all_configs("simulation") if _family(c) == family]
+
+
+def run_simulation_example(cfg):
     args = _load(cfg, run_id=f"ex-{os.path.basename(os.path.dirname(cfg))}")
     args = fedml_tpu.init(args, should_init_logs=False)
     device = fedml_tpu.device.get_device(args)
@@ -53,6 +65,13 @@ def test_simulation_example(cfg):
         assert metrics and "d_fake_score" in metrics
     else:
         assert metrics and "test_acc" in metrics
+
+
+@pytest.mark.parametrize(
+    "cfg", _simulation_configs("host"), ids=lambda p: p.split(os.sep)[-2]
+)
+def test_simulation_example(cfg):
+    run_simulation_example(cfg)
 
 
 @pytest.mark.parametrize(

@@ -199,7 +199,7 @@ def test_block_is_the_reference_and_drops_nothing(model, layer):
     want = _value_and_grads(lambda p, h: ref.block(h, p, model, "highest"), (w, x))
     _assert_close(want, got, 2e-5)
     if layer:
-        counters = program(w, x)[1]["moe"]
+        counters = jax.jit(program)(w, x)[1]["moe"]
         total = 2 * 40 * model["num_experts_per_tok"]
         assert float(counters["moe.assignments_total"]) == total
         assert float(counters["moe.assignments_dropped"]) == 0.0
@@ -266,16 +266,28 @@ def test_model_has_the_references_tree_and_counters(built, model):
     assert jax.tree_util.tree_structure(back) == jax.tree_util.tree_structure(weights)
 
 
+@pytest.fixture(scope="module")
+def losses_and_grads(built, model):
+    """The program's and the reference's loss-and-gradient and the reference's three
+    readings, each jitted once with the row mask as an argument."""
+    module, _, tokens, targets = built
+    program = jax.jit(jax.value_and_grad(
+        lambda v, mask: _program_losses(module, v, tokens, targets, mask), has_aux=True))
+    reference = jax.jit(jax.value_and_grad(
+        lambda w, mask: ref.loss_fn(w, tokens, targets, mask, model, "highest")))
+    readings = jax.jit(lambda w, mask: ref.losses(w, tokens, targets, mask, model, "highest"))
+    return program, reference, readings
+
+
 @pytest.mark.parametrize("mask", [(1.0, 1.0), (1.0, 0.0)], ids=["full_batch", "half_empty_batch"])
-def test_both_losses_and_every_gradient_are_the_references(built, model, mask):
+def test_both_losses_and_every_gradient_are_the_references(built, model, losses_and_grads, mask):
     module, weights, tokens, targets = built
+    program_fn, reference_fn, readings_fn = losses_and_grads
     mask = jnp.asarray(mask)
     program = sim_glm47_flash.to_program(weights)
-    got = jax.jit(jax.value_and_grad(
-        lambda v: _program_losses(module, v, tokens, targets, mask), has_aux=True))(program)
-    want = jax.jit(jax.value_and_grad(
-        lambda w: ref.loss_fn(w, tokens, targets, mask, model, "highest")))(weights)
-    main, mtp, positions = ref.losses(weights, tokens, targets, mask, model, "highest")
+    got = program_fn(program, mask)
+    want = reference_fn(weights, mask)
+    main, mtp, positions = readings_fn(weights, mask)
     (total, sums), grads = got
     assert abs(float(total) - float(want[0])) < 2e-6 * float(want[0])
     assert abs(float(sums["lm.loss_main"]) - float(main)) < 2e-6 * float(main)
@@ -335,7 +347,8 @@ def test_without_train_the_module_is_not_run(built, model, monkeypatch):
         module.apply(program, tokens, train=True)
     # eval is the engine's as for any model
     loss_sum, _, count = engine.make_eval_fn(module)(program, tokens, targets, jnp.ones(2))
-    main = ref.losses(weights, tokens, targets, jnp.ones(2), model, "highest")[0]
+    main = jax.jit(lambda w: ref.losses(w, tokens, targets, jnp.ones(2), model, "highest")[0])(
+        weights)
     assert float(loss_sum / count) == pytest.approx(float(main), rel=1e-5)
 
 
@@ -346,7 +359,8 @@ def test_padded_engine_takes_the_model(built, model):
     args = types.SimpleNamespace(client_optimizer="sgd", learning_rate=0.002, epochs=1)
     train = jax.jit(engine.build_local_train(module, args, batch_size=2, padded_n=2, loss="ce"))
     result = train(sim_glm47_flash.to_program(weights), tokens, targets, 2, jax.random.PRNGKey(0))
-    want = ref.loss_fn(weights, tokens, targets, jnp.ones(2), model, "highest")
+    want = jax.jit(lambda w: ref.loss_fn(w, tokens, targets, jnp.ones(2), model, "highest"))(
+        weights)
     assert float(result.loss) == pytest.approx(float(want), rel=1e-5)
     moved = jax.tree_util.tree_map(lambda a, b: float(jnp.max(jnp.abs(a - b))),
                                    result.variables["params"]["mtp"]["w_eh"],
@@ -368,7 +382,8 @@ def test_module_is_under_the_blocks_remat_and_keeps_its_kernels_results(model, m
     loss = lambda v: _program_losses(module, v, tokens, targets, jnp.ones(1))[0]  # noqa: E731
     calls = _kernel_calls(jax.make_jaxpr(jax.grad(loss))(program).jaxpr)
     assert calls == {"flash_fwd": 4, "flash_bwd_dq": 4, "flash_bwd_dkv": 4}
-    want = ref.loss_fn(weights, tokens, targets, jnp.ones(1), model, "highest")
+    want = jax.jit(lambda w: ref.loss_fn(w, tokens, targets, jnp.ones(1), model, "highest"))(
+        weights)
     assert float(jax.jit(loss)(program)) == pytest.approx(float(want), rel=2e-6)
 
 

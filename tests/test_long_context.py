@@ -31,13 +31,15 @@ class TestRingAttention:
     def test_matches_full_attention_causal(self, sp_mesh):
         q, k, v = _qkv()
         full = reference_attention(q, k, v, causal=True)
-        ring = ring_attention(q, k, v, sp_mesh, axis_name="sp", causal=True)
+        ring = jax.jit(lambda q, k, v: ring_attention(
+            q, k, v, sp_mesh, axis_name="sp", causal=True))(q, k, v)
         np.testing.assert_allclose(np.asarray(ring), np.asarray(full), atol=2e-5)
 
     def test_matches_full_attention_noncausal(self, sp_mesh):
         q, k, v = _qkv(seed=3)
         full = reference_attention(q, k, v, causal=False)
-        ring = ring_attention(q, k, v, sp_mesh, axis_name="sp", causal=False)
+        ring = jax.jit(lambda q, k, v: ring_attention(
+            q, k, v, sp_mesh, axis_name="sp", causal=False))(q, k, v)
         np.testing.assert_allclose(np.asarray(ring), np.asarray(full), atol=2e-5)
 
     def test_grad_flows(self, sp_mesh):
@@ -49,7 +51,7 @@ class TestRingAttention:
         def loss_full(q):
             return jnp.sum(reference_attention(q, k, v) ** 2)
 
-        g_ring = jax.grad(loss_ring)(q)
+        g_ring = jax.jit(jax.grad(loss_ring))(q)
         g_full = jax.grad(loss_full)(q)
         np.testing.assert_allclose(np.asarray(g_ring), np.asarray(g_full), atol=5e-4)
 
@@ -64,7 +66,7 @@ class TestSequenceParallelTransformer:
         tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 128)
 
         single = TransformerLM(cfg).apply(params, tokens)
-        sp = sp_apply(cfg, params, tokens, sp_mesh)
+        sp = jax.jit(lambda p, t: sp_apply(cfg, p, t, sp_mesh))(params, tokens)
         np.testing.assert_allclose(np.asarray(sp), np.asarray(single), atol=3e-4)
 
     def test_sp_training_step_decreases_loss(self, sp_mesh):
@@ -237,11 +239,11 @@ class TestRingPlusPallas:
 
         q, k, v = _qkv(B=1, L=64, H=2, D=16, seed=23)
         full = reference_attention(q, k, v, causal=causal)
-        ring = ring_attention(
+        ring = jax.jit(lambda q, k, v: ring_attention(
             q, k, v, sp_mesh, axis_name="sp", causal=causal,
             block_fn=partial(pallas_block_attend, block_q=8, block_k=8,
                              interpret=True),
-        )
+        ))(q, k, v)
         np.testing.assert_allclose(np.asarray(ring), np.asarray(full), atol=2e-5)
 
     def test_shard_update_matches_block_attend(self):
@@ -288,7 +290,7 @@ class TestRingPlusPallas:
         def loss_full(q):
             return jnp.sum(reference_attention(q, k, v) ** 2)
 
-        g_ring = jax.grad(loss_ring)(q)
+        g_ring = jax.jit(jax.grad(loss_ring))(q)
         g_full = jax.grad(loss_full)(q)
         np.testing.assert_allclose(np.asarray(g_ring), np.asarray(g_full),
                                    atol=5e-4)

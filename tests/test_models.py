@@ -33,8 +33,8 @@ IMAGE_CASES = [
 def test_image_model_forward(name, classes, shape):
     m = hub.create(_Args(name), classes)
     x = jnp.zeros(shape, jnp.float32)
-    variables = m.init(jax.random.PRNGKey(0), x, train=False)
-    out = m.apply(variables, x, train=False)
+    variables = jax.jit(lambda key: m.init(key, x, train=False))(jax.random.PRNGKey(0))
+    out = jax.jit(lambda v: m.apply(v, x, train=False))(variables)
     assert out.shape == (shape[0], classes)
 
 
@@ -48,8 +48,8 @@ SEQ_CASES = [
 def test_seq_model_forward(name, vocab, shape):
     m = hub.create(_Args(name, dataset="shakespeare"), vocab)
     x = jnp.zeros(shape, jnp.int32)
-    variables = m.init(jax.random.PRNGKey(0), x, train=False)
-    out = m.apply(variables, x, train=False)
+    variables = jax.jit(lambda key: m.init(key, x, train=False))(jax.random.PRNGKey(0))
+    out = jax.jit(lambda v: m.apply(v, x, train=False))(variables)
     assert out.shape[0] == shape[0] and out.shape[-1] >= vocab
 
 
@@ -59,8 +59,8 @@ def test_transformer_forward():
     cfg = TransformerConfig(vocab_size=128, d_model=64, n_heads=4, n_layers=2, d_ff=128)
     m = TransformerLM(cfg)
     x = jnp.zeros((2, 16), jnp.int32)
-    variables = m.init(jax.random.PRNGKey(0), x, train=False)
-    out = m.apply(variables, x, train=False)
+    variables = jax.jit(lambda key: m.init(key, x, train=False))(jax.random.PRNGKey(0))
+    out = jax.jit(lambda v: m.apply(v, x, train=False))(variables)
     assert out.shape == (2, 16, 128)
 
 
@@ -72,9 +72,9 @@ def test_transformer_causality():
     m = TransformerLM(cfg)
     x1 = jnp.zeros((1, 8), jnp.int32)
     x2 = x1.at[0, 7].set(5)
-    v = m.init(jax.random.PRNGKey(0), x1, train=False)
-    o1 = m.apply(v, x1, train=False)
-    o2 = m.apply(v, x2, train=False)
+    v = jax.jit(lambda key: m.init(key, x1, train=False))(jax.random.PRNGKey(0))
+    forward = jax.jit(lambda v, x: m.apply(v, x, train=False))
+    o1, o2 = forward(v, x1), forward(v, x2)
     np.testing.assert_allclose(o1[0, :7], o2[0, :7], atol=1e-5)
     assert not np.allclose(o1[0, 7], o2[0, 7])
 
@@ -84,11 +84,11 @@ def test_gan_pair():
 
     g, d = MNISTGenerator(), MNISTDiscriminator()
     z = jnp.zeros((2, 100))
-    gv = g.init(jax.random.PRNGKey(0), z, train=False)
-    img = g.apply(gv, z, train=False)
+    gv = jax.jit(lambda key: g.init(key, z, train=False))(jax.random.PRNGKey(0))
+    img = jax.jit(lambda v: g.apply(v, z, train=False))(gv)
     assert img.shape == (2, 28, 28, 1)
-    dv = d.init(jax.random.PRNGKey(1), img, train=False)
-    out = d.apply(dv, img, train=False)
+    dv = jax.jit(lambda key: d.init(key, img, train=False))(jax.random.PRNGKey(1))
+    out = jax.jit(lambda v: d.apply(v, img, train=False))(dv)
     assert out.shape == (2, 1)
 
 

@@ -135,7 +135,7 @@ def test_mixer_is_the_references(model):
 def test_mixer_init_follows_the_assumed_rules(model):
     cfg = _cfg(model)
     h = jnp.zeros((1, 8, model["hidden_size"]))
-    w = nemotron_h.Mamba2Mixer(cfg).init(jax.random.PRNGKey(0), h)["params"]
+    w = jax.jit(nemotron_h.Mamba2Mixer(cfg).init)(jax.random.PRNGKey(0), h)["params"]
     assert np.all((jnp.exp(w["A_log"]) >= 1.0) & (jnp.exp(w["A_log"]) <= 16.0))
     dt = jax.nn.softplus(w["dt_bias"])
     assert np.all((dt >= 1e-3 * 0.999) & (dt <= 1e-1 * 1.001))
@@ -191,21 +191,32 @@ def test_model_has_the_references_tree(built, model):
     assert module.round_counters == expert_lm.COUNTERS + ("ssm.positions",)
 
 
+@pytest.fixture(scope="module")
+def losses_and_grads(built, model):
+    """The program's and the reference's loss-and-gradient on the next tokens, each jitted
+    once with the row mask as an argument."""
+    module, _, tokens = built
+    targets = jnp.roll(tokens, -1, axis=1)
+    loss_fn = engine.build_loss_fn(module, True, "ce", module.round_counters)
+    program = jax.jit(jax.value_and_grad(
+        lambda v, mask: loss_fn(v["params"], {}, tokens, targets, mask, jax.random.PRNGKey(1)),
+        has_aux=True))
+    reference = jax.jit(jax.value_and_grad(
+        lambda w, mask: ref.loss_fn(w, tokens, targets, mask, model, "highest")))
+    return program, reference
+
+
 @pytest.mark.parametrize("mask", [(1.0, 1.0), (1.0, 0.0)], ids=["full_batch", "half_empty_batch"])
-def test_loss_and_every_gradient_are_the_references(built, model, mask):
+def test_loss_and_every_gradient_are_the_references(built, model, losses_and_grads, mask):
     """Tolerances: the loss to 2e-6 relative; every leaf's gradient to 1e-4 of its largest
     entry (the remat's second forward and the grouped products' sums form FMAs elsewhere
     than the reference's)."""
     module, weights, tokens = built
+    program_fn, reference_fn = losses_and_grads
     mask = jnp.asarray(mask)
-    targets = jnp.roll(tokens, -1, axis=1)
     program = sim_kimi_linear.to_program(weights)
-    loss_fn = engine.build_loss_fn(module, True, "ce", module.round_counters)
-    (total, (_, sums)), grads = jax.jit(jax.value_and_grad(
-        lambda v: loss_fn(v["params"], {}, tokens, targets, mask, jax.random.PRNGKey(1)),
-        has_aux=True))(program)
-    want = jax.jit(jax.value_and_grad(
-        lambda w: ref.loss_fn(w, tokens, targets, mask, model, "highest")))(weights)
+    (total, (_, sums)), grads = program_fn(program, mask)
+    want = reference_fn(weights, mask)
     assert abs(float(total) - float(want[0])) < 2e-6 * float(want[0])
     _assert_close(want[1], sim_kimi_linear.from_program(grads), 1e-4)
     layers = model["hybrid_override_pattern"].count("M")

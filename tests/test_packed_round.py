@@ -230,7 +230,8 @@ def _compiled_and_replayed(module, x_all, y_all, batch, epochs, counts, optimize
     sim = _round_builder(module, args, n_dev, slots, batch, int(counts2d.max()), capture_updates)
     assert (sim.slots, sim.s_max) == (slots, sched.idx.shape[1])
 
-    variables = module.init(jax.random.PRNGKey(3), x_all[:1], train=False)
+    variables = jax.jit(lambda key: module.init(key, x_all[:1], train=False))(
+        jax.random.PRNGKey(3))
     # a server state and client extras that are not zero, so that an
     # algorithm's hook, contribution and per-slot output all show
     noise = lambda tree, seed, lead=(): jax.tree_util.tree_map(  # noqa: E731

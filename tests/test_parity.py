@@ -74,6 +74,9 @@ class TestSyntheticConvergenceGates:
         metrics = _run(_cfg("XLA"))
         assert metrics["test_acc"] >= 0.90, metrics
 
+    # minutes of CPU for a CNN accuracy gate that no benchmark cell runs: tier-1
+    # leaves it out, `-m slow` runs it
+    @pytest.mark.slow
     def test_cifar_resnet20_trajectory(self):
         # shortened CIFAR ResNet trajectory (BENCHMARK_MPI.md:101 shape):
         # above-chance accuracy within a few rounds.  sp backend: one jitted

@@ -155,7 +155,7 @@ def test_a_caller_without_the_policy_does_not_see_the_names(remat, monkeypatch):
                             remat=remat)
     module = TransformerLM(cfg, attention_fn=_flash)
     tokens = jnp.zeros((1, 128), jnp.int32)
-    variables = module.init(jax.random.PRNGKey(0), tokens)
+    variables = jax.jit(module.init)(jax.random.PRNGKey(0), tokens)
 
     def lowered():  # jax numbers its private functions as it lowers: the numbers are cut
         loss = lambda v: jnp.sum(module.apply(v, tokens, train=True))

@@ -1,7 +1,6 @@
 """The ``sdar_moe`` decoder trained by block diffusion against its plain reference, on the
 CPU in float32 at tiny widths with the published ratios (``benchmark/configs/tiny-sdar.json``):
-the block-diffusion mode of the flash kernels (interpret mode) against the mask written
-out, the mixer with and without q/k norms, the expert layer and the shares of a deployment,
+the mixer with and without q/k norms, the expert layer and the shares of a deployment,
 the whole model's loss and gradients, the noise the program draws against the keys the
 reference re-derives, the engine's loss and eval, one packed FedAvg round through
 ``FedMLRunner`` against the reference's round, the round's counters, and the validation of
@@ -40,96 +39,6 @@ def _assert_close(a, b, tol):
     for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b), strict=True):
         scale = float(jnp.max(jnp.abs(x))) + 1e-12
         assert float(jnp.max(jnp.abs(x - y))) <= tol * scale
-
-
-# -- (a) the kernels' block-diffusion mode ------------------------------------------------
-
-def _bd_operands(L, Hq, Hkv, D=8, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed + L + Hq), 4)
-    q = jax.random.normal(ks[0], (1, 2 * L, Hq, D))
-    k, v = (jax.random.normal(key, (1, 2 * L, Hkv, D)) for key in ks[1:3])
-    return q, k, v, jax.random.normal(ks[3], (1, 2 * L, Hq, D))
-
-
-@pytest.mark.parametrize("group", [1, 8])
-@pytest.mark.parametrize("block_len", [1, 4, 16])
-@pytest.mark.parametrize("L,block,queries", [(256, 128, "both"), (640, 256, "both"),
-                                             (256, 128, "noised"), (640, 256, "noised")],
-                         ids=["L256", "L640_padded_tail", "L256_noised", "L640_padded_tail_noised"])
-def test_bd_kernels_are_the_mask_written_out(L, block, queries, block_len, group):
-    """Forward, dQ, dK and dV of the three ``bd_flash_*`` kernels against
-    ``reference_attention`` with the [2L, 2L] mask written out (640 under blocks of 256
-    pads its tail to 768).  ``noised``: q over the noised half alone (L rows against 2L
-    keys: the mask's first L rows), and the same call against the full call's noised half
-    under a cotangent that is zero on the clean half (dK / dV over both halves).
-    Tolerance 2e-6 of a leaf's largest entry: float32 in interpret mode, the online
-    softmax's summation order against the reference's one softmax."""
-    q_full, k, v, w_full = _bd_operands(L, group, 1)
-    rows = L if queries == "noised" else 2 * L
-    q, w = q_full[:, :rows], w_full[:, :rows]
-
-    def out_and_grads(fn, q, w):
-        out, vjp = jax.vjp(fn, q, k, v)
-        return out, vjp(w)
-
-    def kernels(*a):
-        return fa.bd_flash_attention(*a, block_len, block, True)
-
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda: out_and_grads(
-            lambda *a: fa.reference_attention(*a, block_diffusion=block_len), q, w))()
-    got = jax.jit(lambda: out_and_grads(kernels, q, w))()
-    _assert_close(want, got, 2e-6)
-    if queries == "noised":
-        out, (dq, dk, dv) = jax.jit(lambda: out_and_grads(
-            kernels, q_full, w_full.at[:, L:].set(0.0)))()
-        _assert_close((out[:, :L], (dq[:, :L], dk, dv)), got, 2e-6)
-
-
-def test_block_length_one_is_the_causal_call_on_the_clean_half():
-    """At B = 1 a clean query sees the clean keys up to its own: the clean half's output
-    and log-sum-exp are the causal call's, to float32 rounding."""
-    L, Hq, Hkv, D = 256, 4, 2, 8
-    q, k, v, _ = _bd_operands(L, Hq, Hkv, D)
-    out, lse = jax.jit(lambda *a: fa._bd_forward(*a, 1, 128, True))(q, k, v)
-    causal, causal_lse = jax.jit(lambda *a: fa._flash_forward(
-        *a, True, 128, 128, True, with_lse=True))(q[:, L:], k[:, L:], v[:, L:])
-    np.testing.assert_allclose(out[:, L:], causal, atol=2e-6)
-    # lse rows are [B * Hkv * 2 * group, 1, Lp], a kv head's noised heads first
-    clean = lse.reshape(Hkv, 2, Hq // Hkv, L)[:, 1].reshape(Hq, L)
-    np.testing.assert_allclose(clean, causal_lse[:, 0], atol=2e-6)
-
-
-def test_bd_grids_walk_live_steps_and_leave_their_gauges():
-    """The cell's shape: every row's run is causal-shaped, so the paired walk holds live
-    steps only (the issue asks >= 0.95); the gauges carry the bd names, the causal calls'
-    theirs, and the last layer's call of the noised queries alone a second label
-    ``queries: noised``, so the full calls' readings stay."""
-    from fedml_tpu.core import obs
-
-    S = jax.ShapeDtypeStruct
-    kv = S((1, 2 * 8192, 4, 128), jnp.bfloat16)
-    for rows in (2 * 8192, 8192):
-        jax.make_jaxpr(jax.grad(
-            lambda *a: fa.bd_flash_attention(*a, 4).astype(jnp.float32).sum(), (0, 1, 2)))(
-                S((1, rows, 32, 128), jnp.bfloat16), kv, kv)
-    gauges = {(r["metric"], r["labels"].get("kernel"), r["labels"].get("queries")): r["value"]
-              for r in obs.registry().export()
-              if r["kind"] == "gauge" and r["labels"].get("kernel", "").startswith("bd_")}
-    for kernel in fa._BD_KERNELS:
-        for queries, group in ((None, 16), ("noised", 8)):  # the noised copy's heads beside the 8
-            assert gauges[("flash.live_step_share", kernel, queries)] >= 0.95, kernel
-            assert gauges[("flash.kv_group", kernel, queries)] == group
-            assert (gauges[("flash.block_q", kernel, queries)]
-                    == gauges[("flash.block_k", kernel, queries)])
-
-
-@pytest.mark.parametrize("block_len,L,error", [(3, 96, "divide"), (4, 30, "divide"),
-                                               (256, 512, "divide")])
-def test_bd_mode_refuses_blocks_that_do_not_divide_the_tiles(block_len, L, error):
-    q, k, v, _ = _bd_operands(L, 2, 1)
-    with pytest.raises(ValueError, match=error):
-        fa.bd_flash_attention(q, k, v, block_len, None, True)
 
 
 # -- (b) the mixer ------------------------------------------------------------------------
@@ -241,19 +150,31 @@ def test_model_has_the_references_tree(built, model):
     assert module.round_counters == expert_lm.COUNTERS + sdar_moe.BD_COUNTERS
 
 
+@pytest.fixture(scope="module")
+def losses_and_grads(built, model):
+    """The program's and the reference's loss-and-gradient under one step key, each jitted
+    once with the row mask as an argument."""
+    module, _, tokens = built
+    step_key = jax.random.PRNGKey(12)
+    noise = jax.random.fold_in(step_key, ref.NOISE_STREAM)
+    program = jax.jit(jax.value_and_grad(
+        lambda v, mask: _program_loss(module, v, tokens, mask, step_key), has_aux=True))
+    reference = jax.jit(jax.value_and_grad(
+        lambda w, mask: ref.loss_fn(w, tokens, mask, noise, model, "highest")))
+    return program, reference, noise
+
+
 @pytest.mark.parametrize("mask", [(1.0, 1.0), (1.0, 0.0)], ids=["full_batch", "half_empty_batch"])
-def test_loss_and_every_gradient_are_the_references(built, model, mask):
+def test_loss_and_every_gradient_are_the_references(built, model, losses_and_grads, mask):
     """Tolerances: the loss to 2e-6 relative (float32, one sum in another order); every
     leaf's gradient to 1e-4 of its largest entry (the remat's second forward and the
     grouped products' sums form FMAs elsewhere than the reference's, PR 32)."""
     module, weights, tokens = built
-    mask, step_key = jnp.asarray(mask), jax.random.PRNGKey(12)
+    program_fn, reference_fn, noise = losses_and_grads
+    mask = jnp.asarray(mask)
     program = sim_kimi_linear.to_program(weights)
-    (total, sums), grads = jax.jit(jax.value_and_grad(
-        lambda v: _program_loss(module, v, tokens, mask, step_key), has_aux=True))(program)
-    noise = jax.random.fold_in(step_key, ref.NOISE_STREAM)
-    want = jax.jit(jax.value_and_grad(
-        lambda w: ref.loss_fn(w, tokens, mask, noise, model, "highest")))(weights)
+    (total, sums), grads = program_fn(program, mask)
+    want = reference_fn(weights, mask)
     assert abs(float(total) - float(want[0])) < 2e-6 * float(want[0])
     _assert_close(want[1], sim_kimi_linear.from_program(grads), 1e-4)
     assert float(sums["bd.positions"]) == 2 * 32 * float(mask.sum())
@@ -321,8 +242,8 @@ def test_the_engine_hands_the_step_key_on_as_the_noise_stream(built, model):
             loss_fn(program["params"], {}, tokens, tokens, mask, jax.random.PRNGKey(5))
     want = jax.random.fold_in(jax.random.PRNGKey(5), engine.NOISE_STREAM)
     assert len(seen) == 2 and all(np.array_equal(k, want) for k in seen)
-    a = _program_loss(module, program, tokens, mask, jax.random.PRNGKey(5))[1]["bd.masked"]
-    b = _program_loss(module, program, tokens, mask, jax.random.PRNGKey(6))[1]["bd.masked"]
+    masked = jax.jit(lambda key: _program_loss(module, program, tokens, mask, key)[1]["bd.masked"])
+    a, b = masked(jax.random.PRNGKey(5)), masked(jax.random.PRNGKey(6))
     assert float(a) != float(b)
 
 
@@ -348,7 +269,7 @@ def test_eval_reports_its_own_loss_and_the_masked_accuracy(built, model):
     program = sim_kimi_linear.to_program(weights)
     loss_sum, correct, count = engine.make_eval_fn(module)(program, tokens, tokens, jnp.ones(2))
     key = jax.random.fold_in(jax.random.PRNGKey(0), engine.NOISE_STREAM)
-    want = ref.loss_fn(weights, tokens, jnp.ones(2), key, model, "highest")
+    want = jax.jit(lambda w: ref.loss_fn(w, tokens, jnp.ones(2), key, model, "highest"))(weights)
     assert float(count) == 2.0 and float(loss_sum / count) == pytest.approx(float(want), rel=1e-5)
     assert 0.0 <= float(correct / count) <= 1.0
     # the same batch reads the same: one key for every batch
@@ -368,7 +289,7 @@ def test_padded_engine_takes_the_model(built, model):
 
 def test_init_and_a_bare_forward_noise_nothing(built, model):
     module, weights, tokens = built
-    logits = module.apply(sim_kimi_linear.to_program(weights), tokens)
+    logits = jax.jit(module.apply)(sim_kimi_linear.to_program(weights), tokens)
     assert logits.shape == tokens.shape + (model["vocab_size"],)
     with pytest.raises(ValueError, match="blocks of 4"):
         module.apply(sim_kimi_linear.to_program(weights), tokens[:, :30])

@@ -173,7 +173,7 @@ def test_block_is_the_reference_and_drops_nothing(model):
     want = _value_and_grads(lambda p, h: ref.block(h, p, model, 1, "highest"), (w, x))
     _assert_close(want, got, 2e-5)
     assert float(jnp.max(jnp.abs(got[1][0]["router"]))) > 0.0  # the softmax's weights train it
-    counters = program(w, x)[1]
+    counters = jax.jit(program)(w, x)[1]
     total = 2 * 40 * model["moe_num_active_primary_experts"]
     assert float(counters["moe.assignments_total"]) == total
     assert float(counters["moe.assignments_dropped"]) == 0.0
@@ -271,7 +271,8 @@ def test_model_loss_and_gradients_are_the_references(model):
     rng = np.random.default_rng(0)
     tokens, targets = (jnp.asarray(rng.integers(0, model["vocab_size"], (2, 80)), jnp.int32)
                        for _ in range(2))
-    init = module.init(jax.random.PRNGKey(0), tokens[:1, :8], train=False)
+    init = jax.eval_shape(lambda k: module.init(k, tokens[:1, :8], train=False),
+                          jax.random.PRNGKey(0))
     program = sim_kimi_linear.to_program(weights)
     assert list(init) == ["params"]  # no counters among the model's state
     assert (jax.tree_util.tree_map(jnp.shape, init["params"])

@@ -208,48 +208,30 @@ def test_kda_kernels_compile_under_mosaic(v5e, shape):
         assert "kda_" in jax.jit(fn).lower(*args).compile().as_text(), name
 
 
-def test_kimi_linear_ops_compile_for_v5e(v5e):
-    """The XLA-op paths the ``kimi_linear`` decoder adds, forward and backward
-    at the benchmark cell's shapes: KDA chunkwise (scans, the triangular
-    solves) and the expert layer's grouped products (``ragged_dot``, which the
-    TPU compiler turns into its own Mosaic kernels)."""
-    from fedml_tpu.models import expert_lm
-    from fedml_tpu.ops import kda
-
-    sharding = jax.sharding.SingleDeviceSharding(v5e)
-
-    def s(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
-    qkv = s((1, 8192, 32, 128), jnp.bfloat16)
-    kda_args = (qkv, qkv, qkv, s((1, 8192, 32, 128), jnp.float32), s((1, 8192, 32), jnp.float32))
-    kda_grad = jax.grad(lambda *a: kda.kda_chunked(*a).astype(jnp.float32).sum(),
-                        argnums=(0, 1, 2, 3, 4))
-    assert jax.jit(kda_grad).lower(*kda_args).compile() is not None
-
-    def experts(h, chosen, weights, w_gate, w_up, w_down):
-        out, counters = expert_lm.grouped_experts(h, chosen, weights, (0, 8),
-                                                  w_gate, w_up, w_down, 256)
-        return out.astype(jnp.float32).sum() + counters["moe.assignments_dropped"]
-
-    moe_args = (s((8192, 2304), jnp.bfloat16), s((8192, 8), jnp.int32),
-                s((8192, 8), jnp.float32), s((8, 2304, 1024), jnp.bfloat16),
-                s((8, 2304, 1024), jnp.bfloat16), s((8, 1024, 2304), jnp.bfloat16))
-    compiled = jax.jit(jax.grad(experts, argnums=(0, 3, 4, 5))).lower(*moe_args).compile()
-    assert "ragged" in compiled.as_text()
-
+# (tokens, experts a token, held, routed, d, f, gated, activation) of three cells' expert
+# layers: the compiler's own tiles (rows, k, n) are the largest of 512, 256 and 128 that
+# divide a width, so nemotron-3-nano's 2,688 (21 x 128) and 1,856 (14.5 x 128) fall to
+# 128-wide k and n tiles and take a tiling of their own; kimi-linear's and smallthinker's
+# keep the compiler's
+LAYERS = {"nemotron-nano": (8192, 6, 8, 128, 2688, 1856, False, "relu2"),
+          "kimi-linear": (8192, 8, 8, 256, 2304, 1024, True, "silu"),
+          "smallthinker": (16384, 6, 16, 64, 2560, 768, True, "relu")}
+COMPILERS_OWN = {"kimi-linear": {"512,256,512", "512,512,256"},
+                 "smallthinker": {"512,512,256", "512,256,512"}}
 
 _LAYER_TEXTS = {}
 
 
-def _expert_layer(v5e, T, k, held, routed, d, f, gated, activation):
+def _expert_layer(v5e, cell):
     """``grouped_experts`` forward and backward (the value and the gradients of ``h``, the
-    weights and the experts) lowered and compiled for the described v5e at one shape: (the
-    lowered text, the compiled text), kept for the module's other tests at that shape."""
+    weights and the experts) lowered and compiled for the described v5e at a cell's shape:
+    (the lowered text, the compiled text), kept for the module's other tests of that cell."""
     from fedml_tpu.models import expert_lm
 
-    key = (T, k, held, routed, d, f, gated, activation)
-    if key not in _LAYER_TEXTS:
+    if cell not in _LAYER_TEXTS:
+        T, k, held, routed, d, f, gated, activation = LAYERS[cell]
+        activation = {"relu2": expert_lm.relu2, "silu": jax.nn.silu,
+                      "relu": jax.nn.relu}[activation]
         sharding = jax.sharding.SingleDeviceSharding(v5e)
 
         def s(shape, dtype):
@@ -266,8 +248,31 @@ def _expert_layer(v5e, T, k, held, routed, d, f, gated, activation):
             args += (s((held, d, f), jnp.bfloat16),)
         lowered = jax.jit(jax.value_and_grad(experts, argnums=(0, 2) + tuple(
             range(3, len(args))))).lower(*args)
-        _LAYER_TEXTS[key] = (lowered.as_text(), lowered.compile().as_text())
-    return _LAYER_TEXTS[key]
+        _LAYER_TEXTS[cell] = (lowered.as_text(), lowered.compile().as_text())
+    return _LAYER_TEXTS[cell]
+
+
+def test_kimi_linear_ops_compile_for_v5e(v5e):
+    """The XLA-op paths the ``kimi_linear`` decoder adds, forward and backward
+    at the benchmark cell's shapes: KDA chunkwise (scans, the triangular
+    solves) and the expert layer's grouped products (``ragged_dot``, which the
+    TPU compiler turns into its own Mosaic kernels)."""
+    from fedml_tpu.ops import kda
+
+    sharding = jax.sharding.SingleDeviceSharding(v5e)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    qkv = s((1, 8192, 32, 128), jnp.bfloat16)
+    kda_args = (qkv, qkv, qkv, s((1, 8192, 32, 128), jnp.float32), s((1, 8192, 32), jnp.float32))
+    kda_grad = jax.grad(lambda *a: kda.kda_chunked(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2, 3, 4))
+    assert jax.jit(kda_grad).lower(*kda_args).compile() is not None
+
+    # the expert layer at kimi-linear's shape and activation (silu, gated)
+    _, compiled = _expert_layer(v5e, "kimi-linear")
+    assert "ragged" in compiled
 
 
 def test_a_large_tiers_expert_layer_moves_its_rows_by_gathers(v5e):
@@ -277,25 +282,14 @@ def test_a_large_tiers_expert_layer_moves_its_rows_by_gathers(v5e):
     back are gathers and the experts' rows are counted without ``bincount``, so the
     layer holds no scatter.  ``kimi-linear``'s shape, a block of an eighth, keeps its
     row-sized scatter-adds."""
-    def scatters(T, k, held, routed, d, f):
-        _, text = _expert_layer(v5e, T, k, held, routed, d, f, True, jax.nn.relu)
+    def scatters(cell):
+        _, text = _expert_layer(v5e, cell)
         assert "ragged" in text
         return re.findall(r"= (\w+)\[([\d,]*)\]\S* scatter\(", text)
 
-    assert scatters(16384, 6, 16, 64, 2560, 768) == []
-    kept = scatters(8192, 8, 8, 256, 2304, 1024)
+    assert scatters("smallthinker") == []
+    kept = scatters("kimi-linear")
     assert any(dtype in ("bf16", "f32") and shape.startswith("8192,") for dtype, shape in kept)
-
-
-# (tokens, experts a token, held, routed, d, f, gated) of three cells' expert layers: the
-# compiler's own tiles (rows, k, n) are the largest of 512, 256 and 128 that divide a width,
-# so nemotron-3-nano's 2,688 (21 x 128) and 1,856 (14.5 x 128) fall to 128-wide k and n
-# tiles and take a tiling of their own; kimi-linear's and smallthinker's keep the compiler's
-LAYERS = {"nemotron-nano": (8192, 6, 8, 128, 2688, 1856, False),
-          "kimi-linear": (8192, 8, 8, 256, 2304, 1024, True),
-          "smallthinker": (16384, 6, 16, 64, 2560, 768, True)}
-COMPILERS_OWN = {"kimi-linear": {"512,256,512", "512,512,256"},
-                 "smallthinker": {"512,512,256", "512,256,512"}}
 
 
 @pytest.mark.parametrize("cell", list(LAYERS))
@@ -306,9 +300,8 @@ def test_the_grouped_products_take_a_tiling_from_their_widths(v5e, cell):
     matrices, and the remat's forward), the compiler's own pick at the others'."""
     from fedml_tpu.models import expert_lm
 
-    T, k, held, routed, d, f, gated = LAYERS[cell]
-    activation = jax.nn.relu if gated else expert_lm.relu2
-    _, text = _expert_layer(v5e, T, k, held, routed, d, f, gated, activation)
+    T, k, held, routed, d, f = LAYERS[cell][:6]
+    _, text = _expert_layer(v5e, cell)
     kernels = [line for line in text.splitlines()
                if re.match(r"\s*(ROOT )?%ragged-dot-none\S* = ", line)]
     tilings = [re.search(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', line) for line in kernels]
@@ -434,8 +427,6 @@ def test_a_non_gated_expert_layer_compiles_for_v5e(v5e):
     """``sim.fedavg.nemotron-nano.1chip``'s expert layer, forward and backward at the cell's
     shape (8 of 128 experts, 6 a token, squared ReLU): two grouped products a block, not
     three."""
-    from fedml_tpu.models import expert_lm
-
-    lowered, compiled = _expert_layer(v5e, 8192, 6, 8, 128, 2688, 1856, False, expert_lm.relu2)
+    lowered, compiled = _expert_layer(v5e, "nemotron-nano")
     assert lowered.count("ragged_dot") > 0
     assert "ragged" in compiled

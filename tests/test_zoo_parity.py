@@ -84,8 +84,8 @@ class TestModels:
 
         m = EfficientNet(num_classes=10)
         x = jnp.zeros((2, 32, 32, 3))
-        params = m.init(jax.random.PRNGKey(0), x)
-        out = m.apply(params, x)
+        params = jax.jit(m.init)(jax.random.PRNGKey(0), x)
+        out = jax.jit(m.apply)(params, x)
         assert out.shape == (2, 10)
 
     def test_hub_key(self):
